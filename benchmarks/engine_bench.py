@@ -443,7 +443,7 @@ def main(argv=None):
     ap.add_argument("--windows", type=int, default=None)
     ap.add_argument("--out", default=str(JSON_PATH))
     ap.add_argument("--platform", default=None,
-                    choices=("cpu", "gpu", "tpu"),
+                    choices=("cpu", "tpu"),
                     help="pin the jax backend (recorded under meta.backend "
                          "so runs from different platforms never get "
                          "compared silently)")
@@ -451,6 +451,7 @@ def main(argv=None):
 
     from repro import env
     env.set_platform(args.platform)
+    env.enable_compile_cache()
 
     cfg_inc = make_cfg(args.quick)
     # the "full" baseline is the PR-3-era engine: full segment-sum

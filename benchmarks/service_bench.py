@@ -17,6 +17,10 @@ in-process fleet run of the same spec (exact equality — the serving
 equivalence contract), and the cold CLI rows' counter columns are checked
 against the same truth.
 
+A TPU belongs to one process at a time, so the cold CLI children run
+first, before this process initialises a JAX backend (checked); the
+``run.py`` suite hook, whose process already holds the device, skips them.
+
 Writes ``BENCH_service.json`` (lanes/sec per mode, speedups, latency
 percentiles, batch occupancy). ``--quick`` shrinks the workload for the CI
 service-smoke job; ``--check`` fails on an equivalence break or if warm
@@ -36,6 +40,7 @@ import tempfile
 import time
 
 import jax
+from jax._src import xla_bridge
 
 from repro.config import REDUCED_SIM
 from repro.core import tracegen
@@ -119,7 +124,7 @@ def rows_equal(a, b):
         abs(a["cpu_used_frac_mean"] - b["cpu_used_frac_mean"]) < 1e-12
 
 
-def bench(quick: bool):
+def bench(quick: bool, cold_cli: bool = True):
     n_stack = 64 if quick else 128
     n_windows = 32 if quick else 64
     batch_windows = 32
@@ -139,12 +144,20 @@ def bench(quick: bool):
                          shard_windows=batch_windows)
         cfg = replay_config(stack, cfg)
 
+        crows, cold_wall = [], 0.0
+        if cold_cli:
+            # the children need the device: run them while this process
+            # holds none (trace synthesis and precompile are numpy-only)
+            if xla_bridge.backends_are_initialized():
+                raise RuntimeError("cold CLI runs need a process that has "
+                                   "not initialised a JAX backend")
+            crows, cold_wall = cold_cli_rows(stack, specs, n_windows,
+                                             cold_runs)
         truth, seq_wall = direct_rows(cfg, stack, specs, n_windows,
                                       batch_windows)
         srows, srv_wall, stats = served_rows(cfg, stack, specs, n_windows,
                                              batch_windows,
                                              max_lanes=n_queries)
-        crows, cold_wall = cold_cli_rows(stack, specs, n_windows, cold_runs)
 
     served_ok = all(rows_equal(s, t) for s, t in zip(srows, truth))
     # the CLI auto-names its scenario and recomputes deltas vs itself; the
@@ -152,15 +165,12 @@ def bench(quick: bool):
     cold_ok = all(all(c[k] == t[k] for k in NUM_KEYS)
                   for c, t in zip(crows, truth))
 
-    cold_per_query = cold_wall / cold_runs
     out = {
         "meta": {"backend": jax.default_backend(), "quick": quick,
                  "n_stack_windows": n_stack, "query_windows": n_windows,
                  "batch_windows": batch_windows, "queries": n_queries,
                  "max_lanes": n_queries, "schedulers": list(SCHEDULERS),
                  "max_nodes": cfg.max_nodes},
-        "cold_cli": {"runs": cold_runs, "per_query_s": cold_per_query,
-                     "lanes_per_s": 1.0 / cold_per_query},
         "warm_sequential": {"wall_s": seq_wall,
                             "lanes_per_s": n_queries / seq_wall},
         "served": {"wall_s": srv_wall,
@@ -171,17 +181,22 @@ def bench(quick: bool):
                    "latency_p50_s": stats["latency_p50_s"],
                    "latency_p90_s": stats["latency_p90_s"],
                    "latency_p99_s": stats["latency_p99_s"]},
-        "speedup_vs_cold_cli": cold_per_query / (srv_wall / n_queries),
         "speedup_vs_warm_sequential": seq_wall / srv_wall,
-        "equivalence": {"served_matches_direct": served_ok,
-                        "cold_cli_matches_direct": cold_ok},
+        "equivalence": {"served_matches_direct": served_ok},
     }
+    if cold_cli:
+        cold_per_query = cold_wall / cold_runs
+        out["cold_cli"] = {"runs": cold_runs, "per_query_s": cold_per_query,
+                           "lanes_per_s": 1.0 / cold_per_query}
+        out["speedup_vs_cold_cli"] = cold_per_query / (srv_wall / n_queries)
+        out["equivalence"]["cold_cli_matches_direct"] = cold_ok
     return out
 
 
 def run(rows):
-    """run.py suite hook — in-process modes only (no subprocess storms)."""
-    out = bench(quick=True)
+    """run.py suite hook — in-process modes only: the suite's process
+    already holds the device, so no cold CLI children."""
+    out = bench(quick=True, cold_cli=False)
     per_q = out["served"]["wall_s"] / out["meta"]["queries"] * 1e6
     rows.append(("service_served", per_q, out["served"]["lanes_per_s"]))
     rows.append(("service_warm_seq",
@@ -190,8 +205,6 @@ def run(rows):
                  out["warm_sequential"]["lanes_per_s"]))
     rows.append(("service_speedup_vs_seq", 0.0,
                  out["speedup_vs_warm_sequential"]))
-    rows.append(("service_speedup_vs_cold_cli", 0.0,
-                 out["speedup_vs_cold_cli"]))
 
 
 def main():
@@ -200,11 +213,12 @@ def main():
     ap.add_argument("--check", action="store_true",
                     help="fail on equivalence break or < 2x vs cold CLI")
     ap.add_argument("--platform", default=None,
-                    choices=("cpu", "gpu", "tpu"),
+                    choices=("cpu", "tpu"),
                     help="pin the jax backend (recorded in meta.backend)")
     args = ap.parse_args()
     from repro import env
     env.set_platform(args.platform)
+    env.enable_compile_cache()
     out = bench(args.quick)
     print(json.dumps(out, indent=1, sort_keys=True))
     if not args.quick:

@@ -280,7 +280,8 @@ class SimConfig:
     scheduler: str = "greedy"
     sched_batch: int = 1_024          # max pending tasks considered per window
     seed: int = 0
-    use_kernels: bool = False         # Pallas interpret kernels (CPU) vs jnp ref
+    use_kernels: bool = False         # Pallas kernels (compiled on a TPU,
+                                      # interpreted on the CPU) vs jnp ref
     incremental_accounting: bool = True
                                       # maintain node_reserved/node_used by
                                       # per-event deltas (O(events) per window)
@@ -367,9 +368,9 @@ class SimConfig:
                                       # forces the fallback; 'table' demands
                                       # switchless and errors if any lane's
                                       # scheduler is opaque (no table form)
-    commit_tile_p: int = 0            # placement-commit task tile rows per
+    commit_tile_p: int = 0            # placement-commit task rows per
                                       # grid step (0 = kernel default: whole
-                                      # batch under interpret, 128 on TPU)
+                                      # batch interpreted, 8 on a TPU)
     commit_tile_n: int = 0            # node-streaming tile for the commit /
                                       # fused scheduler pass: 0 keeps the
                                       # node dim whole per grid step; k > 0

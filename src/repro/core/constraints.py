@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.events import OP_EQ, OP_GT, OP_LT, OP_NE, OP_NONE
+from repro.core.ordered import sum_columns
 
 
 def constraints_ok(cons: jax.Array, node_attrs: jax.Array) -> jax.Array:
@@ -53,5 +54,5 @@ def placement_scores(req: jax.Array, cons: jax.Array, node_total: jax.Array,
     ok = ok & node_active[None, :]
     denom = jnp.maximum(node_total, 1e-6)          # (N, R)
     leftover = (free[None] - req[:, None]) / denom[None]   # (P, N, R)
-    score = -jnp.sum(leftover, axis=-1)
+    score = -sum_columns(leftover)
     return jnp.where(ok, score, -jnp.inf)

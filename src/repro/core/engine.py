@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from repro.config import SimConfig
 from repro.core import stats as stats_mod
 from repro.core.events import REMOVE_REASON_EVICT, EventKind, EventWindow
+from repro.core.ordered import ordered_scatter_add
 from repro.core.state import (SimState, TASK_EMPTY, TASK_PENDING,
                               TASK_RUNNING, init_state)
 from repro.kernels.segment_usage.ops import segment_usage
@@ -57,13 +58,6 @@ from repro.kernels.segment_usage.ops import segment_usage
 def _masked_slot(mask: jax.Array, slot: jax.Array, overflow: int) -> jax.Array:
     """Route masked-out rows to a dummy overflow index (scatter no-op row)."""
     return jnp.where(mask, slot, overflow)
-
-
-def _scatter_delta(acc: jax.Array, node: jax.Array, mask: jax.Array,
-                   vals: jax.Array) -> jax.Array:
-    """acc (N, R) += vals (E, R) at ``node`` where ``mask``; other rows drop."""
-    return acc.at[_masked_slot(mask, node, acc.shape[0])].add(
-        jnp.where(mask[:, None], vals, 0.0), mode="drop")
 
 
 def apply_node_events(state: SimState, w: EventWindow, cfg: SimConfig
@@ -122,12 +116,13 @@ def apply_task_events(state: SimState, w: EventWindow, cfg: SimConfig
         # (REMOVE, or an ADD reusing a slot that is still running — e.g. the
         # injection pool recycling before its synthesised REMOVE fired)
         gone = (rem | add) & was_running
-        node_reserved = _scatter_delta(node_reserved, ev_node, gone, -old_req)
-        node_used = _scatter_delta(node_used, ev_node, gone, -old_used)
+        node_reserved = ordered_scatter_add(node_reserved, ev_node, gone,
+                                            -old_req)
+        node_used = ordered_scatter_add(node_used, ev_node, gone, -old_used)
         # requirement updates on running tasks move the reservation
         moved = upd & was_running
-        node_reserved = _scatter_delta(node_reserved, ev_node, moved,
-                                       w.a - old_req)
+        node_reserved = ordered_scatter_add(node_reserved, ev_node, moved,
+                                            w.a - old_req)
 
     # --- removals first (a slot can be freed and re-used next window) ---
     rem_rows = _masked_slot(rem, w.slot, T)
@@ -157,8 +152,8 @@ def apply_task_events(state: SimState, w: EventWindow, cfg: SimConfig
         # recompute would see an EMPTY slot, and `gone` already debited the
         # whole old contribution)
         samp = use & (task_state[w.slot] == TASK_RUNNING)
-        node_used = _scatter_delta(node_used, ev_node, samp,
-                                   w.u[:, ucols] - old_used)
+        node_used = ordered_scatter_add(node_used, ev_node, samp,
+                                        w.u[:, ucols] - old_used)
     task_usage = state.task_usage.at[_masked_slot(use, w.slot, T)].set(
         w.u, mode="drop")
 
@@ -204,19 +199,20 @@ def evict_invalid(state: SimState, cfg: SimConfig) -> SimState:
 
 
 def recompute_accounting(state: SimState, cfg: SimConfig) -> SimState:
-    """node_reserved / node_used from the task table (segment-usage kernel).
+    """node_reserved / node_used from the task table (segment usage).
 
     The whole inner loop in full-recompute mode; the periodic *resync* path
     (and the oracle the equivalence tests compare against) under incremental
-    accounting.
+    accounting. Later fits compare against these tallies, so they take the
+    ordered reference sum under ``use_kernels`` too: the segment-usage
+    kernel's matmul sums in its own order (``kernels/segment_usage``).
     """
     running = state.task_state == TASK_RUNNING
     reserved = segment_usage(state.task_node, state.task_req, running,
-                             cfg.max_nodes, use_kernel=cfg.use_kernels)
+                             cfg.max_nodes)
     # align usage columns with the (cpu, memory, disk) resource axes
     used_cols = state.task_usage[:, jnp.array(stats_mod.ACCOUNTED_USAGE_COLS)]
-    used = segment_usage(state.task_node, used_cols, running,
-                         cfg.max_nodes, use_kernel=cfg.use_kernels)
+    used = segment_usage(state.task_node, used_cols, running, cfg.max_nodes)
     return state._replace(node_reserved=reserved, node_used=used)
 
 

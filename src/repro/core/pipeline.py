@@ -12,9 +12,9 @@ The pipeline is fully asynchronous end-to-end:
 
 * batches are staged into a preallocated buffer ring (no per-batch
   ``np.stack`` allocations) and copied to the device *on the fill thread*
-  (``jnp.array(copy=True)`` — see ``WindowPrefetcher._put`` for why it must
-  not be ``device_put``), so host tensorisation + H2D transfer of batch k+1
-  overlap device compute of batch k;
+  (from a private host copy — see ``WindowPrefetcher._put`` for why), so
+  host tensorisation + H2D transfer of batch k+1 overlap device compute of
+  batch k;
 * the drive loop never materialises the per-batch stats pytree — rows stay
   device-resident and dispatch runs ahead, bounded to
   ``WindowedDriver.max_inflight_batches`` so a fast parser cannot pile up
@@ -31,7 +31,6 @@ import time
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.config import SimConfig
@@ -45,11 +44,11 @@ class _StagingPool:
 
     ``stack`` copies a batch of windows into the next ring slot — replacing
     the per-batch ``np.stack`` allocations on the consumer-critical fill
-    path. Reuse is safe because every slot is copied to the device
-    (``jnp.array(copy=True)`` in ``WindowPrefetcher._put``) before the ring
-    wraps around; the raw numpy buffers are never passed into ``jit`` or
-    ``device_put``, both of which zero-copy alias 64-byte-aligned numpy
-    buffers on CPU and would let a later refill corrupt an in-flight batch
+    path. Reuse is safe because ``WindowPrefetcher._put`` transfers a
+    private host copy of the slot, never the slot itself: ``jit`` and
+    ``device_put`` zero-copy alias 64-byte-aligned numpy buffers on the CPU,
+    and a TPU transfer may still read its source after ``device_put``
+    returns, so either would let a later refill corrupt an in-flight batch
     (regression-tested in tests/test_pipeline_async.py).
     """
 
@@ -78,10 +77,10 @@ class WindowPrefetcher:
     The source may yield single windows (staged here into device batches of
     ``batch_windows``) or pre-stacked (W, ...) batches — e.g. straight from
     ``core.precompile.replay_windows`` — which skip the staging copy. Either
-    way the fill thread finishes each batch with an owning device copy
-    (``jnp.array(copy=True)`` in ``_put`` — never ``device_put``, which
-    would alias the staging ring), so the consumer dequeues device-resident
-    tensors and the H2D transfer overlaps the simulation of earlier batches.
+    way the fill thread transfers each batch from a private host copy (never
+    the staging ring itself, see ``_put``), so the consumer dequeues
+    device-resident tensors and the H2D transfer overlaps the simulation of
+    earlier batches.
     """
 
     def __init__(self, cfg: SimConfig, window_iter: Iterator[EventWindow],
@@ -116,12 +115,13 @@ class WindowPrefetcher:
 
     def _put(self, item: EventWindow):
         n = int(np.sum(np.asarray(item.n_valid)))
-        # jnp.array(copy=True), NOT device_put: on CPU, device_put (and raw
-        # jit inputs) zero-copy ALIAS any 64-byte-aligned numpy buffer, so a
-        # staging-ring slot could be rewritten under an in-flight batch. The
-        # explicit copy is the H2D transfer, done here on the fill thread so
-        # it overlaps device compute of earlier batches.
-        dev = jax.tree.map(lambda x: jnp.array(x, copy=True), item)
+        # transfer from a private host copy, never from the staging-ring slot
+        # itself: on the CPU device_put zero-copy ALIASES an aligned numpy
+        # buffer, and on a TPU the H2D copy may still be reading its source
+        # after device_put returns, so either way the next batch would
+        # rewrite the slot under an in-flight batch. The transfer runs here
+        # on the fill thread, overlapping device compute of earlier batches.
+        dev = jax.device_put(jax.tree.map(np.array, item))
         with self._lock:
             self._events_in += n
         self._q.put((dev, n))
@@ -129,6 +129,7 @@ class WindowPrefetcher:
     def _fill(self):
         batch: List[EventWindow] = []
         pool: Optional[_StagingPool] = None
+        end = self._done
         try:
             for w in self._src:
                 if w.kind.ndim == 2:          # pre-stacked (W, E) batch
@@ -145,14 +146,20 @@ class WindowPrefetcher:
                     batch = []
             if batch:
                 self._put(pool.stack(batch))
+        except BaseException as e:  # noqa: BLE001 — re-raised by __iter__
+            end = e
         finally:
-            self._q.put(self._done)
+            self._q.put(end)
 
     def __iter__(self):
         while True:
             item = self._q.get()
             if item is self._done:
                 return
+            if isinstance(item, BaseException):
+                # a source that failed mid-trace must fail the run, not
+                # end it early as if the trace were complete
+                raise item
             dev, n = item
             with self._lock:
                 self._events_out += n

@@ -13,7 +13,10 @@ shape the paper (and refs [15, 16, 27]) describe:
 * node churn (add/remove/update during the trace — paper §III bullet 4);
 * obfuscated attribute key/values + task constraints with {=, ≠, <, >} ops;
 * the 10-minute (600 s) time shift before which pre-existing machines are
-  declared.
+  declared;
+* optionally a standing workload (``standing_tasks``): long-running jobs
+  submitted before the shift, as the real trace opens on a cell that
+  already holds ~140K tasks.
 
 Output: the six GCD tables as CSVs (optionally .gz) so the *parser* is
 exercised end-to-end, plus a ground-truth summary used by tests.
@@ -53,7 +56,8 @@ def generate_trace(out_dir: str, *, n_machines: int = 128, n_jobs: int = 200,
                    seed: int = 0, gz: bool = False,
                    churn_prob: float = 0.002,
                    constraint_prob: float = 0.25,
-                   usage_period_us: int = USAGE_PERIOD_US) -> TraceSummary:
+                   usage_period_us: int = USAGE_PERIOD_US,
+                   standing_tasks: int = 0) -> TraceSummary:
     rng = np.random.default_rng(seed)
     os.makedirs(out_dir, exist_ok=True)
     horizon_us = SHIFT_US + horizon_windows * window_us
@@ -95,61 +99,87 @@ def generate_trace(out_dir: str, *, n_machines: int = 128, n_jobs: int = 200,
     task_rows: List[Tuple] = []
     cons_rows: List[Tuple] = []
     usage_rows: List[Tuple] = []
+
+    def emit_task(rng, job_id, ti, user, sched_class, prio, t_submit,
+                  dur_mu, dur_sigma):
+        cpu_req = float(np.clip(rng.lognormal(-3.2, 0.8), 0.001, 0.5))
+        ram_req = float(np.clip(rng.lognormal(-3.5, 0.9), 0.001, 0.5))
+        disk_req = float(np.clip(rng.lognormal(-6.0, 1.0), 1e-5, 0.2))
+        dur_w = max(1, int(rng.lognormal(dur_mu, dur_sigma)))
+        t0 = t_submit + int(rng.integers(0, 1_000_000))
+        task_rows.append((t0, "", job_id, ti, "", 0, user, sched_class, prio,
+                          cpu_req, ram_req, disk_req, 0))
+        # end event: FINISH (4) mostly; EVICT(2)/FAIL(3)/KILL(5) minority —
+        # "significant parts of the tasks were killed by the native system"
+        end_kind = int(rng.choice([4, 2, 3, 5], p=[.62, .15, .08, .15]))
+        t_end = t0 + dur_w * window_us + int(rng.integers(0, window_us))
+        if t_end < horizon_us:
+            task_rows.append((t_end, "", job_id, ti, "", end_kind, user,
+                              sched_class, prio, cpu_req, ram_req, disk_req,
+                              0))
+        # occasional requirement update while alive (UPDATE_RUNNING=8)
+        if rng.random() < 0.05:
+            t_up = t0 + int(rng.integers(1, max(dur_w, 2))) * window_us
+            if t_up < min(t_end, horizon_us):
+                task_rows.append((t_up, "", job_id, ti, "", 8, user,
+                                  sched_class, prio, cpu_req * 1.5, ram_req,
+                                  disk_req, 0))
+        # constraints
+        if rng.random() < constraint_prob:
+            for _ in range(rng.integers(1, 3)):
+                cons_rows.append((t0, job_id, ti, int(rng.integers(0, 4)),
+                                  f"attr_{int(rng.integers(0, 12))}",
+                                  int(rng.integers(0, 4))))
+        # usage samples every 5-minute GCD period while alive
+        frac = float(np.clip(rng.beta(1.3, 8.0), 0.01, 1.0))  # ~98% waste tail
+        t_u = t0 + usage_period_us
+        while t_u < min(t_end, horizon_us):
+            cpu = cpu_req * frac * float(np.clip(rng.normal(1, .25), .05, 2))
+            ram = ram_req * frac
+            usage_rows.append((
+                t_u - usage_period_us, t_u, job_id, ti, "",
+                cpu, ram, ram * 1.1, ram * 0.05, ram * 0.15, ram * 1.2,
+                float(np.clip(rng.lognormal(-4, 1), 0, .5)),   # disk io time
+                disk_req * frac,
+                cpu * 1.4, 0.01,
+                float(np.clip(rng.normal(1.5, .4), .5, 4)),    # CPI
+                float(np.clip(rng.normal(.03, .01), .001, .2)),  # MAI
+                1.0, 1, cpu))
+            t_u += usage_period_us
+
+    def job_shape(rng):
+        n_t = min(1 + int(rng.pareto(1.2)), 64)          # heavy tail
+        sched_class = int(rng.integers(0, 4))
+        prio = int(rng.choice([0, 1, 2, 4, 8, 9, 10, 11],
+                              p=[.25, .2, .15, .1, .1, .08, .07, .05]))
+        return n_t, sched_class, prio
+
     n_tasks = 0
     for j in range(n_jobs):
         job_id = 6_000_000_000 + j
         arrive_w = int(rng.integers(0, max(horizon_windows - 4, 1)))
         t_submit = SHIFT_US + arrive_w * window_us + int(rng.integers(0, window_us))
-        n_t = min(1 + int(rng.pareto(1.2)), 64)          # heavy tail
-        sched_class = int(rng.integers(0, 4))
-        prio = int(rng.choice([0, 1, 2, 4, 8, 9, 10, 11],
-                              p=[.25, .2, .15, .1, .1, .08, .07, .05]))
+        n_t, sched_class, prio = job_shape(rng)
         for ti in range(n_t):
             n_tasks += 1
-            cpu_req = float(np.clip(rng.lognormal(-3.2, 0.8), 0.001, 0.5))
-            ram_req = float(np.clip(rng.lognormal(-3.5, 0.9), 0.001, 0.5))
-            disk_req = float(np.clip(rng.lognormal(-6.0, 1.0), 1e-5, 0.2))
-            dur_w = max(1, int(rng.lognormal(2.2, 1.1)))
-            t0 = t_submit + int(rng.integers(0, 1_000_000))
-            task_rows.append((t0, "", job_id, ti, "", 0, f"user_{j % 17}",
-                              sched_class, prio, cpu_req, ram_req, disk_req, 0))
-            # end event: FINISH (4) mostly; EVICT(2)/FAIL(3)/KILL(5) minority —
-            # "significant parts of the tasks were killed by the native system"
-            end_kind = int(rng.choice([4, 2, 3, 5], p=[.62, .15, .08, .15]))
-            t_end = t0 + dur_w * window_us + int(rng.integers(0, window_us))
-            if t_end < horizon_us:
-                task_rows.append((t_end, "", job_id, ti, "", end_kind,
-                                  f"user_{j % 17}", sched_class, prio,
-                                  cpu_req, ram_req, disk_req, 0))
-            # occasional requirement update while alive (UPDATE_RUNNING=8)
-            if rng.random() < 0.05:
-                t_up = t0 + int(rng.integers(1, max(dur_w, 2))) * window_us
-                if t_up < min(t_end, horizon_us):
-                    task_rows.append((t_up, "", job_id, ti, "", 8,
-                                      f"user_{j % 17}", sched_class, prio,
-                                      cpu_req * 1.5, ram_req, disk_req, 0))
-            # constraints
-            if rng.random() < constraint_prob:
-                for _ in range(rng.integers(1, 3)):
-                    cons_rows.append((t0, job_id, ti, int(rng.integers(0, 4)),
-                                      f"attr_{int(rng.integers(0, 12))}",
-                                      int(rng.integers(0, 4))))
-            # usage samples every 5-minute GCD period while alive
-            frac = float(np.clip(rng.beta(1.3, 8.0), 0.01, 1.0))  # ~98% waste tail
-            t_u = t0 + usage_period_us
-            while t_u < min(t_end, horizon_us):
-                cpu = cpu_req * frac * float(np.clip(rng.normal(1, .25), .05, 2))
-                ram = ram_req * frac
-                usage_rows.append((
-                    t_u - usage_period_us, t_u, job_id, ti, "",
-                    cpu, ram, ram * 1.1, ram * 0.05, ram * 0.15, ram * 1.2,
-                    float(np.clip(rng.lognormal(-4, 1), 0, .5)),   # disk io time
-                    disk_req * frac,
-                    cpu * 1.4, 0.01,
-                    float(np.clip(rng.normal(1.5, .4), .5, 4)),    # CPI
-                    float(np.clip(rng.normal(.03, .01), .001, .2)),  # MAI
-                    1.0, 1, cpu))
-                t_u += usage_period_us
+            emit_task(rng, job_id, ti, f"user_{j % 17}", sched_class, prio,
+                      t_submit, 2.2, 1.1)
+
+    # the standing workload: long-running jobs submitted before the shift,
+    # which the trace records as it opens (the real cell A holds ~140K
+    # tasks at any time). Its own stream, so the jobs above do not change.
+    srng = np.random.default_rng([seed, 1])
+    n_standing_jobs = 0
+    placed = 0
+    while placed < standing_tasks:
+        job_id = 7_000_000_000 + n_standing_jobs
+        t_submit = int(srng.integers(0, SHIFT_US - 1_000_000))
+        n_t, sched_class, prio = job_shape(srng)
+        for ti in range(min(n_t, standing_tasks - placed)):
+            emit_task(srng, job_id, ti, f"user_{n_standing_jobs % 17}",
+                      sched_class, prio, t_submit, 7.0, 1.0)
+            placed += 1
+        n_standing_jobs += 1
 
     # ---- write tables (GCD v2 column order) ----
     def write(name: str, rows: List[Tuple], tcol: int = 0):
@@ -169,7 +199,8 @@ def generate_trace(out_dir: str, *, n_machines: int = 128, n_jobs: int = 200,
     write("job_events-00000-of-00001.csv", list(job_rows))
 
     return TraceSummary(
-        n_machines=n_machines, n_jobs=n_jobs, n_tasks=n_tasks,
+        n_machines=n_machines, n_jobs=n_jobs + n_standing_jobs,
+        n_tasks=n_tasks + standing_tasks,
         n_task_events=len(task_rows), n_usage_records=len(usage_rows),
         n_machine_events=n_machine_events, horizon_us=horizon_us)
 
@@ -180,6 +211,7 @@ def generate_trace(out_dir: str, *, n_machines: int = 128, n_jobs: int = 200,
 
 PAPER_CELL_MACHINES = 12_500         # cell A node count (paper §II)
 PAPER_JOBS_PER_HOUR = 550            # cell A's ~order-of-magnitude admit rate
+PAPER_CONCURRENT_TASKS = 140_000     # cell A's tasks at any time (paper §II)
 
 
 def generate_paper_scale_trace(out_dir: str, *,

@@ -23,22 +23,6 @@ Axis = Union[str, None, Tuple[str, ...]]
 _state = threading.local()
 
 
-def import_shard_map():
-    """``(shard_map, check_kwargs)`` across JAX versions: the function moved
-    from jax.experimental to the top level, and the replication-check kwarg
-    was renamed check_rep -> check_vma. Every shard_map call site in the
-    repo should go through this one shim."""
-    try:
-        from jax import shard_map              # jax >= 0.7
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    import inspect
-    sig = inspect.signature(shard_map).parameters
-    check_kw = {"check_vma": False} if "check_vma" in sig else \
-        ({"check_rep": False} if "check_rep" in sig else {})
-    return shard_map, check_kw
-
-
 def _current() -> Tuple[Optional[Mesh], Optional[Dict[str, Axis]]]:
     return getattr(_state, "mesh", None), getattr(_state, "rules", None)
 

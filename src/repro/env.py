@@ -1,43 +1,55 @@
-"""Computation-environment configuration: platform selection + XLA flag
-presets, shared by the CLIs (``simulate --platform``, ``whatif
---platform``) and the benchmark drivers.
+"""Computation-environment configuration: platform selection, the persistent
+compile cache and fake host devices, shared by the CLIs (``simulate
+--platform``, ``whatif --platform``), the benchmark scripts and
+``chip_smoke.py``.
 
 All of these only take effect at the very start of a program — before jax
 initialises its backend — so the CLIs call them first thing in ``main()``,
 ahead of any jnp import side effects. The simulator itself is
-platform-agnostic (pure JAX + interpret-mode Pallas on CPU, compiled
-kernels on TPU); these helpers are the one place backend choice lives, and
-the BENCH_* writers record :func:`backend` next to their numbers so runs
-from different platforms never get compared silently.
+platform-agnostic (pure JAX; Pallas kernels compile for a TPU and run in
+the interpreter elsewhere, see ``repro.kernels.by_platform``); these helpers
+are the one place backend choice lives, and the BENCH_* writers record
+:func:`backend` next to their numbers so runs from different platforms
+never get compared silently.
 """
 from __future__ import annotations
 
 import os
+import pathlib
 
 import jax
 
-# flags appended to XLA_FLAGS when a GPU platform is selected — the
-# standard performance set (async collectives + latency-hiding scheduler);
-# harmless on a single device
-GPU_XLA_FLAGS = (
-    "--xla_gpu_triton_gemm_any=True "
-    "--xla_gpu_enable_latency_hiding_scheduler=true "
-    "--xla_gpu_enable_highest_priority_async_stream=true "
-)
+# the checkout's root: src/repro/env.py -> ../..
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[2]
+COMPILE_CACHE_DIR = REPO_ROOT / ".jax_cache"
 
 
 def set_platform(platform: str | None) -> None:
-    """Pin jax to ``'cpu'`` / ``'gpu'`` / ``'tpu'`` (None = jax's default
-    auto-detection). GPU additionally appends the :data:`GPU_XLA_FLAGS`
-    preset to ``XLA_FLAGS``. Call before any computation runs."""
+    """Pin jax to ``'cpu'`` / ``'tpu'`` (None = jax's default
+    auto-detection). Call before any computation runs."""
     if platform is None:
         return
-    if platform not in ("cpu", "gpu", "tpu"):
-        raise ValueError(f"platform {platform!r} not in (cpu, gpu, tpu)")
+    if platform not in ("cpu", "tpu"):
+        raise ValueError(f"platform {platform!r} not in (cpu, tpu)")
     jax.config.update("jax_platform_name", platform)
-    if platform == "gpu":
-        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
-                                   + GPU_XLA_FLAGS).strip()
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps the cache
+    there and nothing is changed. Otherwise the cache goes to a fixed path
+    inside the checkout (``<repo>/.jax_cache``, listed in .gitignore): the
+    path is part of a cache entry's key, so only a directory that never
+    moves is ever read back by the next run. Every compile is cached,
+    however short."""
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def set_host_device_count(n: int) -> None:
@@ -62,6 +74,6 @@ def set_debug_nans(debug: bool = True) -> None:
 
 
 def backend() -> str:
-    """The active jax backend name ('cpu' / 'gpu' / 'tpu') — the key the
-    BENCH_* writers record next to their numbers."""
+    """The active jax backend name ('cpu' / 'tpu') — the key the BENCH_*
+    writers record next to their numbers."""
     return jax.default_backend()

@@ -1,3 +1,42 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the simulator's hot passes, each as kernel.py (the
+Pallas body) + ops.py (padding, layout, dispatch) + ref.py (the pure-jnp
+oracle the tests hold the kernel to).
+
+:func:`by_platform` is the one place that decides how a kernel runs: the
+Mosaic-compiled kernel when the call lowers for a TPU, the Pallas
+interpreter when it lowers for anything else (the CPU test backend). The
+choice is made at lowering time (``lax.platform_dependent``), so a program
+compiled for a TPU can never reach the interpreter, and one process can run
+the same traced function on both its TPU and its CPU devices.
+"""
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+
+
+def by_platform(fn, *args):
+    """``fn(*args, interpret=False)`` when lowered for a TPU, else
+    ``fn(*args, interpret=True)``. ``fn`` may pick its tiling from
+    ``interpret``; both branches must return the same shapes."""
+    return jax.lax.platform_dependent(
+        *args,
+        tpu=functools.partial(fn, interpret=False),
+        default=functools.partial(fn, interpret=True))
+
+
+def vmem_limit(blocks, headroom: int = 4 << 20) -> int:
+    """Scoped-VMEM request for a kernel whose blocks are ``blocks`` — a
+    list of ``(shape, dtype)``. Each block is double-buffered and padded to
+    the (8, 128) native tile; the result is clamped to [16 MiB, 100 MiB]
+    (a v5e core has 128 MiB of VMEM)."""
+    total = 0
+    for shape, dtype in blocks:
+        dims = list(shape) or [1]
+        dims[-1] = -(-dims[-1] // 128) * 128
+        if len(dims) > 1:
+            dims[-2] = -(-dims[-2] // 8) * 8
+        total += 2 * math.prod(dims) * jax.numpy.dtype(dtype).itemsize
+    return int(min(max(total + headroom, 16 << 20), 100 << 20))

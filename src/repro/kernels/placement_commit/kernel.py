@@ -2,37 +2,53 @@
 
 The scheduler finaliser is the one inherently sequential pass in the engine:
 tasks are walked in priority order and each assignment changes the free
-capacity the next task sees. The XLA ``fori_loop`` formulation re-materialises
-the (N, R) free-capacity matrix (and, for best-fit, an (N, R) division) from
-HBM-resident operands on every task. Here the loop runs *inside* one kernel:
+capacity the next task sees. The XLA ``fori_loop`` formulation re-reads the
+(N, R) free-capacity matrix from HBM on every task. Here the loop runs
+inside one kernel:
 
-* grid-steps over task tiles (priority order = row order is preserved — the
-  grid is sequential on TPU, which is exactly what a priority scan needs);
+* the grid steps over task rows in priority order (the TPU grid is
+  sequential, which is exactly what a priority scan needs);
 * the running reservation tally is a revisited output block resident in
-  VMEM across the whole scan (the same accumulation pattern as
-  ``segment_usage``);
+  VMEM across the whole scan;
 * per-task work is vector arithmetic on VMEM-resident blocks: fit mask,
-  (optional) dynamic best-fit re-score, argmax, and a one-row tally update —
-  no HBM round-trips between tasks.
+  (optional) dynamic best-fit re-score, first-index argmax, and a one-node
+  tally update — no HBM round-trips between tasks.
 
-The kernel is **natively batched**: every operand carries a leading lane
-axis ``B`` (the scenario fleet's vmap axis — see ``ops.placement_commit``'s
-``custom_vmap`` rule) and the per-task loop vectorises across lanes inside
-one kernel invocation. The single-trajectory engine is just ``B=1``. This
-matters: the generic Pallas vmap fallback would serialise lanes into extra
-grid steps, where the lane axis really wants to ride the vector units.
+One kernel serves every caller. The preference row of a lane is derived in
+the body from its proposal *family* (``FAM_*``, a static per-lane tuple):
+the base-pass scores themselves (greedy; also the plain commit, whose
+``pref`` is passed as the scores), a node-order ranking computed from a
+per-lane start (first-fit / round-robin), or a pre-evaluated external row
+(opaque proposals). So the (B, P, N) preference tensor of the fused
+families never exists in HBM.
+
+Layout (what ops.py hands in — node columns on the 128-wide lane axis,
+scenario lanes on sublanes, task rows and resource columns on untiled
+leading axes, so every per-task value is a 2-D (B, nodes) tile):
+
+* row operands (P, B|1, Np): scores, ok (i32), ext; req (P, B|1, R);
+  valid (P, B|1, 1) i32;
+* node operands (R, K, B|1, TN): total (dead / padded nodes = -1), denom,
+  reserved0 — the node axis cut into K tiles of TN columns;
+* dyn (B, 1) i32 per-lane dynamic-best-fit flags; start (B|1, 1) i32.
+
+The node axis is either whole (K = 1; any number of task rows per grid
+step, looped in the body) or streamed in K > 1 tiles of TN columns (one
+task row per step, grid (P, K)) with a cross-tile running argmax carried
+in revisited output blocks: tile 0 is adopted unconditionally, later tiles
+only on a STRICT improvement, which preserves the reference's global
+first-index tie-break including the all ``-inf`` row (node 0, placed iff it
+fits). Fit is carried with the score, not re-derived from it.
 
 The assignment semantics are bit-identical to ``ref.placement_commit_ref``
-(the seed finaliser) per lane: same fit epsilon, same score expressions,
-same first-index argmax tie-break, and the tally update writes
-``reserved[n] + add`` for the argmax row even when the task cannot place
-(add = 0), exactly like the reference's ``.at[n].add(add)``.
+per lane: same fit epsilon, same score expressions (the re-score sums its
+R terms in column order, like the reference), the same first-index argmax,
+and the tally update writes ``reserved[n] + add`` for the argmax node even
+when the task cannot place (add = 0), exactly like ``.at[n].add(add)``.
 
 ``mode`` specialises the compiled body: 'static' never computes the dynamic
-re-score, 'dynamic' never reads the preference matrix, and 'both' selects at
-runtime from a per-lane flag — the scenario fleet dispatches schedulers
-per-lane with a *traced* dynamic_bestfit, so the flag must be data, not
-structure.
+re-score, 'dynamic' never reads a preference, and 'both' selects at run
+time from the per-lane flag (the fleet dispatches with a traced flag).
 """
 from __future__ import annotations
 
@@ -41,466 +57,211 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import vmem_limit
 
 NEG_INF = float("-inf")
 
-# Proposal family codes for the fused scheduler pass (``_sched_kernel`` /
-# ``_sched_kernel_tiled``): how a lane's preference row is derived *inside*
-# the kernel, so the (B, P, N) pref tensor never round-trips through HBM.
-# Defined here (not in repro.sched) so the kernel package stays importable
-# without the scheduler registry.
+# Proposal family codes: how a lane's preference row is derived inside the
+# kernel. Defined here (not in repro.sched) so the kernel package stays
+# importable without the scheduler registry.
 FAM_EXTERNAL = 0    # pref comes in via the ``ext`` operand (opaque proposal)
-FAM_SCORES = 1      # pref IS the base-pass score matrix (greedy best-fit)
+FAM_SCORES = 1      # pref IS the score operand (greedy; the plain commit)
 FAM_NODE_ORDER = 2  # pref = -((col - start) % N) — first-fit / round-robin
 
 
-def _kernel(pref_ref, req_ref, ok_ref, valid_ref, total_ref, denom_ref,
-            res0_ref, dyn_ref, node_ref, res_ref, *, mode: str,
-            n_lanes: int):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        res_ref[...] = jnp.broadcast_to(res0_ref[...], res_ref.shape)
-
-    # every operand carries a lane axis of size B — or 1 when the lane is
-    # shared (a vmap over a broadcast operand): the body's arithmetic
-    # broadcasts size-1 lanes for free, which keeps lane-invariant blocks
-    # (the usual case for req/ok/total in a fleet over one workload) from
-    # being copied B times
-    pref = pref_ref[...]                       # (B|1, TP, N) f32
-    req = req_ref[...]                         # (B|1, TP, R) f32
-    ok = ok_ref[...]                           # (B|1, TP, N) bool
-    valid = valid_ref[...]                     # (B|1, TP)    bool
-    total = total_ref[...]                     # (B|1, N, R)  f32, dead = -1
-    denom = denom_ref[...]                     # (B|1, N, R)  f32
-    dyn = dyn_ref[...][:, 0] != 0              # (B|1,) lane flags ('both')
-
-    B = n_lanes
-    _, TP, N = pref.shape
-    R = req.shape[2]
-    lanes = jax.lax.iota(jnp.int32, B)
-
-    def body(j, carry):
-        reserved, node_of = carry
-        req_j = jax.lax.dynamic_slice_in_dim(req, j, 1, 1)    # (B, 1, R)
-        free = total - reserved                               # (B, N, R)
-        fit = (req_j <= free + 1e-9).all(-1) \
-            & jax.lax.dynamic_slice_in_dim(ok, j, 1, 1)[:, 0]   # (B, N)
-        if mode != "static":
-            sc_dyn = -((free - req_j) / denom).sum(-1)        # (B, N)
-        if mode != "dynamic":
-            pref_j = jax.lax.dynamic_slice_in_dim(pref, j, 1, 1)[:, 0]
-        if mode == "both":
-            sc = jnp.where(dyn[:, None], sc_dyn, pref_j)
-            sc = jnp.where(fit, sc, NEG_INF)
-        elif mode == "dynamic":
-            sc = jnp.where(fit, sc_dyn, NEG_INF)
-        else:
-            sc = jnp.where(fit, pref_j, NEG_INF)
-        n = jnp.argmax(sc, axis=-1).astype(jnp.int32)         # (B,)
-        flat = lanes * N + n         # per-lane winner as flat (B*N) indices
-        fit_n = fit.reshape(B * N)[flat]
-        can = fit_n & jax.lax.dynamic_slice_in_dim(valid, j, 1, 1)[:, 0]
-        add = jnp.where(can[:, None], req_j[:, 0, :], 0.0)    # (B, R)
-        # exactly the reference's reserved.at[n].add(add), one row per lane
-        # (flat 1-D scatter: lowers tighter than a 2-D (lane, node) scatter)
-        reserved = reserved.reshape(B * N, R).at[flat].add(add) \
-                           .reshape(B, N, R)
-        node_of = jax.lax.dynamic_update_slice_in_dim(
-            node_of, jnp.where(can, n, -1)[:, None], j, 1)
-        return reserved, node_of
-
-    node_of0 = jnp.full((B, TP), -1, jnp.int32)
-    reserved, node_of = jax.lax.fori_loop(0, TP, body,
-                                          (res_ref[...], node_of0))
-    res_ref[...] = reserved
-    node_ref[...] = node_of
-
-
-def placement_commit_pallas(pref, req, ok, valid, total, denom, reserved0,
-                            dyn, *, n_lanes: int, mode: str = "both",
-                            tile_p: int = 128, interpret: bool = True):
-    """Batched commit over ``n_lanes`` scenario lanes (1 for the
-    single-trajectory engine). Each operand's leading lane axis is either
-    ``n_lanes`` or 1 (lane-shared — kept un-copied). Returns
-    (node_of (n_lanes, P) i32, reserved (n_lanes, N, R) f32) — the final
-    VMEM-resident tally is emitted rather than discarded, so incremental
-    accounting can adopt it as the post-commit node_reserved."""
-    P, N = pref.shape[1], pref.shape[2]
-    R = req.shape[2]
-    assert P % tile_p == 0, (P, tile_p)
-    assert mode in ("static", "dynamic", "both"), mode
-
-    grid = (P // tile_p,)
-    kernel = functools.partial(_kernel, mode=mode, n_lanes=n_lanes)
-
-    def task_spec(x, last):
-        return pl.BlockSpec((x.shape[0], tile_p) + last, lambda i: (0, i)
-                            + (0,) * len(last))
-
-    def node_spec(x):
-        return pl.BlockSpec(x.shape, lambda i: (0,) * x.ndim)
-
-    node_of, reserved = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            task_spec(pref, (N,)),
-            task_spec(req, (R,)),
-            task_spec(ok, (N,)),
-            task_spec(valid, ()),
-            node_spec(total),
-            node_spec(denom),
-            node_spec(reserved0),
-            node_spec(dyn),
-        ],
-        out_specs=(
-            pl.BlockSpec((n_lanes, tile_p), lambda i: (0, i)),
-            pl.BlockSpec((n_lanes, N, R), lambda i: (0, 0, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((n_lanes, P), jnp.int32),
-            jax.ShapeDtypeStruct((n_lanes, N, R), jnp.float32),
-        ),
-        interpret=interpret,
-    )(pref, req, ok, valid, total, denom, reserved0, dyn)
-    return node_of, reserved
-
-
-# ---------------------------------------------------------------------------
-# Fused scheduler pass: proposal derivation + commit in one kernel
-# ---------------------------------------------------------------------------
-
-def _lane_mask(fam, target):
-    """(B,) bool lane mask for ``fam[i] == target``, built from iota
-    compares against Python int literals — Pallas kernels may not capture
-    array constants, so the static tuple is lowered comparison by
-    comparison (B is the lane count, single digits in practice)."""
-    lanes = jax.lax.iota(jnp.int32, len(fam))
-    m = jnp.zeros((len(fam),), jnp.bool_)
+def _lane_mask(fam, target, n_lanes):
+    """(B, 1) bool: lanes whose static family is ``target``."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (n_lanes, 1), 0)
+    m = lanes < 0
     for i, f in enumerate(fam):
         if f == target:
             m = m | (lanes == i)
     return m
 
 
-def _family_pref(scores_j, no_j, ext_j, fam, ext_row):
-    """Derive this task row's preference block per lane from its proposal
-    family (static ``fam`` tuple): scores pass through, node-order prefs are
-    ``no_j`` (computed from the runtime start operand), external lanes gather
-    their pre-evaluated row from ``ext_j`` via the static ``ext_row`` map.
-    Single-family calls collapse to the bare operand (no select), so the
-    all-greedy / all-first-fit fleets pay nothing for the generality."""
-    pref = scores_j
+def _family_pref(scores, no, ext, fam, ext_row, n_lanes):
+    """This task row's (B, TN) preference per lane from its static family.
+    Single-family calls collapse to the bare operand (no select)."""
+    pref = scores
     if any(f == FAM_NODE_ORDER for f in fam):
         if all(f == FAM_NODE_ORDER for f in fam):
-            pref = no_j
+            pref = no
         else:
-            pref = jnp.where(_lane_mask(fam, FAM_NODE_ORDER)[:, None],
-                             no_j, pref)
+            pref = jnp.where(_lane_mask(fam, FAM_NODE_ORDER, n_lanes), no,
+                             pref)
     if any(f == FAM_EXTERNAL for f in fam):
-        lanes = jax.lax.iota(jnp.int32, len(fam))
-        idx = jnp.zeros((len(fam),), jnp.int32)
-        for i, r in enumerate(ext_row):
-            if r:
-                idx = jnp.where(lanes == i, r, idx)
-        sel = jnp.take(ext_j, idx, axis=0)
-        if all(f == FAM_EXTERNAL for f in fam):
-            pref = sel
-        else:
-            pref = jnp.where(_lane_mask(fam, FAM_EXTERNAL)[:, None],
-                             sel, pref)
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (n_lanes, 1), 0)
+        sel = jnp.broadcast_to(pref, (n_lanes, pref.shape[-1]))
+        for i, (f, r) in enumerate(zip(fam, ext_row)):
+            if f == FAM_EXTERNAL:
+                sel = jnp.where(lanes == i, ext[r:r + 1], sel)
+        pref = sel
     return pref
 
 
-def _sched_kernel(scores_ref, req_ref, ok_ref, valid_ref, total_ref,
-                  denom_ref, res0_ref, dyn_ref, start_ref, *rest,
-                  mode: str, n_lanes: int, fam, ext_row, n_real: int):
-    """Fused proposal+commit, whole node dim resident (tile_n off): the
-    commit scan of ``_kernel`` with the preference row derived in-body from
-    ``scores`` + per-lane family params instead of a materialised pref."""
+def _commit_kernel(scores_ref, ok_ref, req_ref, valid_ref, total_ref,
+                   denom_ref, res0_ref, dyn_ref, start_ref, *rest,
+                   mode: str, n_lanes: int, fam, ext_row, n_real: int,
+                   n_tiles: int, n_rows: int):
     has_ext = any(f == FAM_EXTERNAL for f in fam)
-    if has_ext:
-        ext_ref, node_ref, res_ref = rest
+    ext_ref = rest[0] if has_ext else None
+    outs = rest[1:] if has_ext else rest
+    if n_tiles > 1:
+        node_ref, res_ref, bsc_ref, bn_ref, bfit_ref = outs
     else:
-        node_ref, res_ref = rest
-        ext_ref = None
-    i = pl.program_id(0)
+        node_ref, res_ref = outs
+    i, k = pl.program_id(0), pl.program_id(1)
+    B, R = n_lanes, total_ref.shape[0]
+    TN = total_ref.shape[-1]
 
-    @pl.when(i == 0)
+    @pl.when((i == 0) & (k == 0))
     def _init():
         res_ref[...] = jnp.broadcast_to(res0_ref[...], res_ref.shape)
 
-    scores = scores_ref[...]                   # (B|1, TP, Np) f32
-    req = req_ref[...]                         # (B|1, TP, R) f32
-    ok = ok_ref[...]                           # (B|1, TP, Np) bool
-    valid = valid_ref[...]                     # (B|1, TP)    bool
-    total = total_ref[...]                     # (B|1, Np, R) f32, dead = -1
-    denom = denom_ref[...]                     # (B|1, Np, R) f32
-    dyn = dyn_ref[...][:, 0] != 0              # (B|1,) lane flags ('both')
-    start = start_ref[...][:, 0]               # (B|1,) node-order rotations
-    ext = ext_ref[...] if has_ext else None    # (BE, TP, Np) f32
-
-    B = n_lanes
-    _, TP, Np = scores.shape
-    R = req.shape[2]
-    lanes = jax.lax.iota(jnp.int32, B)
-
-    need_no = any(f == FAM_NODE_ORDER for f in fam)
+    lane_col = jax.lax.broadcasted_iota(jnp.int32, (B, TN), 1)
+    tot = [total_ref[r, k] for r in range(R)]                 # (B|1, TN)
+    den = [denom_ref[r, k] for r in range(R)] if mode != "static" else None
+    dyn = dyn_ref[...] != 0                                   # (B, 1)
     no = None
-    if need_no and mode != "dynamic":
-        # node-order preference, shared by every task row of the window:
-        # -((col - start) % N) — first-fit at start=0, round-robin at the
-        # window-rotated start. Padded columns (col >= n_real) produce
-        # garbage that the fit mask (total = -1 there) always rejects.
-        col = jax.lax.iota(jnp.int32, Np)[None, :]
-        no = -(((col - start[:, None]) % n_real).astype(jnp.float32))
+    if mode != "dynamic" and any(f == FAM_NODE_ORDER for f in fam):
+        # node-order preference, shared by every task row: first-fit at
+        # start = 0, round-robin at the window-rotated start. Padded
+        # columns (>= n_real) give garbage the fit mask always rejects.
+        col = k * TN + lane_col
+        no = -(((col - start_ref[...]) % n_real).astype(jnp.float32))
 
-    def body(j, carry):
-        reserved, node_of = carry
-        req_j = jax.lax.dynamic_slice_in_dim(req, j, 1, 1)    # (B, 1, R)
-        free = total - reserved                               # (B, Np, R)
-        fit = (req_j <= free + 1e-9).all(-1) \
-            & jax.lax.dynamic_slice_in_dim(ok, j, 1, 1)[:, 0]   # (B, Np)
+    def choose(t, res):
+        """Tile-local choice for task row t against tally tiles ``res``:
+        (tile best score, tile argmax column, fit there, req columns)."""
+        req_row = req_ref[t]                                  # (B|1, R)
+        rq = [req_row[:, r:r + 1] for r in range(R)]          # (B|1, 1)
+        free = [tot[r] - res[r] for r in range(R)]            # (B, TN)
+        fit = ok_ref[t] != 0
+        for r in range(R):
+            fit = fit & (rq[r] <= free[r] + 1e-9)
+        fit = jnp.broadcast_to(fit, (B, TN))
         if mode != "static":
-            sc_dyn = -((free - req_j) / denom).sum(-1)        # (B, Np)
+            terms = [(free[r] - rq[r]) / den[r] for r in range(R)]
+            sc_dyn = -functools.reduce(jnp.add, terms)
         if mode != "dynamic":
-            scores_j = jax.lax.dynamic_slice_in_dim(scores, j, 1, 1)[:, 0]
-            ext_j = (jax.lax.dynamic_slice_in_dim(ext, j, 1, 1)[:, 0]
-                     if has_ext else None)
-            pref_j = _family_pref(scores_j, no, ext_j, fam, ext_row)
+            pref = _family_pref(scores_ref[t], no,
+                                ext_ref[t] if has_ext else None,
+                                fam, ext_row, B)
         if mode == "both":
-            sc = jnp.where(dyn[:, None], sc_dyn, pref_j)
-            sc = jnp.where(fit, sc, NEG_INF)
+            sc = jnp.where(dyn, sc_dyn, pref)
         elif mode == "dynamic":
-            sc = jnp.where(fit, sc_dyn, NEG_INF)
+            sc = sc_dyn
         else:
-            sc = jnp.where(fit, pref_j, NEG_INF)
-        n = jnp.argmax(sc, axis=-1).astype(jnp.int32)         # (B,)
-        flat = lanes * Np + n
-        fit_n = fit.reshape(B * Np)[flat]
-        can = fit_n & jax.lax.dynamic_slice_in_dim(valid, j, 1, 1)[:, 0]
-        add = jnp.where(can[:, None], req_j[:, 0, :], 0.0)    # (B, R)
-        reserved = reserved.reshape(B * Np, R).at[flat].add(add) \
-                           .reshape(B, Np, R)
-        node_of = jax.lax.dynamic_update_slice_in_dim(
-            node_of, jnp.where(can, n, -1)[:, None], j, 1)
-        return reserved, node_of
+            sc = pref
+        sc = jnp.where(fit, jnp.broadcast_to(sc, (B, TN)), NEG_INF)
+        best = jnp.max(sc, axis=1, keepdims=True)             # (B, 1)
+        loc = jnp.min(jnp.where(sc == best, lane_col, TN), axis=1,
+                      keepdims=True)
+        fit_at = jnp.max(jnp.where(lane_col == loc, fit.astype(jnp.int32), 0),
+                         axis=1, keepdims=True)
+        return best, loc, fit_at, rq
 
-    node_of0 = jnp.full((B, TP), -1, jnp.int32)
-    reserved, node_of = jax.lax.fori_loop(0, TP, body,
-                                          (res_ref[...], node_of0))
-    res_ref[...] = reserved
-    node_ref[...] = node_of
+    def place(t, best_n, best_fit, rq):
+        """Write row t's decision; return the (B, 1) per-column adds."""
+        can = (best_fit != 0) & (valid_ref[t] != 0)
+        node_ref[t] = jnp.where(can, best_n, -1)
+        return [jnp.where(can, rq[r], 0.0) for r in range(R)]
 
+    if n_tiles == 1:
+        def row(t, res):
+            _, loc, fit_at, rq = choose(t, res)
+            add = place(t, loc, fit_at, rq)
+            hit = lane_col == loc
+            return tuple(jnp.where(hit, res[r] + add[r], res[r])
+                         for r in range(R))
 
-def _sched_kernel_tiled(scores_ref, req_ref, ok_ref, valid_ref, total_ref,
-                        denom_ref, res0_ref, dyn_ref, start_ref, *rest,
-                        mode: str, n_lanes: int, fam, ext_row, n_real: int,
-                        tile_n: int):
-    """Node-streaming fused pass: grid (P, N/tile_n), one task row per outer
-    step, score/pref blocks streamed tile-by-tile over the node dim with a
-    cross-tile running argmax carried in revisited output blocks — the full
-    (B, P, N) pref never exists and per-step working blocks are (B, tile_n),
-    which is what holds the pass at the 12.5K-node full cell.
+        res = tuple(jnp.broadcast_to(res_ref[r, 0], (B, TN))
+                    for r in range(R))
+        res = jax.lax.fori_loop(0, n_rows, row, res)
+        for r in range(R):
+            res_ref[r, 0] = res[r]
+        return
 
-    Carry contract (csc = best score, cni = [best node, best fit]): tile 0
-    is adopted unconditionally, later tiles only on a STRICT improvement —
-    preserving the reference's global first-index argmax tie-break, including
-    the all--inf edge where the ref places at node 0 iff fit[0] held (hence
-    fit is carried alongside the score, not re-derived from it). NaN prefs
-    would diverge (NaN never wins a strict compare) — the proposal contract
-    (finite or -inf) already excludes them."""
-    has_ext = any(f == FAM_EXTERNAL for f in fam)
-    if has_ext:
-        ext_ref, node_ref, res_ref, csc_ref, cni_ref = rest
-    else:
-        node_ref, res_ref, csc_ref, cni_ref = rest
-        ext_ref = None
-    j, k = pl.program_id(0), pl.program_id(1)
-    K = pl.num_programs(1)
+    # node-streamed: one task row per grid step, argmax carried over tiles
+    best, loc, fit_at, rq = choose(0, [res_ref[r, k] for r in range(R)])
+    adopt = (k == 0) | (best > bsc_ref[...])
+    best_n = jnp.where(adopt, k * TN + loc, bn_ref[...])
+    best_fit = jnp.where(adopt, fit_at, bfit_ref[...])
+    bsc_ref[...] = jnp.where(adopt, best, bsc_ref[...])
+    bn_ref[...] = best_n
+    bfit_ref[...] = best_fit
+    add = place(0, best_n, best_fit, rq)
 
-    @pl.when((j == 0) & (k == 0))
-    def _init():
-        res_ref[...] = jnp.broadcast_to(res0_ref[...], res_ref.shape)
-
-    B = n_lanes
-    reserved = res_ref[...]                    # (B, Np, R) running tally
-    Np, R = reserved.shape[1], reserved.shape[2]
-    TN = tile_n
-    off = k * TN
-    lanes = jax.lax.iota(jnp.int32, B)
-
-    res_t = jax.lax.dynamic_slice_in_dim(reserved, off, TN, 1)
-    tot_t = jax.lax.dynamic_slice_in_dim(total_ref[...], off, TN, 1)
-    free = tot_t - res_t                       # (B, TN, R)
-    req_j = req_ref[...][:, 0, :]              # (B|1, R)
-    ok_j = ok_ref[...][:, 0, :]                # (B|1, TN)
-    fit = (req_j[:, None, :] <= free + 1e-9).all(-1) & ok_j   # (B, TN)
-    dyn = dyn_ref[...][:, 0] != 0
-    start = start_ref[...][:, 0]
-    if mode != "static":
-        den_t = jax.lax.dynamic_slice_in_dim(denom_ref[...], off, TN, 1)
-        sc_dyn = -((free - req_j[:, None, :]) / den_t).sum(-1)
-    if mode != "dynamic":
-        scores_j = scores_ref[...][:, 0, :]    # (B|1, TN)
-        no = None
-        if any(f == FAM_NODE_ORDER for f in fam):
-            col = (off + jax.lax.iota(jnp.int32, TN))[None, :]
-            no = -(((col - start[:, None]) % n_real).astype(jnp.float32))
-        ext_j = ext_ref[...][:, 0, :] if has_ext else None
-        pref_j = _family_pref(scores_j, no, ext_j, fam, ext_row)
-    if mode == "both":
-        sc = jnp.where(dyn[:, None], sc_dyn, pref_j)
-        sc = jnp.where(fit, sc, NEG_INF)
-    elif mode == "dynamic":
-        sc = jnp.where(fit, sc_dyn, NEG_INF)
-    else:
-        sc = jnp.where(fit, pref_j, NEG_INF)
-    sc = jnp.broadcast_to(sc, (B, TN))
-
-    loc = jnp.argmax(sc, axis=-1).astype(jnp.int32)           # (B,)
-    tile_best = jnp.max(sc, axis=-1)                          # (B,)
-    fit_at = jnp.broadcast_to(fit, (B, TN)).reshape(B * TN)[lanes * TN + loc]
-    glob_n = off + loc
-
-    prev = cni_ref[...]
-    adopt = (k == 0) | (tile_best > csc_ref[...][:, 0])
-    best_sc = jnp.where(adopt, tile_best, csc_ref[...][:, 0])
-    best_n = jnp.where(adopt, glob_n, prev[:, 0])
-    best_fit = jnp.where(adopt, fit_at, prev[:, 1] != 0)
-    csc_ref[...] = best_sc[:, None]
-    cni_ref[...] = jnp.stack([best_n, best_fit.astype(jnp.int32)], axis=1)
-
-    can = best_fit & jnp.broadcast_to(valid_ref[...][:, 0], (B,))
-    node_ref[...] = jnp.where(can, best_n, -1)[:, None]
-
-    @pl.when(k == K - 1)
+    @pl.when(k == n_tiles - 1)
     def _commit():
-        add = jnp.where(can[:, None],
-                        jnp.broadcast_to(req_j, (B, R)), 0.0)
-        flat = lanes * Np + best_n
-        res_ref[...] = reserved.reshape(B * Np, R).at[flat].add(add) \
-                               .reshape(B, Np, R)
+        def tile(kk, carry):
+            hit = kk * TN + lane_col == best_n
+            for r in range(R):
+                cur = res_ref[r, kk]
+                res_ref[r, kk] = jnp.where(hit, cur + add[r], cur)
+            return carry
+
+        jax.lax.fori_loop(0, n_tiles, tile, 0)
 
 
-def _sched_specs(req, valid, total, denom, reserved0, dyn, start,
-                 col_blocked, tile_p, tile_n, col_grid):
-    """Shared in_specs builder for the two fused callers. ``col_blocked``
-    lists the (B|1, P, Np) operands (scores, ok, ext when present) that take
-    a node-column block; ``col_grid`` adds the node-tile grid axis (tiled
-    kernel) to them."""
-    if col_grid:
-        def task_cols(x):
-            return pl.BlockSpec((x.shape[0], tile_p, tile_n),
-                                lambda j, k: (0, j, k))
-
-        def task_spec(x, last):
-            return pl.BlockSpec((x.shape[0], tile_p) + last,
-                                lambda j, k: (0, j) + (0,) * len(last))
-
-        def node_spec(x):
-            return pl.BlockSpec(x.shape, lambda j, k: (0,) * x.ndim)
-    else:
-        def task_cols(x):
-            return pl.BlockSpec((x.shape[0], tile_p, tile_n),
-                                lambda i: (0, i, 0))
-
-        def task_spec(x, last):
-            return pl.BlockSpec((x.shape[0], tile_p) + last,
-                                lambda i: (0, i) + (0,) * len(last))
-
-        def node_spec(x):
-            return pl.BlockSpec(x.shape, lambda i: (0,) * x.ndim)
-
-    scores, ok = col_blocked[0], col_blocked[1]
-    specs = [
-        task_cols(scores),
-        task_spec(req, (req.shape[2],)),
-        task_cols(ok),
-        task_spec(valid, ()),
-        node_spec(total),
-        node_spec(denom),
-        node_spec(reserved0),
-        node_spec(dyn),
-        node_spec(start),
-    ]
-    for extra in col_blocked[2:]:
-        specs.append(task_cols(extra))
-    return specs
-
-
-def sched_commit_pallas(scores, req, ok, valid, total, denom, reserved0,
-                        dyn, start, ext, *, n_lanes: int, fam, ext_row,
-                        n_real: int, mode: str = "both", tile_p: int = 128,
-                        tile_n=None, interpret: bool = True):
-    """Batched fused proposal+commit over ``n_lanes`` lanes.
-
-    scores (B|1, P, Np) base-pass scores; ext (BE, P, Np) pre-evaluated
-    external prefs (None when no lane is FAM_EXTERNAL); start (B|1, 1) i32
-    node-order rotations; fam / ext_row static per-lane tuples (length B, or
-    1 when every lane shares one family); n_real the unpadded node count the
-    node-order modulus uses. ``tile_n=None`` keeps the node dim whole per
-    step (the CPU-interpret default); an int streams (B, tile_n) blocks over
-    a (P, Np/tile_n) grid with a cross-tile argmax carry. Returns
-    (node_of (B, P) i32, reserved (B, Np, R) f32) like
-    ``placement_commit_pallas`` — bitwise-identical to composing the
-    family's proposal with ``placement_commit_ref``."""
-    P, Np = scores.shape[1], scores.shape[2]
-    R = req.shape[2]
+def commit_pallas(scores, ok, req, valid, total, denom, reserved0, dyn,
+                  start, ext, *, n_lanes: int, fam, ext_row, n_real: int,
+                  mode: str, tile_p: int, interpret: bool):
+    """Batched commit over ``n_lanes`` lanes in the kernel layout (module
+    doc). The node tiling is read off ``total`` (R, K, B|1, TN); K > 1
+    needs ``tile_p == 1``. Returns (node_of (P, B, 1) i32, reserved
+    (R, K, B, TN) f32 — the final VMEM-resident tally)."""
+    P, Np = scores.shape[0], scores.shape[2]
+    R, K, _, TN = total.shape
     assert mode in ("static", "dynamic", "both"), mode
     assert len(fam) in (1, n_lanes), (len(fam), n_lanes)
+    assert P % tile_p == 0 and K * TN == Np, (P, tile_p, K, TN, Np)
+    assert K == 1 or tile_p == 1, (K, tile_p)
+    B = n_lanes
 
-    operands = [scores, req, ok, valid, total, denom, reserved0, dyn, start]
+    def row_spec(x):
+        return pl.BlockSpec((tile_p,) + x.shape[1:-1] + (TN,),
+                            lambda i, k: (i, 0, k))
+
+    def whole(x):
+        return pl.BlockSpec(x.shape, lambda i, k: (0,) * x.ndim)
+
+    operands = [scores, ok, req, valid, total, denom, reserved0, dyn, start]
+    in_specs = [row_spec(scores), row_spec(ok),
+                pl.BlockSpec((tile_p,) + req.shape[1:],
+                             lambda i, k: (i, 0, 0)),
+                pl.BlockSpec((tile_p,) + valid.shape[1:],
+                             lambda i, k: (i, 0, 0)),
+                whole(total), whole(denom), whole(reserved0), whole(dyn),
+                whole(start)]
     if ext is not None:
         operands.append(ext)
+        in_specs.append(row_spec(ext))
+    out_shape = [jax.ShapeDtypeStruct((P, B, 1), jnp.int32),
+                 jax.ShapeDtypeStruct((R, K, B, TN), jnp.float32)]
+    out_specs = [pl.BlockSpec((tile_p, B, 1), lambda i, k: (i, 0, 0)),
+                 pl.BlockSpec((R, K, B, TN), lambda i, k: (0, 0, 0, 0))]
+    if K > 1:
+        for dt in (jnp.float32, jnp.int32, jnp.int32):
+            out_shape.append(jax.ShapeDtypeStruct((B, 1), dt))
+            out_specs.append(pl.BlockSpec((B, 1), lambda i, k: (0, 0)))
 
-    col_blocked = [scores, ok] + ([ext] if ext is not None else [])
-
-    if tile_n is None or tile_n >= Np:
-        assert P % tile_p == 0, (P, tile_p)
-        kernel = functools.partial(_sched_kernel, mode=mode, n_lanes=n_lanes,
-                                   fam=fam, ext_row=ext_row, n_real=n_real)
-        node_of, reserved = pl.pallas_call(
-            kernel,
-            grid=(P // tile_p,),
-            in_specs=_sched_specs(req, valid, total, denom, reserved0, dyn,
-                                  start, col_blocked, tile_p, Np,
-                                  col_grid=False),
-            out_specs=(
-                pl.BlockSpec((n_lanes, tile_p), lambda i: (0, i)),
-                pl.BlockSpec((n_lanes, Np, R), lambda i: (0, 0, 0)),
-            ),
-            out_shape=(
-                jax.ShapeDtypeStruct((n_lanes, P), jnp.int32),
-                jax.ShapeDtypeStruct((n_lanes, Np, R), jnp.float32),
-            ),
-            interpret=interpret,
-        )(*operands)
-        return node_of, reserved
-
-    assert Np % tile_n == 0, (Np, tile_n)
-    kernel = functools.partial(_sched_kernel_tiled, mode=mode,
-                               n_lanes=n_lanes, fam=fam, ext_row=ext_row,
-                               n_real=n_real, tile_n=tile_n)
-    node_of, reserved, _csc, _cni = pl.pallas_call(
+    blocks = [(s.block_shape, x.dtype) for s, x in zip(in_specs, operands)]
+    blocks += [(s.block_shape, o.dtype) for s, o in zip(out_specs, out_shape)]
+    kernel = functools.partial(_commit_kernel, mode=mode, n_lanes=B, fam=fam,
+                               ext_row=ext_row, n_real=n_real, n_tiles=K,
+                               n_rows=tile_p)
+    out = pl.pallas_call(
         kernel,
-        grid=(P, Np // tile_n),
-        in_specs=_sched_specs(req, valid, total, denom, reserved0, dyn,
-                              start, col_blocked, 1, tile_n, col_grid=True),
-        out_specs=(
-            pl.BlockSpec((n_lanes, 1), lambda j, k: (0, j)),
-            pl.BlockSpec((n_lanes, Np, R), lambda j, k: (0, 0, 0)),
-            pl.BlockSpec((n_lanes, 1), lambda j, k: (0, 0)),
-            pl.BlockSpec((n_lanes, 2), lambda j, k: (0, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((n_lanes, P), jnp.int32),
-            jax.ShapeDtypeStruct((n_lanes, Np, R), jnp.float32),
-            jax.ShapeDtypeStruct((n_lanes, 1), jnp.float32),
-            jax.ShapeDtypeStruct((n_lanes, 2), jnp.int32),
-        ),
+        grid=(P // tile_p, K),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem_limit(blocks)),
         interpret=interpret,
     )(*operands)
-    return node_of, reserved
+    return out[0], out[1]

@@ -1,7 +1,15 @@
-"""jit-able wrapper for the placement-commit kernel: padding, dtype folding,
-static/dynamic/both mode selection, kernel/ref dispatch — and the
-``custom_vmap`` rule that makes the scenario fleet's lane axis ride ONE
-batched kernel invocation instead of Pallas's serialising vmap fallback."""
+"""jit-able wrappers for the placement-commit kernel: padding, the kernel
+layout (see kernel.py), static/dynamic/both mode selection, kernel/ref
+dispatch — and the ``custom_vmap`` rule that makes the scenario fleet's
+lane axis ride ONE batched kernel invocation instead of Pallas's
+serialising vmap fallback.
+
+Whether the kernel runs compiled or interpreted, and the tiling that goes
+with it, is decided by :func:`repro.kernels.by_platform` from the platform
+the call lowers for: on a TPU the node axis is padded to the 128-wide lane
+tile and task rows go 8 (or, for wide fleets, 1) per grid step; under the
+CPU interpreter the whole batch is one grid step and nothing is padded.
+"""
 from __future__ import annotations
 
 import functools
@@ -11,13 +19,16 @@ import jax
 import jax.numpy as jnp
 from jax.custom_batching import custom_vmap
 
+from repro.kernels import by_platform
 from repro.kernels.placement_commit.kernel import (FAM_EXTERNAL,
                                                    FAM_NODE_ORDER,
                                                    FAM_SCORES,
-                                                   placement_commit_pallas,
-                                                   sched_commit_pallas)
+                                                   commit_pallas)
 from repro.kernels.placement_commit.ref import (placement_commit_ref,
                                                 sched_pref_ref)
+
+__all__ = ["FAM_EXTERNAL", "FAM_NODE_ORDER", "FAM_SCORES",
+           "placement_commit", "sched_pass", "sched_commit_fleet"]
 
 
 def _pad_to(x: jax.Array, n: int, axis: int, fill=0):
@@ -29,118 +40,99 @@ def _pad_to(x: jax.Array, n: int, axis: int, fill=0):
     return jnp.pad(x, widths, constant_values=fill)
 
 
+def _commit_batched(n_lanes, scores, ok, req, valid, total, denom, res0, dyn,
+                    start, ext, *, fam, ext_row, mode, tile_p, tile_n):
+    """Lane-batched commit. Operands carry a leading lane axis of B or 1:
+    scores/ok/ext (B|1, P, N), req (B|1, P, R), valid (B|1, P), total/
+    denom/res0 (B|1, N, R), dyn (B, 1) i32, start (B|1, 1) i32. ``tile_n``
+    < N streams the node axis in tiles. Returns (node_of (B, P) i32,
+    reserved (B, N, R) f32)."""
+    P, N = scores.shape[1], scores.shape[2]
+    stream = tile_n is not None and tile_n < N
+
+    def run(scores, ok, req, valid, total, denom, res0, dyn, start, ext, *,
+            interpret):
+        if stream:
+            tn = tile_n if interpret else -(-tile_n // 128) * 128
+            tp = 1
+        else:
+            tn = N if interpret else -(-N // 128) * 128
+            tp = tile_p or (P if interpret else (8 if n_lanes <= 8 else 1))
+            tp = min(tp, P)
+        Pp = -(-P // tp) * tp
+        Np = -(-N // tn) * tn
+
+        def rows(x, fill=0):          # (B|1, P, N) -> (Pp, B|1, Np)
+            return _pad_to(_pad_to(x, Pp, 1), Np, 2, fill).transpose(1, 0, 2)
+
+        def nodes(x, fill):           # (B|1, N, R) -> (R, K, B|1, TN)
+            x = _pad_to(x, Np, 1, fill)
+            b, _, r = x.shape
+            return x.reshape(b, Np // tn, tn, r).transpose(3, 1, 0, 2)
+
+        node_of, reserved = commit_pallas(
+            rows(scores), rows(ok.astype(jnp.int32)),
+            _pad_to(req, Pp, 1).transpose(1, 0, 2),
+            _pad_to(valid.astype(jnp.int32), Pp, 1).T[:, :, None],
+            nodes(total, -1.0),       # padded nodes can never fit
+            nodes(denom, 1.0),        # keep the re-score finite
+            nodes(res0, 0.0), dyn, start,
+            None if ext is None else rows(ext),
+            n_lanes=n_lanes, fam=fam, ext_row=ext_row, n_real=N, mode=mode,
+            tile_p=tp, interpret=interpret)
+        R = reserved.shape[0]
+        reserved = reserved.transpose(2, 1, 3, 0).reshape(n_lanes, Np, R)
+        return node_of[:P, :, 0].T, reserved[:, :N]
+
+    return by_platform(run, scores, ok, req, valid, total, denom, res0, dyn,
+                       start, ext)
+
+
+def _mode_and_flag(dynamic_bestfit):
+    """Kernel mode + (1,) i32 flag operand for a static or traced
+    ``dynamic_bestfit``: static flags specialise the kernel body."""
+    if isinstance(dynamic_bestfit, jax.Array):
+        return "both", dynamic_bestfit.astype(jnp.int32).reshape(1)
+    mode = "dynamic" if dynamic_bestfit else "static"
+    return mode, jnp.full((1,), int(bool(dynamic_bestfit)), jnp.int32)
+
+
 @functools.lru_cache(maxsize=None)
-def _make_commit(mode: str, tile_p: Optional[int], tile_n: int,
-                 interpret: bool):
-    """Build the (cached) kernel entry for one static configuration.
+def _make_single(family: int, mode: str, tile_p: Optional[int],
+                 tile_n: Optional[int]):
+    """Cached ``custom_vmap`` entry for one family: the primal path runs the
+    batched kernel at B=1; the vmap rule keeps unbatched (lane-shared)
+    operands at a size-1 lane axis and runs the SAME kernel with the real
+    lane axis inside the block."""
+    fam, ext_row = (family,), (0,)
 
-    The primal path runs the batched kernel at B=1; the ``custom_vmap`` rule
-    broadcasts any unbatched operand and runs the SAME kernel with the real
-    lane axis inside the block, so vmapped commits (the scenario fleet)
-    vectorise across lanes instead of being serialised into grid steps.
-    """
-
-    def call_batched(n_lanes, pref, req, ok, valid, total, denom, res0, dyn):
-        P, N = pref.shape[1], pref.shape[2]
-        tp = min(tile_p or (P if interpret else 128), P)
-        Pp = ((P + tp - 1) // tp) * tp
-        tn = min(tile_n, N)
-        Np = ((N + tn - 1) // tn) * tn
-        node_of, reserved = placement_commit_pallas(
-            _pad_to(_pad_to(pref, Pp, 1), Np, 2),
-            _pad_to(req, Pp, 1),
-            _pad_to(_pad_to(ok, Pp, 1), Np, 2),
-            _pad_to(valid, Pp, 1),
-            _pad_to(total, Np, 1, fill=-1.0),  # padded nodes can never fit
-            _pad_to(denom, Np, 1, fill=1.0),   # keep the re-score finite
-            _pad_to(res0, Np, 1),
-            dyn, n_lanes=n_lanes, mode=mode, tile_p=tp, interpret=interpret)
-        return node_of[:, :P], reserved[:, :N]
+    def call(n_lanes, scores, req, ok, valid, total, denom, res0, dyn,
+             start):
+        dyn = jnp.broadcast_to(dyn.reshape(-1, 1), (n_lanes, 1))
+        return _commit_batched(
+            n_lanes, scores, ok, req, valid, total, denom, res0, dyn,
+            start.reshape(-1, 1), None, fam=fam, ext_row=ext_row, mode=mode,
+            tile_p=tile_p, tile_n=tile_n)
 
     @custom_vmap
-    def commit(pref, req, ok, valid, total, denom, res0, dyn):
-        args = (pref, req, ok, valid, total, denom, res0, dyn)
-        node_of, reserved = call_batched(1, *(x[None] for x in args))
+    def commit(scores, req, ok, valid, total, denom, res0, dyn, start):
+        args = (scores, req, ok, valid, total, denom, res0, dyn, start)
+        node_of, reserved = call(1, *(x[None] for x in args))
         return node_of[0], reserved[0]
 
     @commit.def_vmap
     def _batched_rule(axis_size, in_batched, *args):
-        # unbatched (lane-shared) operands keep a size-1 lane axis — the
-        # kernel broadcasts them instead of materialising B copies
         lanes = [x if b else x[None] for x, b in zip(args, in_batched)]
-        return call_batched(axis_size, *lanes), (True, True)
+        return call(axis_size, *lanes), (True, True)
 
     return commit
-
-
-def _sched_call_batched(n_lanes, scores, req, ok, valid, total, denom, res0,
-                        dyn, start, ext, *, fam, ext_row, mode, tile_p,
-                        tile_n, interpret):
-    """Pad + call the fused scheduler kernel; slices padding back off.
-
-    ``tile_n`` < N selects the node-streaming tiled kernel (one task row per
-    grid step, cross-tile argmax carry); otherwise the node dim stays whole
-    per step and ``tile_n`` only sets the TPU lane-alignment padding.
-    ``n_real`` = the unpadded node count keeps the node-order modulus
-    honest in the padded geometry."""
-    P, N = scores.shape[1], scores.shape[2]
-    stream = tile_n is not None and tile_n < N
-    if stream:
-        tp, tn, Pp = 1, tile_n, P
-    else:
-        tp = min(tile_p or (P if interpret else 128), P)
-        Pp = ((P + tp - 1) // tp) * tp
-        tn = min(tile_n or 128, N)
-    Np = ((N + tn - 1) // tn) * tn
-    node_of, reserved = sched_commit_pallas(
-        _pad_to(_pad_to(scores, Pp, 1), Np, 2),
-        _pad_to(req, Pp, 1),
-        _pad_to(_pad_to(ok, Pp, 1), Np, 2),
-        _pad_to(valid, Pp, 1),
-        _pad_to(total, Np, 1, fill=-1.0),  # padded nodes can never fit
-        _pad_to(denom, Np, 1, fill=1.0),   # keep the re-score finite
-        _pad_to(res0, Np, 1),
-        dyn, start,
-        None if ext is None else _pad_to(_pad_to(ext, Pp, 1), Np, 2),
-        n_lanes=n_lanes, fam=fam, ext_row=ext_row, n_real=N, mode=mode,
-        tile_p=tp, tile_n=(tn if stream else None), interpret=interpret)
-    return node_of[:, :P], reserved[:, :N]
-
-
-@functools.lru_cache(maxsize=None)
-def _make_sched(family: int, mode: str, tile_p: Optional[int],
-                tile_n: Optional[int], interpret: bool):
-    """Cached ``custom_vmap`` entry for the single-family fused pass (the
-    mixed-family fleet goes through :func:`sched_commit_fleet`, which is
-    natively batched and needs no vmap rule)."""
-    fam, ext_row = (family,), (0,)
-
-    def call_batched(n_lanes, scores, req, ok, valid, total, denom, res0,
-                     dyn, start):
-        return _sched_call_batched(
-            n_lanes, scores, req, ok, valid, total, denom, res0, dyn, start,
-            None, fam=fam, ext_row=ext_row, mode=mode, tile_p=tile_p,
-            tile_n=tile_n, interpret=interpret)
-
-    @custom_vmap
-    def sched(scores, req, ok, valid, total, denom, res0, dyn, start):
-        args = (scores, req, ok, valid, total, denom, res0, dyn, start)
-        node_of, reserved = call_batched(1, *(x[None] for x in args))
-        return node_of[0], reserved[0]
-
-    @sched.def_vmap
-    def _batched_rule(axis_size, in_batched, *args):
-        lanes = [x if b else x[None] for x, b in zip(args, in_batched)]
-        return call_batched(axis_size, *lanes), (True, True)
-
-    return sched
 
 
 def sched_pass(scores, req, base_ok, valid, total, denom, reserved0,
                dynamic_bestfit=False, *, family: int = FAM_SCORES,
                start=0, ext=None, use_kernel: bool = False,
-               interpret: bool = True, tile_p: Optional[int] = None,
-               tile_n: Optional[int] = None, return_tally: bool = False):
+               tile_p: Optional[int] = None, tile_n: Optional[int] = None,
+               return_tally: bool = False):
     """Fused proposal+commit for ONE proposal family: derive the preference
     matrix from the base-pass ``scores`` + family params (``kernel.FAM_*``)
     and run the capacity-checked commit without materialising pref in HBM.
@@ -150,33 +142,25 @@ def sched_pass(scores, req, base_ok, valid, total, denom, reserved0,
     (greedy), FAM_NODE_ORDER ranks by ``-((col - start) % N)`` (first-fit /
     round-robin; ``start`` may be a traced scalar — the window rotation),
     FAM_EXTERNAL takes the pre-evaluated ``ext`` (opaque proposal — the
-    commit still kernelises, the derivation cannot). ``tile_n`` streams
-    node-dim tiles through the commit (see ``placement_commit``'s
-    ``stream_n``). Kernel and ref are bitwise-identical; the kernel path
-    vmaps through a ``custom_vmap`` rule like the plain commit."""
+    commit still kernelises, the derivation cannot). ``tile_n`` < N
+    streams the node axis in tiles. Kernel and ref are bitwise-identical;
+    the kernel path vmaps through a ``custom_vmap`` rule."""
     if not use_kernel or family == FAM_EXTERNAL:
         pref = sched_pref_ref(scores, start, family, ext)
         return placement_commit(pref, req, base_ok, valid, total, denom,
                                 reserved0, dynamic_bestfit,
-                                use_kernel=use_kernel, interpret=interpret,
-                                tile_p=tile_p, stream_n=tile_n,
-                                return_tally=return_tally)
-    if isinstance(dynamic_bestfit, jax.Array):
-        mode = "both"
-        dyn = dynamic_bestfit.astype(jnp.int32).reshape(1)
-    else:
-        mode = "dynamic" if dynamic_bestfit else "static"
-        dyn = jnp.full((1,), int(bool(dynamic_bestfit)), jnp.int32)
-    start_arr = jnp.asarray(start, jnp.int32).reshape(1)
-    sched = _make_sched(family, mode, tile_p, tile_n, interpret)
-    out = sched(scores, req, base_ok, valid, total, denom, reserved0, dyn,
-                start_arr)
+                                use_kernel=use_kernel, tile_p=tile_p,
+                                stream_n=tile_n, return_tally=return_tally)
+    mode, dyn = _mode_and_flag(dynamic_bestfit)
+    out = _make_single(family, mode, tile_p, tile_n)(
+        scores, req, base_ok, valid, total, denom, reserved0, dyn,
+        jnp.asarray(start, jnp.int32).reshape(1))
     return out if return_tally else out[0]
 
 
 def sched_commit_fleet(scores, ok, req, valid, total, denom, reserved0,
                        start, *, fam, dynamic, ext=None, ext_row=None,
-                       interpret: bool = True, tile_p: Optional[int] = None,
+                       tile_p: Optional[int] = None,
                        tile_n: Optional[int] = None):
     """Mixed-family fused pass for the switchless scenario fleet — natively
     batched (every operand already carries the lane axis B).
@@ -199,17 +183,16 @@ def sched_commit_fleet(scores, ok, req, valid, total, denom, reserved0,
     dyn = jnp.asarray([int(d) for d in dynamic], jnp.int32)[:, None]
     if ext_row is None:
         ext_row = (0,) * len(fam)
-    return _sched_call_batched(
-        B, scores, req, ok, valid, total, denom, reserved0, dyn,
+    return _commit_batched(
+        B, scores, ok, req, valid, total, denom, reserved0, dyn,
         start.astype(jnp.int32)[:, None], ext, fam=tuple(fam),
-        ext_row=tuple(ext_row), mode=mode, tile_p=tile_p, tile_n=tile_n,
-        interpret=interpret)
+        ext_row=tuple(ext_row), mode=mode, tile_p=tile_p, tile_n=tile_n)
 
 
 def placement_commit(pref, req, base_ok, valid, total, denom, reserved0,
                      dynamic_bestfit=False, *, use_kernel: bool = False,
-                     interpret: bool = True, tile_p: Optional[int] = None,
-                     tile_n: int = 128, stream_n: Optional[int] = None,
+                     tile_p: Optional[int] = None,
+                     stream_n: Optional[int] = None,
                      return_tally: bool = False):
     """Sequential capacity-checked assignment in priority (row) order.
 
@@ -222,45 +205,29 @@ def placement_commit(pref, req, base_ok, valid, total, denom, reserved0,
     request) — the kernel holds it resident across grid steps anyway, and
     incremental accounting (engine/sched) adopts it as the post-commit
     node_reserved instead of re-deriving it with a segment-sum. Bit-identical
-    between the Pallas kernel (TPU target; interpret=True on CPU) and the
-    pure-jnp reference — the engine invariant (no overcommit) is enforced by
-    both. ``dynamic_bestfit`` may be a traced bool scalar (per-lane scheduler
-    dispatch in the scenario fleet); static True/False specialise the kernel
-    to skip the unused score path.
+    between the Pallas kernel and the pure-jnp reference — the engine
+    invariant (no overcommit) is enforced by both. ``dynamic_bestfit`` may
+    be a traced bool scalar (per-lane scheduler dispatch in the scenario
+    fleet); static True/False specialise the kernel to skip the unused
+    score path.
 
-    Under ``jax.vmap`` the kernel path dispatches through a ``custom_vmap``
-    rule to one natively-batched kernel call (lane axis inside the block) —
-    Pallas's default batching would serialise lanes into extra grid steps.
+    The kernel is the FAM_SCORES commit with ``pref`` as its scores.
+    ``stream_n`` < N streams the node axis in tiles of that many columns
+    with a cross-tile argmax carry (the full-cell path); ``tile_p`` sets the
+    task rows per grid step (default: see the module doc). Under
+    ``jax.vmap`` the kernel path dispatches through a ``custom_vmap`` rule
+    to one natively-batched kernel call.
 
     Not jit-wrapped here: every caller (engine scan, scenario fleet, tests)
     already traces it, and a jit boundary would force the static/traced
     distinction of ``dynamic_bestfit`` into the signature.
-
-    ``tile_p=None`` picks the default task tile: the whole batch under
-    ``interpret`` (CPU — there is no VMEM budget and each grid step costs a
-    trip through the interpreter loop) and 128 rows on a real TPU (keeps the
-    per-step pref block comfortably inside VMEM at cell-A node counts).
     """
     if not use_kernel:
         out = placement_commit_ref(pref, req, base_ok, valid, total, denom,
                                    reserved0, dynamic_bestfit)
     else:
-        if isinstance(dynamic_bestfit, jax.Array):
-            mode = "both"
-            dyn = dynamic_bestfit.astype(jnp.int32).reshape(1)
-        else:
-            mode = "dynamic" if dynamic_bestfit else "static"
-            dyn = jnp.full((1,), int(bool(dynamic_bestfit)), jnp.int32)
-        if stream_n is not None and stream_n < pref.shape[-1]:
-            # node-streaming commit: FAM_SCORES with pref as the score
-            # matrix IS the plain commit, tiled over node blocks with a
-            # cross-tile argmax carry (the full-cell N=12,500 path)
-            sched = _make_sched(FAM_SCORES, mode, tile_p, stream_n,
-                                interpret)
-            out = sched(pref, req, base_ok, valid, total, denom, reserved0,
-                        dyn, jnp.zeros((1,), jnp.int32))
-        else:
-            commit = _make_commit(mode, tile_p, tile_n, interpret)
-            out = commit(pref, req, base_ok, valid, total, denom, reserved0,
-                         dyn)
+        mode, dyn = _mode_and_flag(dynamic_bestfit)
+        out = _make_single(FAM_SCORES, mode, tile_p, stream_n)(
+            pref, req, base_ok, valid, total, denom, reserved0, dyn,
+            jnp.zeros((1,), jnp.int32))
     return out if return_tally else out[0]

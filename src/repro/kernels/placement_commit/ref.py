@@ -13,6 +13,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.ordered import sum_columns
+
 NEG = -jnp.inf
 
 
@@ -39,7 +41,7 @@ def placement_commit_ref(pref: jax.Array, req: jax.Array, base_ok: jax.Array,
         free = total - reserved                                 # (N, R)
         fit = (req[i][None, :] <= free + 1e-9).all(-1) & base_ok[i]
         if is_traced or dynamic_bestfit:
-            sc_dyn = -((free - req[i][None, :]) / denom).sum(-1)
+            sc_dyn = -sum_columns((free - req[i][None, :]) / denom)
         if is_traced:
             sc = jnp.where(dynamic_bestfit, sc_dyn, pref[i])
             sc = jnp.where(fit, sc, NEG)

@@ -1,21 +1,19 @@
-"""Pallas TPU kernel: every window-stats reduction in ONE task-table sweep.
+"""Pallas TPU kernel: every task-table stats reduction in ONE sweep.
 
 After PR 4 removed the accounting recomputes, ``window_stats`` was the
 engine's last O(max_tasks) consumer per window: ~6 independent full passes
 (running/pending masks and counts, the masked usage-mean sum, the
-per-priority scatter) plus the node-table spread reductions, ×B in the
-scenario fleet.  Here the task table is grid-stepped ONCE:
+per-priority scatter), ×B in the scenario fleet. Here the task table is
+grid-stepped ONCE: each grid step loads one task tile and accumulates — in
+revisited output blocks resident in VMEM across the whole sweep (the
+``segment_usage`` accumulation pattern) — the masked usage sum and the
+per-priority running/pending populations (masked lane reductions; the
+counts are their row sums).
 
-* each grid step loads one task tile and accumulates — in revisited output
-  blocks resident in VMEM across the whole sweep (the ``segment_usage``
-  accumulation pattern) — the running/pending counts, the masked usage sum,
-  and the (12, 2) per-priority population (one-hot compare against the
-  priority iota, reduced over the tile);
-* the small node-table pass (active capacity, reserved/used sums, both
-  utilisation-spread variances) is fused into the same kernel: the node
-  blocks are VMEM-resident with constant index maps, and grid step 0
-  computes all of them in one shot — no second kernel launch, no extra HBM
-  round-trip.
+The small node-table pass (capacity, tallies, balance variances) is not
+here: it is ``ref.node_reductions_ref`` on every path, so its float sums —
+whose order a kernel's lane reduction would change — are the same bits with
+and without kernels.
 
 The kernel is **natively batched** exactly like ``placement_commit``: every
 operand carries a leading lane axis of size B or 1 (lane-shared), the
@@ -23,10 +21,13 @@ per-tile arithmetic broadcasts across lanes on the vector units, and the
 ``custom_vmap`` rule in ops.py routes the scenario fleet's vmap into one
 kernel invocation instead of Pallas's serialising fallback.
 
-Integer outputs (counts, histogram) are exact, and the float expressions
-mirror ``ref.window_reductions_ref`` term for term, so on grid-aligned data
-the kernel is bitwise identical to the jnp reference (the equivalence
-suite's bar); on real traces only summation order differs.
+Layout: task columns sit on the 128-wide lane axis — state/prio (B|1, T)
+i32, usage (B|1, U, T) — so every reduction is a lane reduction of a 2-D
+(lanes, columns) tile.
+
+Integer outputs (counts, histogram) are exact. The usage sum accumulates
+tile by tile, so it can differ from ``ref.window_reductions_ref`` in the
+last bits (summation order); on grid-aligned data it is exact and equal.
 """
 from __future__ import annotations
 
@@ -35,144 +36,82 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.state import TASK_PENDING, TASK_RUNNING
+from repro.kernels import vmem_limit
 from repro.kernels.window_stats.ref import N_PRIO
 
 
-def _kernel(state_ref, usage_ref, prio_ref, active_ref, total_ref, resv_ref,
-            used_ref, counts_ref, hist_ref, usum_ref, node_ref, *,
+def _columns(parts, n_lanes):
+    """Assemble (B|1, 1) columns into one (B, len(parts)) block."""
+    col = jax.lax.broadcasted_iota(jnp.int32, (n_lanes, len(parts)), 1)
+    out = jnp.zeros((n_lanes, len(parts)), parts[0].dtype)
+    for j, p in enumerate(parts):
+        out = jnp.where(col == j, p, out)
+    return out
+
+
+def _kernel(state_ref, usage_ref, prio_ref, hist_ref, usum_ref, *,
             n_lanes: int):
     i = pl.program_id(0)
+    B = n_lanes
 
     @pl.when(i == 0)
     def _init():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
         hist_ref[...] = jnp.zeros_like(hist_ref)
         usum_ref[...] = jnp.zeros_like(usum_ref)
 
+    # --- task tile: one load, every accumulator updated -------------------
+    state = state_ref[...]                             # (B|1, TT) i32
+    prio = jnp.clip(prio_ref[...], 0, N_PRIO - 1)
+    running = state == TASK_RUNNING
+    pending = state == TASK_PENDING
+    for c, mask in enumerate((running, pending)):
+        m = mask.astype(jnp.int32)
+        per_prio = [jnp.sum(jnp.where(prio == p, m, 0), axis=1, keepdims=True)
+                    for p in range(N_PRIO)]
+        hist_ref[c] += _columns(per_prio, B)           # (B, 12)
+    n_use = usage_ref.shape[1]
+    usum = [jnp.sum(jnp.where(running, usage_ref[:, u, :], 0.0), axis=1,
+                    keepdims=True) for u in range(n_use)]
+    usum_ref[...] += _columns(usum, B)                 # (B, U)
+
+
+def window_stats_pallas(task_state, task_usage, task_prio, *, n_lanes: int,
+                        tile_t: int, interpret: bool):
+    """Fused task-table reductions over ``n_lanes`` scenario lanes (1 for
+    the single-trajectory engine), in the layout of the module doc; each
+    operand's lane axis is ``n_lanes`` or 1 (lane-shared, kept un-copied).
+    Returns (by_prio (2, B, 12) i32 = [running, pending] populations,
+    usage_sum (B, U) f32)."""
+    T = task_state.shape[1]
+    U = task_usage.shape[1]
+    assert T % tile_t == 0, (T, tile_t)
     B = n_lanes
 
-    # --- task tile: one load, every accumulator updated -------------------
-    task_state = state_ref[...]                        # (B|1, TT) i8
-    prio = prio_ref[...]                               # (B|1, TT) i32
-    usage = usage_ref[...]                             # (B|1, TT, U) f32
-    running = task_state == TASK_RUNNING
-    pending = task_state == TASK_PENDING
-    rp = jnp.stack([running, pending], axis=-1).astype(jnp.float32)
+    def task_spec(x):
+        return pl.BlockSpec(x.shape[:-1] + (tile_t,),
+                            lambda i: (0,) * (x.ndim - 1) + (i,))
 
-    prio = jnp.clip(prio, 0, N_PRIO - 1)
-    onehot = (prio[..., None] == jax.lax.broadcasted_iota(
-        prio.dtype, prio.shape + (N_PRIO,), prio.ndim)
-              ).astype(jnp.float32)                          # (B|1, TT, 12)
-    # per-priority population as a batched one-hot matmul (MXU-friendly;
-    # counts < 2^24 so the f32 accumulate is exact and the i32 cast bitwise)
-    hist = jax.lax.dot_general(
-        onehot, rp, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32).astype(jnp.int32)
-    hist_ref[...] += jnp.broadcast_to(hist, hist_ref.shape)  # (B, 12, 2)
-
-    usum = jax.lax.dot_general(rp[..., 0], usage,
-                               (((1,), (1,)), ((0,), (0,))),
-                               preferred_element_type=jnp.float32)
-    usum_ref[...] += jnp.broadcast_to(usum, usum_ref.shape)  # (B, U)
-
-    counts = jnp.concatenate(
-        [jnp.sum(rp, axis=1, dtype=jnp.int32),
-         jnp.zeros((rp.shape[0], 1), jnp.int32)], axis=-1)   # (B|1, 3)
-    counts_ref[...] += jnp.broadcast_to(counts, counts_ref.shape)
-
-    # --- node pass: whole (B|1, N, R) blocks, computed once ---------------
-    @pl.when(i == 0)
-    def _nodes():
-        active = active_ref[...]                       # (B|1, N) bool
-        total = total_ref[...]                         # (B|1, N, R) f32
-        reserved = resv_ref[...]
-        used = used_ref[...]
-        R = total.shape[-1]
-
-        cap = jnp.where(active[..., None], total, 0.0).sum(1)     # (B|1, R)
-        resv = reserved.sum(1)
-        usd = used.sum(1)
-        n_nodes = jnp.sum(active, axis=1, dtype=jnp.int32)        # (B|1,)
-        n_div = jnp.maximum(n_nodes, 1)
-
-        node_util = jnp.where(active[..., None],
-                              used / jnp.maximum(total, 1e-9),
-                              0.0)[..., 0]                        # (B|1, N)
-        util_mean = node_util.sum(1) / n_div
-        util_var = jnp.where(active,
-                             (node_util - util_mean[:, None]) ** 2,
-                             0.0).sum(1) / n_div
-        node_res = jnp.where(active[..., None],
-                             reserved / jnp.maximum(total, 1e-9),
-                             0.0).mean(-1)
-        res_mean = node_res.sum(1) / n_div
-        res_var = jnp.where(active,
-                            (node_res - res_mean[:, None]) ** 2,
-                            0.0).sum(1) / n_div
-
-        red = jnp.concatenate(
-            [cap, resv, usd, util_var[:, None], res_var[:, None]], axis=-1)
-        node_ref[...] = jnp.broadcast_to(red, node_ref.shape)
-        # n_nodes rides the i32 counts output's third column
-        counts_ref[...] += jnp.broadcast_to(
-            jnp.stack([jnp.zeros_like(n_nodes), jnp.zeros_like(n_nodes),
-                       n_nodes], axis=-1), counts_ref.shape)
-
-
-def window_stats_pallas(task_state, task_usage, task_prio, node_active,
-                        node_total, node_reserved, node_used, *,
-                        n_lanes: int, tile_t: int = 1024,
-                        interpret: bool = True):
-    """Fused stats reductions over ``n_lanes`` scenario lanes (1 for the
-    single-trajectory engine).  Each operand's leading lane axis is either
-    ``n_lanes`` or 1 (lane-shared, kept un-copied).  Returns
-    (counts (B, 3) i32 = [n_running, n_pending, n_nodes],
-     by_prio (B, 12, 2) i32, usage_sum (B, U) f32,
-     node_red (B, 3R+2) f32 = [cap | reserved | used | util_var, res_var])."""
-    T = task_state.shape[1]
-    U = task_usage.shape[2]
-    N, R = node_total.shape[1], node_total.shape[2]
-    assert T % tile_t == 0, (T, tile_t)
-
-    grid = (T // tile_t,)
-    kernel = functools.partial(_kernel, n_lanes=n_lanes)
-
-    def task_spec(x, last):
-        return pl.BlockSpec((x.shape[0], tile_t) + last,
-                            lambda i: (0, i) + (0,) * len(last))
-
-    def node_spec(x):
-        return pl.BlockSpec(x.shape, lambda i: (0,) * x.ndim)
-
-    def out_spec(shape):
+    def whole(shape):
         return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
 
+    operands = (task_state, task_usage, task_prio)
+    in_specs = [task_spec(x) for x in operands]
+    out_shape = (jax.ShapeDtypeStruct((2, B, N_PRIO), jnp.int32),
+                 jax.ShapeDtypeStruct((B, U), jnp.float32))
+    out_specs = tuple(whole(o.shape) for o in out_shape)
+    blocks = [(s.block_shape, x.dtype) for s, x in zip(in_specs, operands)]
+    blocks += [(o.shape, o.dtype) for o in out_shape]
     return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            task_spec(task_state, ()),
-            task_spec(task_usage, (U,)),
-            task_spec(task_prio, ()),
-            node_spec(node_active),
-            node_spec(node_total),
-            node_spec(node_reserved),
-            node_spec(node_used),
-        ],
-        out_specs=(
-            out_spec((n_lanes, 3)),
-            out_spec((n_lanes, N_PRIO, 2)),
-            out_spec((n_lanes, U)),
-            out_spec((n_lanes, 3 * R + 2)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((n_lanes, 3), jnp.int32),
-            jax.ShapeDtypeStruct((n_lanes, N_PRIO, 2), jnp.int32),
-            jax.ShapeDtypeStruct((n_lanes, U), jnp.float32),
-            jax.ShapeDtypeStruct((n_lanes, 3 * R + 2), jnp.float32),
-        ),
+        functools.partial(_kernel, n_lanes=B),
+        grid=(T // tile_t,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit(blocks)),
         interpret=interpret,
-    )(task_state, task_usage, task_prio, node_active, node_total,
-      node_reserved, node_used)
+    )(*operands)

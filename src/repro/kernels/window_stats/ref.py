@@ -57,12 +57,15 @@ def task_reductions_ref(task_state: jax.Array, task_usage: jax.Array,
     onehot = (prio[:, None] == jnp.arange(N_PRIO, dtype=prio.dtype)
               ).astype(jnp.float32)                                 # (T, 12)
     # counts < 2^24, so the f32 matmul is exact and the i32 cast bitwise
+    # (0/1 operands are exact in bf16: this holds at TPU default precision)
     by_prio = jax.lax.dot_general(
         onehot, rp, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(jnp.int32)       # (12, 2)
     n_running = by_prio[:, 0].sum()          # == running.sum() exactly
     n_pending = by_prio[:, 1].sum()
-    usage_sum = rp[:, 0] @ task_usage        # masked sum, no (T, U) temp
+    # masked sum, the unfused path's expression: an exact select + reduce
+    # (an f32 matmul would run as one bf16 pass on a TPU)
+    usage_sum = jnp.where(running[:, None], task_usage, 0.0).sum(0)
     return n_running, n_pending, by_prio, usage_sum
 
 

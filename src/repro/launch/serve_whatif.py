@@ -100,6 +100,8 @@ def main(argv=None):
                          " — queries must still succeed (after retries)")
     args = ap.parse_args(argv)
 
+    from repro import env
+    env.enable_compile_cache()
     schedulers = args.schedulers.split(",")
     cfg = build_cfg(args)
 

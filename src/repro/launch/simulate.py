@@ -75,7 +75,8 @@ def main(argv=None):
                     help="print the scheduler registry and exit")
     ap.add_argument("--speed-factor", type=float, default=0.0)
     ap.add_argument("--use-kernels", action="store_true",
-                    help="Pallas kernels (interpret mode on CPU)")
+                    help="Pallas kernels (compiled on a TPU, interpreted "
+                         "on the CPU)")
     ap.add_argument("--stats-stride", type=int, default=1,
                     help="emit a stats row every k-th window (headless "
                          "sweeps; skipped windows pay zero stats cost, "
@@ -87,13 +88,13 @@ def main(argv=None):
     ap.add_argument("--batch-windows", type=int, default=32)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--platform", default=None,
-                    choices=("cpu", "gpu", "tpu"),
-                    help="pin the jax backend (default: auto-detect); gpu "
-                         "adds the XLA perf-flag preset (repro.env)")
+                    choices=("cpu", "tpu"),
+                    help="pin the jax backend (default: auto-detect)")
     args = ap.parse_args(argv)
 
     from repro import env
     env.set_platform(args.platform)
+    env.enable_compile_cache()
 
     if args.list_schedulers:
         from repro.sched import describe_schedulers

@@ -166,9 +166,8 @@ def main(argv=None):
     ap.add_argument("--snapshot", default=None,
                     help="write a batched fleet snapshot here at the end")
     ap.add_argument("--platform", default=None,
-                    choices=("cpu", "gpu", "tpu"),
-                    help="pin the jax backend (default: auto-detect); gpu "
-                         "adds the XLA perf-flag preset (repro.env)")
+                    choices=("cpu", "tpu"),
+                    help="pin the jax backend (default: auto-detect)")
     ap.add_argument("--dispatch", default=None,
                     choices=("auto", "switch", "table"),
                     help="scheduler dispatch strategy (cfg.sched_dispatch): "
@@ -180,6 +179,7 @@ def main(argv=None):
 
     from repro import env
     env.set_platform(args.platform)
+    env.enable_compile_cache()
 
     if args.list_schedulers:
         from repro.sched import describe_schedulers

@@ -203,9 +203,7 @@ def _local_dispatch(x2d, weights, expert_idx, keep_mask, wi, wo, e_lo, E_loc,
 
 
 def _apply_shard_map(params, cfg, x, mesh, rules) -> Tuple[jax.Array, jax.Array]:
-    from repro.distributed.sharding import import_shard_map
     from jax.sharding import PartitionSpec as P
-    shard_map, check_kw = import_shard_map()
 
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     tp = "model"
@@ -252,12 +250,12 @@ def _apply_shard_map(params, cfg, x, mesh, rules) -> Tuple[jax.Array, jax.Array]
         return y.reshape(Bl, Sl, d).astype(dtype), aux
 
     dpx = dp if len(dp) > 1 else (dp[0] if dp else None)
-    out = shard_map(
+    out = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(dpx, None, None), P(dpx, None),
                   P(tp, dpx, None), P(tp, None, dpx)),
         out_specs=(P(dpx, None, None), P()),
-        **check_kw,
+        check_vma=False,
     )(x, params["router"], wi_p, wo_p)
     return out
 

@@ -32,10 +32,9 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import SimConfig
-from repro.distributed.sharding import import_shard_map
 from repro.core import engine as eng
 from repro.core import stats as stats_mod
 from repro.core.events import EventWindow
@@ -57,7 +56,10 @@ def fleet_mesh(n_devices: Optional[int] = None) -> Mesh:
         raise ValueError(f"--mesh {n} > {jax.device_count()} devices "
                          "(on CPU, fake devices need XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N)")
-    return jax.make_mesh((n,), (FLEET_AXIS,))
+    # Auto axis: lanes never communicate, and the runner slices padding
+    # lanes off sharded outputs with plain indexing, which an Explicit
+    # axis (make_mesh's default) would reject without an out_sharding
+    return jax.make_mesh((n,), (FLEET_AXIS,), axis_types=(AxisType.Auto,))
 
 
 def shard_over_fleet(tree, mesh: Optional[Mesh]):
@@ -357,7 +359,6 @@ def run_scenarios_sharded(state: SimState, windows: EventWindow,
     through the ``lax.switch`` path (``cfg.sched_dispatch='table'`` raises
     here; lane trajectories are bitwise-identical either way).
     """
-    shard_map, check_kw = import_shard_map()
     B = jax.tree.leaves(state)[0].shape[0]
     n_dev = mesh.shape[FLEET_AXIS]
     if B % n_dev:
@@ -373,10 +374,10 @@ def run_scenarios_sharded(state: SimState, windows: EventWindow,
         return run_scenarios(s, w, k, cfg, scheduler_names, seed, has_storm,
                              table)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(FLEET_AXIS), P(), P(FLEET_AXIS)),
-                   out_specs=(P(FLEET_AXIS), P(None, FLEET_AXIS)),
-                   **check_kw)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(FLEET_AXIS), P(), P(FLEET_AXIS)),
+                       out_specs=(P(FLEET_AXIS), P(None, FLEET_AXIS)),
+                       check_vma=False)
     return fn(state, windows, knobs)
 
 

@@ -52,6 +52,7 @@ import jax.numpy as jnp
 
 from repro.config import SimConfig
 from repro.core.events import EventKind, EventWindow
+from repro.core.ordered import ordered_scatter_add
 from repro.core.state import SimState, TASK_EMPTY, TASK_PENDING, TASK_RUNNING
 from repro.core.stats import ACCOUNTED_USAGE_COLS
 from repro.kernels.segment_usage.ops import segment_usage
@@ -242,14 +243,14 @@ def expire_injected(state: SimState, k: ScenarioKnobs, cfg: SimConfig
         # scatter (the pool is small), matching what a full recompute of the
         # post-expiry table would drop. Lanes without victims subtract
         # exact zeros, so the lane-0 bitwise identity survives.
-        idxn = jnp.where(was_running, old_node, cfg.max_nodes)
         ucols = jnp.array(ACCOUNTED_USAGE_COLS)
-        sub = jnp.where(was_running[:, None], state.task_req[rows], 0.0)
-        subu = jnp.where(was_running[:, None],
-                         state.task_usage[rows][:, ucols], 0.0)
         state = state._replace(
-            node_reserved=state.node_reserved.at[idxn].add(-sub, mode="drop"),
-            node_used=state.node_used.at[idxn].add(-subu, mode="drop"))
+            node_reserved=ordered_scatter_add(
+                state.node_reserved, old_node, was_running,
+                -state.task_req[rows]),
+            node_used=ordered_scatter_add(
+                state.node_used, old_node, was_running,
+                -state.task_usage[rows][:, ucols]))
     return state
 
 
@@ -302,8 +303,9 @@ def storm_debit(state: SimState, victim: jax.Array, cum, cfg: SimConfig
         R = state.task_req.shape[1]
         vals = jnp.concatenate(
             [state.task_req, state.task_usage[:, ucols]], axis=1)
-        sub = segment_usage(state.task_node, vals, victim, cfg.max_nodes,
-                            use_kernel=cfg.use_kernels)
+        # the ordered reference under use_kernels too: later fits read
+        # these tallies (see engine.recompute_accounting)
+        sub = segment_usage(state.task_node, vals, victim, cfg.max_nodes)
         return state._replace(node_reserved=state.node_reserved - sub[:, :R],
                               node_used=state.node_used - sub[:, R:])
     # victim compaction: the (j+1)-th victim lives at the first row whose
@@ -312,12 +314,12 @@ def storm_debit(state: SimState, victim: jax.Array, cum, cfg: SimConfig
     vrows = jnp.searchsorted(cum, jnp.arange(1, V + 1, dtype=cum.dtype))
     valid = jnp.arange(V) < jnp.minimum(cum[-1], V)
     rows = jnp.minimum(vrows, T - 1)
-    vnode = jnp.where(valid, state.task_node[rows], cfg.max_nodes)
-    vreq = jnp.where(valid[:, None], state.task_req[rows], 0.0)
-    vuse = jnp.where(valid[:, None], state.task_usage[rows][:, ucols], 0.0)
+    vnode = state.task_node[rows]
     return state._replace(
-        node_reserved=state.node_reserved.at[vnode].add(-vreq, mode="drop"),
-        node_used=state.node_used.at[vnode].add(-vuse, mode="drop"))
+        node_reserved=ordered_scatter_add(state.node_reserved, vnode, valid,
+                                          -state.task_req[rows]),
+        node_used=ordered_scatter_add(state.node_used, vnode, valid,
+                                      -state.task_usage[rows][:, ucols]))
 
 
 def storm_evict(state: SimState, k: ScenarioKnobs, cfg: SimConfig) -> SimState:

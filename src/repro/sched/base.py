@@ -25,10 +25,19 @@ NEG = -jnp.inf
 
 
 def pending_batch(state: SimState, cfg: SimConfig):
-    """Top-P pending task slots by priority (descending)."""
-    P = cfg.sched_batch
+    """Top-P pending task slots by priority (descending), equal priorities
+    in slot order, then the non-pending slots in slot order.
+
+    Every key is distinct — (priority + 1) * T plus the reversed slot for a
+    pending slot, the reversed slot alone otherwise — because ``top_k``
+    leaves the order of equal keys to the backend, and the TPU's differs
+    from the CPU's: the commit would then place equal-priority tasks in
+    another order."""
+    P, T = cfg.sched_batch, cfg.max_tasks
     pend = state.task_state == TASK_PENDING
-    key = jnp.where(pend, state.task_prio, jnp.iinfo(jnp.int32).min)
+    top = (jnp.iinfo(jnp.int32).max // T) - 2     # keys stay in int32
+    rank = jnp.where(pend, jnp.clip(state.task_prio, 0, top) + 1, 0)
+    key = rank * T + (T - 1 - jnp.arange(T, dtype=jnp.int32))
     _, idx = jax.lax.top_k(key, P)
     valid = pend[idx]
     return idx, valid

@@ -13,18 +13,23 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.ordered import sum_columns, tree_sum
 from repro.sched.base import NEG
 from repro.sched.registry import register_scheduler
 from repro.sched.table import SchedContext, TableForm, context_from_state
 
 
 def balance_objective(reserved, total, active):
-    """Variance of per-node reservation fraction (lower = better balanced)."""
+    """Variance of per-node reservation fraction (lower = better balanced).
+
+    Summed in a fixed order (``core.ordered``): the searches compare
+    energies strictly, so an energy that rounds differently on the TPU
+    than on the CPU would steer them apart."""
     frac = jnp.where(active[:, None], reserved / jnp.maximum(total, 1e-9), 0.0)
-    f = frac.mean(-1)
+    f = sum_columns(frac) / frac.shape[-1]
     na = jnp.maximum(active.sum(), 1)
-    mu = f.sum() / na
-    return jnp.where(active, (f - mu) ** 2, 0.0).sum() / na
+    mu = tree_sum(f) / na
+    return tree_sum(jnp.where(active, (f - mu) ** 2, 0.0)) / na
 
 
 def _surrogate(req, node_reserved, node_total, node_active, valid, base_ok):
@@ -37,7 +42,11 @@ def _surrogate(req, node_reserved, node_total, node_active, valid, base_ok):
     def trial_reserved(pref_m):
         choice = jnp.argmax(jnp.where(base_ok, pref_m, NEG), axis=1)
         onehot = jax.nn.one_hot(choice, N, dtype=jnp.float32) * weight
-        return node_reserved + onehot.T @ req
+        # HIGHEST: at the default precision a TPU runs this f32 matmul as
+        # one bf16 pass, rounding the requests — and the energies that
+        # decide every annealing / tabu / GA step would leave the CPU's
+        return node_reserved + jnp.matmul(
+            onehot.T, req, precision=jax.lax.Precision.HIGHEST)
 
     def energy(pref_m):
         return balance_objective(trial_reserved(pref_m), node_total,

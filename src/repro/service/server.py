@@ -227,7 +227,7 @@ class WhatIfServer:
         attempt = 1
         while True:
             try:
-                self._run_batch(tickets)
+                results = self._run_batch(tickets)
             except StackCorruptionError as e:
                 self.metrics.on_checksum_failure()
                 breaker.on_failure()
@@ -248,10 +248,15 @@ class WhatIfServer:
                 attempt += 1
                 time.sleep(delay)
                 continue
+            # settle the breaker before any waiter wakes, so a caller that
+            # reads stats() after its result sees the close it caused
             breaker.on_success()
+            for t, result in zip(tickets, results):
+                t.finish(result)
             return
 
-    def _run_batch(self, tickets: List[Ticket]):
+    def _run_batch(self, tickets: List[Ticket]) -> List[WhatIfResult]:
+        """Run one padded micro-batch; returns each ticket's result."""
         queries = [t.query for t in tickets]
         S, N, seed = queries[0].batch_key()
         live = len(queries)
@@ -301,17 +306,18 @@ class WhatIfServer:
         frame = {k: np.concatenate([np.asarray(r[k]) for r in rows])
                  for k in rows[0]}
         self.metrics.on_batch(live, B - live, N)
-        for i, t in enumerate(tickets):
-            q = t.query
+        results = []
+        for i, q in enumerate(queries):
             lane = {k: v[:, i:i + 1] for k, v in frame.items()}
             rep = scenario_report([q.spec.name], lane, [q.spec.scheduler])
-            t.finish(WhatIfResult(
+            results.append(WhatIfResult(
                 name=q.spec.name, scheduler=q.spec.scheduler,
                 start_window=S, n_windows=N,
                 row=rep["scenarios"][0],
                 curves=rep["curves"] if q.include_curves else None,
                 frame={k: np.asarray(v[:, i]) for k, v in frame.items()},
                 batch_lanes=live, batch_size=B))
+        return results
 
     # --- telemetry -----------------------------------------------------------
 

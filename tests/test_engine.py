@@ -10,6 +10,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.config import REDUCED_SIM
 from repro.core import engine as eng
+from repro.core.ordered import ordered_scatter_add, tree_sum
 from repro.core.events import (EventKind, HostEvent, REMOVE_REASON_EVICT,
                                pack_window, stack_windows)
 from repro.sched import get_scheduler
@@ -150,3 +151,88 @@ def test_property_no_overcommit_random_streams(seed):
         windows.append(evs)
     state, _ = _run(windows)
     assert validate_invariants(state, CFG) == {}
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_ordered_scatter_add_sums_each_index_once(seed):
+    """Each index takes one add, ``acc + sum(its rows)``: exact on
+    grid-aligned values, masked and out-of-range rows dropped, and the
+    result depends only on each index's own rows in row order (any
+    interleaving of the indices gives the same bits)."""
+    r = np.random.default_rng(seed)
+    N, E, R = 7, 300, 3
+    idx = r.integers(-1, N + 1, E).astype(np.int32)
+    mask = r.random(E) < 0.8
+    keep = mask & (idx >= 0) & (idx < N)
+
+    def run(acc, idx, mask, vals):
+        return np.asarray(ordered_scatter_add(
+            jnp.asarray(acc), jnp.asarray(idx), jnp.asarray(mask),
+            jnp.asarray(vals)))
+
+    # multiples of 2^-10: every partial sum is exact, whatever the order
+    acc = (r.integers(0, 1024, (N, R)) / 1024).astype(np.float32)
+    vals = (r.integers(-512, 512, (E, R)) / 1024).astype(np.float32)
+    want = acc.astype(np.float64)
+    np.add.at(want, idx[keep], vals[keep])
+    np.testing.assert_array_equal(run(acc, idx, mask, vals), want)
+
+    # values whose sums round: one add per index on top of its row sum,
+    # and only the order within an index matters
+    acc = r.random((N, R)).astype(np.float32)
+    vals = (r.random((E, R)) * 1e-3 - 3e-4).astype(np.float32)
+    got = run(acc, idx, mask, vals)
+    np.testing.assert_array_equal(
+        got, acc + run(np.zeros_like(acc), idx, mask, vals))
+    want = acc.astype(np.float64)
+    np.add.at(want, idx[keep], vals[keep])
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    # a random interleaving of the indices; each index's rows stay in order
+    tag = np.where(keep, idx, N)
+    shuffled = r.permutation(E)
+    tag_at = tag[shuffled]
+    for k in np.unique(tag):
+        shuffled[tag_at == k] = np.nonzero(tag == k)[0]
+    np.testing.assert_array_equal(
+        run(acc, idx[shuffled], mask[shuffled], vals[shuffled]), got)
+
+
+def test_tree_sum_is_a_fixed_pairwise_tree():
+    """tree_sum adds neighbours level by level (zero-padded to a power of
+    two) — bitwise the same tree written out in numpy."""
+    r = np.random.default_rng(0)
+    for n in (1, 2, 5, 8, 13):
+        x = r.random((n, 3)).astype(np.float32)
+        lvl = np.concatenate([x, np.zeros((8 * 2 - n, 3), np.float32)])
+        lvl = lvl[:1 << max(0, (n - 1).bit_length())]
+        while len(lvl) > 1:
+            lvl = lvl[0::2] + lvl[1::2]
+        np.testing.assert_array_equal(np.asarray(tree_sum(jnp.asarray(x))),
+                                      lvl[0])
+
+
+def test_resync_tallies_bitwise_under_use_kernels():
+    """The accounting resync sets tallies that later fits compare against,
+    so with ``use_kernels`` it must give the reference's bits: many tasks
+    per node and values that round."""
+    import dataclasses
+    r = np.random.default_rng(0)
+    T, N = CFG.max_tasks, CFG.max_nodes
+    state = init_state(CFG)
+    state = state._replace(
+        task_state=jnp.asarray(np.where(r.random(T) < 0.7, TASK_RUNNING,
+                                        TASK_PENDING).astype(np.int8)),
+        # a quarter of the nodes: dozens of tasks each
+        task_node=jnp.asarray(r.integers(-1, N // 4, T).astype(np.int32)),
+        task_req=jnp.asarray(r.random(state.task_req.shape, np.float32)
+                             * 0.03),
+        task_usage=jnp.asarray(r.random(state.task_usage.shape, np.float32)
+                               * 0.01))
+    placed = np.asarray(state.task_node)
+    assert np.bincount(placed[placed >= 0]).max() > 20
+    ref = eng.recompute_accounting(state, CFG)
+    ker = eng.recompute_accounting(
+        state, dataclasses.replace(CFG, use_kernels=True))
+    for a, b in ((ker.node_reserved, ref.node_reserved),
+                 (ker.node_used, ref.node_used)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

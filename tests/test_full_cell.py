@@ -124,7 +124,7 @@ def test_full_cell_sched_pass_streams_node_tiles():
     ref = sched_pass(scores, req, ok, valid, total, denom, res0,
                      use_kernel=False, return_tally=True)
     got = sched_pass(scores, req, ok, valid, total, denom, res0,
-                     family=FAM_SCORES, use_kernel=True, interpret=True,
+                     family=FAM_SCORES, use_kernel=True,
                      tile_n=8_192, return_tally=True)
     for a, b in zip(ref, got):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -6,7 +6,7 @@
 * the staging-buffer ring replaces per-batch ``np.stack`` without aliasing
   in-flight device batches (CPU jit would zero-copy raw numpy inputs);
 * the prefetcher's event ledger is guarded and ``buffer_occupancy()``
-  balances;
+  balances, and a source that fails mid-trace fails the run;
 * donated state buffers are actually consumed;
 * the periodic accounting resync fires on the configured cadence.
 """
@@ -65,6 +65,24 @@ def test_run_syncs_once_and_keeps_stats_on_device(monkeypatch):
     for row in sim.stats_rows:
         for v in row.values():
             assert isinstance(v, np.ndarray)
+
+
+def test_source_failure_mid_trace_fails_the_run():
+    """A window source that raises after some batches must make run()
+    raise on the consumer thread — not end early as if the trace were
+    complete (the fill thread ends the queue either way)."""
+    class TraceReadError(RuntimeError):
+        pass
+
+    def source():
+        yield from _windows(9)
+        raise TraceReadError("chunk 3 unreadable")
+
+    sim = pipe.Simulation(CFG, source(), batch_windows=4)
+    with pytest.raises(TraceReadError, match="chunk 3"):
+        sim.run()
+    # the batches before the failure were simulated; the rest were not
+    assert sim.windows_done == 8
 
 
 def test_runahead_is_bounded(monkeypatch):

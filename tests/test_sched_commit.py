@@ -50,13 +50,15 @@ def _assert_kernel_bitwise(seed, dyn, traced, P=None, N=None,
     args = _inputs(r, P, N)
     flag = jnp.asarray(dyn) if traced else dyn
     ref = placement_commit(*args, flag, use_kernel=False)
-    ker = placement_commit(*args, flag, use_kernel=True,
-                           tile_p=tile_p, tile_n=tile_n)
+    ker = placement_commit(*args, flag, use_kernel=True, tile_p=tile_p)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(ker))
+    # node-streamed: tile_n-column tiles with the cross-tile argmax carry
+    streamed = placement_commit(*args, flag, use_kernel=True,
+                                stream_n=tile_n)
+    np.testing.assert_array_equal(np.asarray(ref), np.asarray(streamed))
     if traced:
         # the traced flag must agree with the static specialisation too
-        stat = placement_commit(*args, dyn, use_kernel=True,
-                                tile_p=tile_p, tile_n=tile_n)
+        stat = placement_commit(*args, dyn, use_kernel=True, tile_p=tile_p)
         np.testing.assert_array_equal(np.asarray(ker), np.asarray(stat))
 
 
@@ -68,7 +70,7 @@ def _assert_no_overcommit(seed, dyn, use_kernel):
     pref = pref + jnp.asarray(reserved0.sum(-1)[None, :] * 100.0, jnp.float32)
     node_of = np.asarray(placement_commit(
         pref, req, base_ok, valid, total, denom, reserved0, dyn,
-        use_kernel=use_kernel, tile_p=16, tile_n=32))
+        use_kernel=use_kernel, tile_p=16))
     reqn, okn, validn = np.asarray(req), np.asarray(base_ok), np.asarray(valid)
     tally = np.asarray(reserved0).copy()
     assigned = np.zeros(N, bool)
@@ -150,7 +152,7 @@ def test_commit_kernel_vmapped_matches_ref():
     def one(p, f, use_kernel):
         return placement_commit(p, req, base_ok, valid, total, denom,
                                 reserved0, f, use_kernel=use_kernel,
-                                tile_p=8, tile_n=16)
+                                tile_p=8)
 
     ker = jax.vmap(lambda p, f: one(p, f, True))(prefs, flags)
     ref = jax.vmap(lambda p, f: one(p, f, False))(prefs, flags)
@@ -166,7 +168,7 @@ def test_commit_all_infeasible_places_nothing():
     for use_kernel in (False, True):
         node_of = placement_commit(pref, req, base_ok, valid, total, denom,
                                    reserved0, False, use_kernel=use_kernel,
-                                   tile_p=8, tile_n=8)
+                                   tile_p=8)
         assert (np.asarray(node_of) == -1).all()
 
 
@@ -184,5 +186,5 @@ def test_commit_priority_order_consumes_capacity_in_row_order():
     for use_kernel in (False, True):
         node_of = np.asarray(placement_commit(
             pref, req, ok, valid, total, denom, res0, False,
-            use_kernel=use_kernel, tile_p=2, tile_n=8))
+            use_kernel=use_kernel, tile_p=2))
         assert node_of[0] == 0 and node_of[1] == -1, node_of

@@ -285,7 +285,7 @@ def test_sched_pass_kernel_matches_ref_nondivisible(P, N, family_start, dyn):
                      use_kernel=False, return_tally=True)
     for tile_p, tile_n in ((16, None), (16, 16), (8, 32)):
         got = sched_pass(*ops, dynamic_bestfit=dyn, family=fam, start=start,
-                         use_kernel=True, interpret=True, tile_p=tile_p,
+                         use_kernel=True, tile_p=tile_p,
                          tile_n=tile_n, return_tally=True)
         for a, b in zip(ref, got):
             np.testing.assert_array_equal(
@@ -312,7 +312,7 @@ def test_sched_pass_streaming_tiles_match_whole_n():
                      return_tally=True)
     for tile_n in (8, 16, 24):
         got = sched_pass(*ops, family=FAM_SCORES, use_kernel=True,
-                         interpret=True, tile_n=tile_n, return_tally=True)
+                         tile_n=tile_n, return_tally=True)
         for a, b in zip(ref, got):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                           err_msg=f"tile_n={tile_n}")

@@ -107,3 +107,24 @@ def test_vmapped_scheduler_replicas():
     assert (placements > 0).all()
     # different seeds -> not all identical placements
     assert not bool(jnp.all(nodes[0] == nodes[1]))
+
+
+def test_pending_batch_order_is_fully_determined():
+    """Pending slots by priority descending, equal priorities in slot
+    order, then non-pending slots in slot order — no tie left for top_k
+    to break (the TPU breaks ties differently from the CPU)."""
+    from repro.core.state import TASK_PENDING
+    from repro.sched.base import pending_batch
+    r = np.random.default_rng(0)
+    T, P = CFG.max_tasks, CFG.sched_batch
+    st = init_state(CFG)
+    task_state = r.choice([0, TASK_PENDING, TASK_RUNNING], T).astype(np.int8)
+    prio = r.integers(0, 3, T).astype(np.int32)          # many ties
+    st = st._replace(task_state=jnp.asarray(task_state),
+                     task_prio=jnp.asarray(prio))
+    idx, valid = pending_batch(st, CFG)
+    pend = task_state == TASK_PENDING
+    slots = np.arange(T)
+    want = np.lexsort((slots, -np.where(pend, prio + 1, 0)))[:P]
+    np.testing.assert_array_equal(np.asarray(idx), want)
+    np.testing.assert_array_equal(np.asarray(valid), pend[want])

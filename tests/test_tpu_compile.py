@@ -1,0 +1,153 @@
+"""Compile the main path's Pallas kernels for a TPU v5e at cell-A widths.
+
+The interpreter that runs the kernels in the rest of the suite cannot see
+what the chip's compiler (Mosaic) refuses: block shapes off the (8, 128)
+tile, primitives it cannot lower, VMEM overruns. Here each kernel entry is
+lowered for a *described* v5e chip — the TPU compiler is installed, no chip
+is attached — and compiled; ``repro.kernels.by_platform`` picks the
+compiled kernel because the call lowers for a TPU, and the test checks that
+the program really holds a Mosaic kernel (``tpu_custom_call``).
+
+Widths are cell A's (``configs/agocs_cell_a.py``): P = sched_batch = 1,024
+pending tasks, N = 12,500 nodes, T = 262,144 task slots.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU library, so describing it while a test
+worker imports this file would break the other workers.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_sim_config
+
+CELL_A = get_sim_config("agocs_cell_a")
+P, N, T = CELL_A.sched_batch, CELL_A.max_nodes, CELL_A.max_tasks
+R, C, K = CELL_A.n_resources, CELL_A.max_constraints, CELL_A.n_attr_slots
+U = CELL_A.n_usage_stats
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one — keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return compiled
+
+
+def _node_shapes(n_lanes=None):
+    lead = () if n_lanes is None else (n_lanes,)
+    return [(lead + (N, R), jnp.float32)] * 3
+
+
+def test_constraint_match_compiles(one_chip):
+    from repro.kernels.constraint_match.ops import constraint_match
+
+    def fn(req, cons, total, reserved, attrs, active):
+        return constraint_match(req, cons, total, reserved, attrs, active,
+                                use_kernel=True)
+
+    _compile(fn, one_chip, ((P, R), jnp.float32), ((P, C, 3), jnp.int32),
+             ((N, R), jnp.float32), ((N, R), jnp.float32),
+             ((N, K), jnp.int32), ((N,), jnp.bool_))
+
+
+@pytest.mark.parametrize("n_lanes", [None, 4])
+def test_window_stats_compiles(one_chip, n_lanes):
+    from repro.kernels.window_stats.ops import window_reductions
+
+    def one(state, usage, prio, active, total, resv, used):
+        return window_reductions(state, usage, prio, active, total, resv,
+                                 used, use_kernel=True)
+
+    fn = one if n_lanes is None else jax.vmap(one)
+    lead = () if n_lanes is None else (n_lanes,)
+    _compile(fn, one_chip, (lead + (T,), jnp.int8),
+             (lead + (T, U), jnp.float32), (lead + (T,), jnp.int32),
+             (lead + (N,), jnp.bool_), *_node_shapes(n_lanes))
+
+
+@pytest.mark.parametrize("stream_n", [None, 8_192])
+def test_placement_commit_compiles(one_chip, stream_n):
+    from repro.kernels.placement_commit.ops import placement_commit
+
+    def fn(pref, req, ok, valid, total, denom, res0):
+        return placement_commit(pref, req, ok, valid, total, denom, res0,
+                                True, use_kernel=True, stream_n=stream_n,
+                                return_tally=True)
+
+    _compile(fn, one_chip, ((P, N), jnp.float32), ((P, R), jnp.float32),
+             ((P, N), jnp.bool_), ((P,), jnp.bool_), *_node_shapes())
+
+
+def test_sched_pass_streamed_compiles(one_chip):
+    from repro.kernels.placement_commit.ops import FAM_NODE_ORDER, sched_pass
+
+    def fn(scores, req, ok, valid, total, denom, res0, start):
+        return sched_pass(scores, req, ok, valid, total, denom, res0,
+                          family=FAM_NODE_ORDER, start=start,
+                          use_kernel=True, tile_n=8_192, return_tally=True)
+
+    _compile(fn, one_chip, ((P, N), jnp.float32), ((P, R), jnp.float32),
+             ((P, N), jnp.bool_), ((P,), jnp.bool_), *_node_shapes(),
+             ((), jnp.int32))
+
+
+def test_sched_commit_fleet_mixed_compiles(one_chip):
+    """The switchless fleet's fused pass over cell A's four-scheduler mix:
+    greedy (scores, dynamic), simulated annealing (external), round-robin
+    and first-fit (node order)."""
+    from repro.kernels.placement_commit.ops import (FAM_EXTERNAL,
+                                                    FAM_NODE_ORDER,
+                                                    FAM_SCORES,
+                                                    sched_commit_fleet)
+    B = 4
+    fam = (FAM_SCORES, FAM_EXTERNAL, FAM_NODE_ORDER, FAM_NODE_ORDER)
+
+    def fn(scores, ok, req, valid, total, denom, res0, start, ext):
+        return sched_commit_fleet(scores, ok, req, valid, total, denom, res0,
+                                  start, fam=fam,
+                                  dynamic=(True, False, False, False),
+                                  ext=ext, ext_row=(0, 0, 0, 0))
+
+    _compile(fn, one_chip, ((B, P, N), jnp.float32),
+             ((B, P, N), jnp.bool_), ((B, P, R), jnp.float32),
+             ((B, P), jnp.bool_), *_node_shapes(B), ((B,), jnp.int32),
+             ((1, P, N), jnp.float32))
+
+
+def test_segment_usage_compiles(one_chip):
+    from repro.kernels.segment_usage.ops import segment_usage
+
+    def fn(task_node, values, mask):
+        return segment_usage(task_node, values, mask, N, use_kernel=True)
+
+    _compile(fn, one_chip, ((T,), jnp.int32), ((T, R), jnp.float32),
+             ((T,), jnp.bool_))
