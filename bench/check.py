@@ -1,0 +1,165 @@
+"""The comparison that decides ``correct``.
+
+The program's state before one batch of the timed window goes through the
+plain reference (``reference.py``); the program's state after the batch and
+its stats rows are compared with the reference's. The numbers compared,
+each held to its limit (``limits/``):
+
+* ``events_differ``: entries of the node and task tables that event
+  application writes (capacities, attributes, activity, requests,
+  priorities, jobs, constraints, usage, the window counter) that differ;
+* ``decisions_differ``: task slots whose state or node differs, plus the
+  differences of the placement, completion, eviction and drop counters;
+* ``tallies_differ``: entries of the reservation tally (``node_reserved``)
+  that differ: the program sums it in a documented order, so it is exact;
+* ``stats_differ``: integer entries of the stats rows that differ;
+* ``stats_gap``: the widest relative gap of a float stats entry (sums over
+  nodes or tasks in the program's order against the reference's);
+* ``tally_drift``: the widest gap, as a share of the node's capacity,
+  between a tally the program keeps incrementally (``node_reserved``,
+  ``node_used``) and a float64 recompute from its own task table, over
+  every lane, before and after the batch.
+"""
+from __future__ import annotations
+
+import numpy as np
+
+import reference as ref
+
+EVENT_FIELDS = ("node_active", "node_total", "node_attrs", "task_req",
+                "task_usage", "task_prio", "task_job", "task_constraints",
+                "window")
+COUNTERS = ("evictions", "completions", "placements", "overflow_drops")
+USED_COLS = list(ref.USED_COLS)
+
+
+def _lanes(tree, single: bool, axis: int = 0):
+    """Give a one-trajectory state or stats rows a lane axis."""
+    if not single:
+        return tree
+    return {k: np.expand_dims(np.asarray(v), axis) for k, v in tree.items()}
+
+
+def as_dict(state) -> dict:
+    if isinstance(state, dict):
+        return {f: np.asarray(state[f]) for f in ref.STATE_FIELDS}
+    return {f: np.asarray(getattr(state, f)) for f in ref.STATE_FIELDS}
+
+
+def drift(s: dict) -> float:
+    """Widest |tally - float64 recompute| / capacity over nodes, resources
+    and lanes."""
+    worst = 0.0
+    for i in range(s["task_state"].shape[0]):
+        run = s["task_state"][i] == ref.RUNNING
+        node = s["task_node"][i][run]
+        N = s["node_total"].shape[1]
+        cap = np.maximum(s["node_total"][i].astype(np.float64), 1e-6)
+        for tally, vals in (("node_reserved", s["task_req"][i][run]),
+                            ("node_used",
+                             s["task_usage"][i][run][:, USED_COLS])):
+            want = np.zeros((N, vals.shape[1]))
+            np.add.at(want, node, vals.astype(np.float64))
+            got = s[tally][i].astype(np.float64)
+            live = s["node_active"][i][:, None]
+            gap = np.where(live, np.abs(got - want) / cap, 0.0)
+            worst = max(worst, float(gap.max(initial=0.0)))
+    return worst
+
+
+def reference_batch(cfg, lane: dict, s_before: dict, windows: dict,
+                    seed: int, dtype=None):
+    """The reference's (state after, stats rows) for one lane."""
+    import jax
+    import jax.numpy as jnp
+    out = ref.run_batch(
+        {k: jnp.asarray(v) for k, v in s_before.items()},
+        {k: jnp.asarray(v) for k, v in windows.items()}, seed,
+        np.float32(lane.get("node_outage_frac", 0.0)),
+        np.float32(lane.get("capacity_scale", 1.0)),
+        salt=int(cfg.scenario_salt),
+        sched=lane["scheduler"],
+        dims=(cfg.max_nodes, cfg.sched_batch, cfg.max_tasks),
+        dtype=dtype or jnp.float32)
+    return jax.tree.map(np.asarray, out)
+
+
+def control_outputs(cfg, lanes, before, windows, seed):
+    """The control: the reference in the program's place, with scores and
+    tallies in bfloat16, one step below the float32 the configuration
+    states. Returns (state after, stats rows) shaped as the program's."""
+    import jax.numpy as jnp
+    b = as_dict(before)
+    single = np.ndim(b["window"]) == 0
+    if single:
+        b = _lanes(b, True)
+    outs = [reference_batch(cfg, lane, {k: v[i] for k, v in b.items()},
+                            windows, seed, dtype=jnp.bfloat16)
+            for i, lane in enumerate(lanes)]
+    after = {k: np.stack([o[0][k] for o in outs]) for k in outs[0][0]}
+    rows = {k: np.stack([o[1][k] for o in outs], axis=1) for k in outs[0][1]}
+    if single:
+        after = {k: v[0] for k, v in after.items()}
+        rows = {k: v[:, 0] for k, v in rows.items()}
+    return after, rows
+
+
+def _rel(a, b) -> float:
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    den = np.maximum(np.abs(b), 1e-6)
+    return float((np.abs(a - b) / den).max(initial=0.0))
+
+
+def lane_numbers(cfg, lane, before_i, after_i, rows_i, windows, seed) -> dict:
+    r_state, r_rows = reference_batch(cfg, lane, before_i, windows, seed)
+    n = {"events_differ": 0, "decisions_differ": 0, "tallies_differ": 0,
+         "stats_differ": 0, "stats_gap": 0.0}
+    for f in EVENT_FIELDS:
+        n["events_differ"] += int(np.sum(np.asarray(after_i[f])
+                                         != r_state[f]))
+    for f in ("task_state", "task_node"):
+        n["decisions_differ"] += int(np.sum(after_i[f] != r_state[f]))
+    for f in COUNTERS:
+        n["decisions_differ"] += int(abs(int(after_i[f]) - int(r_state[f])))
+    n["tallies_differ"] = int(np.sum(after_i["node_reserved"]
+                                     != r_state["node_reserved"]))
+    for k, got in rows_i.items():
+        got = np.asarray(got)
+        want = r_rows.get(k, np.zeros_like(got))
+        if got.shape != want.shape:
+            n["stats_differ"] += max(got.size, want.size)
+        elif got.dtype.kind in "iub":
+            n["stats_differ"] += int(np.sum(got != want))
+        else:
+            n["stats_gap"] = max(n["stats_gap"], _rel(got, want))
+    return n
+
+
+def compare(cfg, lanes, before, after, rows, windows, seed,
+            limits) -> dict:
+    """Every number, the worst over the lanes, with its limit; ``correct``
+    when none is over its limit."""
+    b, a = as_dict(before), as_dict(after)
+    single = len(lanes) == 1 and np.ndim(b["window"]) == 0
+    if single:
+        b, a = _lanes(b, True), _lanes(a, True)
+        rows = _lanes(rows, True, axis=1)
+    worst = {}
+    failed = 0
+    for i in range(len(lanes)):
+        n = lane_numbers(cfg, lanes[i], {k: v[i] for k, v in b.items()},
+                         {k: v[i] for k, v in a.items()},
+                         {k: np.asarray(v)[:, i] for k, v in rows.items()},
+                         windows, seed)
+        failed += any(n[k] > limits[k] for k in n)
+        for k, v in n.items():
+            worst[k] = max(worst.get(k, v), v)
+    worst["tally_drift"] = max(drift(b), drift(a))
+    failed = max(failed, int(worst["tally_drift"] > limits["tally_drift"]))
+    numbers = {k: {"value": worst[k], "limit": limits[k]}
+               for k in ("events_differ", "decisions_differ",
+                         "tallies_differ", "stats_differ", "stats_gap",
+                         "tally_drift")}
+    return {"correct": all(v["value"] <= v["limit"]
+                           for v in numbers.values()),
+            "failed_lanes": failed, "numbers": numbers}
