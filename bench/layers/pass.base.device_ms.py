@@ -1,0 +1,7 @@
+"""Device time of the window program's ``pass.base`` scope (op self time,
+``scopes.py``) per lane-window advanced in the traced trajectory."""
+import scopes
+
+
+def read(ctx):
+    return scopes.pass_ms(ctx, "pass.base")

@@ -1,6 +1,6 @@
 """Benchmark driver — one module per paper table/figure + framework extras.
 Prints ``name,us_per_call,derived`` CSV rows (derived is benchmark-specific:
-speed factor, tasks/s, feature flag, roofline fraction, ...)."""
+speed factor, tasks/s, feature flag, ...)."""
 from __future__ import annotations
 
 import sys
@@ -9,9 +9,8 @@ import time
 
 def main() -> None:
     from benchmarks import (fig7_scaling, ingest_bench, kernels_bench,
-                            roofline_bench, scenarios_bench,
-                            schedulers_bench, service_bench, table2_features,
-                            throughput)
+                            scenarios_bench, schedulers_bench, service_bench,
+                            table2_features, throughput)
     suites = [
         ("table2_features", table2_features),   # paper Table II
         ("kernels", kernels_bench),
@@ -20,7 +19,6 @@ def main() -> None:
         ("fig7_scaling", fig7_scaling),         # paper Fig. 7
         ("throughput", throughput),             # paper §IV/§VI claims
         ("ingest", ingest_bench),               # streaming vs legacy writer
-        ("roofline", roofline_bench),           # framework §Roofline
         ("service", service_bench),             # what-if serving loop
     ]
     rows = []

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 
 from repro.config import SimConfig
 from repro.core import stats as stats_mod
+from repro.core import trace
 from repro.core.events import REMOVE_REASON_EVICT, EventKind, EventWindow
 from repro.core.ordered import ordered_scatter_add
 from repro.core.state import (SimState, TASK_EMPTY, TASK_PENDING,
@@ -60,6 +61,7 @@ def _masked_slot(mask: jax.Array, slot: jax.Array, overflow: int) -> jax.Array:
     return jnp.where(mask, slot, overflow)
 
 
+@trace.scoped(trace.EVENTS)
 def apply_node_events(state: SimState, w: EventWindow, cfg: SimConfig
                       ) -> SimState:
     # node events never move accounting: a capacity change or removal leaves
@@ -90,6 +92,7 @@ def apply_node_events(state: SimState, w: EventWindow, cfg: SimConfig
                           node_attrs=node_attrs)
 
 
+@trace.scoped(trace.EVENTS)
 def apply_task_events(state: SimState, w: EventWindow, cfg: SimConfig
                       ) -> SimState:
     T = cfg.max_tasks
@@ -166,6 +169,7 @@ def apply_task_events(state: SimState, w: EventWindow, cfg: SimConfig
         evictions=state.evictions + n_evict)
 
 
+@trace.scoped(trace.EVENTS)
 def evict_invalid(state: SimState, cfg: SimConfig) -> SimState:
     """Evict running tasks whose placement became invalid:
 
@@ -198,6 +202,7 @@ def evict_invalid(state: SimState, cfg: SimConfig) -> SimState:
     return state
 
 
+@trace.scoped(trace.EVENTS)
 def recompute_accounting(state: SimState, cfg: SimConfig) -> SimState:
     """node_reserved / node_used from the task table (segment usage).
 

@@ -35,6 +35,7 @@ import numpy as np
 
 from repro.config import SimConfig
 from repro.core import engine as engine_mod
+from repro.core import trace
 from repro.core.events import EventWindow, stack_windows
 from repro.core.state import SimState, init_state
 
@@ -94,6 +95,8 @@ class WindowPrefetcher:
         self._lock = threading.Lock()
         self._events_in = 0       # produced into the buffer (fill thread)
         self._events_out = 0      # consumed by the driver (main thread)
+        self._batches_in = 0      # batches put (fill thread): the next id
+        self.batch_id = -1        # id of the batch last handed out
         self._thread = threading.Thread(target=self._fill, daemon=True)
         self._thread.start()
 
@@ -121,17 +124,33 @@ class WindowPrefetcher:
         # after device_put returns, so either way the next batch would
         # rewrite the slot under an in-flight batch. The transfer runs here
         # on the fill thread, overlapping device compute of earlier batches.
-        dev = jax.device_put(jax.tree.map(np.array, item))
+        i = self._batches_in
+        with trace.span("feed.h2d", batch=i, windows=int(item.kind.shape[0]),
+                        rows=n, bytes=sum(int(np.asarray(f).nbytes)
+                                          for f in item)):
+            dev = jax.device_put(jax.tree.map(np.array, item))
         with self._lock:
             self._events_in += n
-        self._q.put((dev, n))
+        self._batches_in += 1
+        self._q.put((dev, n, i))
+
+    def _windows(self):
+        """The source's items, each read inside a ``feed.source`` span
+        tagged with the batch it goes into."""
+        src = iter(self._src)
+        while True:
+            with trace.span("feed.source", batch=self._batches_in):
+                w = next(src, self._done)
+            if w is self._done:
+                return
+            yield w
 
     def _fill(self):
         batch: List[EventWindow] = []
         pool: Optional[_StagingPool] = None
         end = self._done
         try:
-            for w in self._src:
+            for w in self._windows():
                 if w.kind.ndim == 2:          # pre-stacked (W, E) batch
                     if batch:                 # keep arrival order
                         self._put(pool.stack(batch))
@@ -153,14 +172,16 @@ class WindowPrefetcher:
 
     def __iter__(self):
         while True:
-            item = self._q.get()
+            # queued == 0 on entry: the device side waits on the feed
+            with trace.span("drive.wait_feed", queued=self._q.qsize()):
+                item = self._q.get()
             if item is self._done:
                 return
             if isinstance(item, BaseException):
                 # a source that failed mid-trace must fail the run, not
                 # end it early as if the trace were complete
                 raise item
-            dev, n = item
+            dev, n, self.batch_id = item
             with self._lock:
                 self._events_out += n
             yield dev
@@ -250,7 +271,9 @@ class WindowedDriver:
             while self._paused.is_set():
                 time.sleep(0.01)
             W = batch.kind.shape[0]
-            stats = self._advance(batch, self.seed + self.windows_done)
+            with trace.span("drive.dispatch", batch=self.prefetcher.batch_id,
+                            windows=W, inflight=len(self._inflight)):
+                stats = self._advance(batch, self.seed + self.windows_done)
             k = self.cfg.stats_stride
             m, r = divmod(W, k)
             self._row_windows.extend(
@@ -260,11 +283,15 @@ class WindowedDriver:
             self.stats_rows.append(stats)
             self._inflight.append(stats)
             if len(self._inflight) > self.max_inflight_batches:
-                jax.block_until_ready(self._inflight.popleft())
+                with trace.span("drive.backpressure",
+                                inflight=len(self._inflight)):
+                    jax.block_until_ready(self._inflight.popleft())
             if resync_every:
                 self._since_resync += W
                 if self._since_resync >= resync_every:
-                    self.state = self._resync()
+                    with trace.span("drive.resync",
+                                    windows_done=self.windows_done):
+                        self.state = self._resync()
                     self.resyncs_done += 1
                     self._since_resync = 0
             if on_batch is not None:
@@ -277,7 +304,8 @@ class WindowedDriver:
                     time.sleep(lag)
             if max_windows is not None and self.windows_done >= max_windows:
                 break
-        jax.block_until_ready(self.state)
+        with trace.span("drive.drain", windows_done=self.windows_done):
+            jax.block_until_ready(self.state)
         return self.state
 
     def stats_window_indices(self) -> np.ndarray:

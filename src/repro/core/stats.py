@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import SimConfig
+from repro.core import trace
 from repro.core.state import SimState, TASK_PENDING, TASK_RUNNING
 from repro.kernels.window_stats.ops import window_reductions
 
@@ -46,6 +47,7 @@ USAGE_NAMES = ("cpu_rate", "canonical_mem", "assigned_mem", "page_cache",
 ACCOUNTED_USAGE_COLS = (U_CPU, U_CANON_MEM, U_DISK_SPACE)
 
 
+@trace.scoped(trace.STATS)
 def window_stats(state: SimState, cfg: SimConfig) -> Dict[str, jax.Array]:
     """One stats row from the current state (fused path; see module doc)."""
     if not cfg.fused_window_stats:

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import os
 import pathlib
+import re
 
 import jax
 
@@ -42,9 +43,18 @@ def enable_compile_cache() -> str:
     inside the checkout (``<repo>/.jax_cache``, listed in .gitignore): the
     path is part of a cache entry's key, so only a directory that never
     moves is ever read back by the next run. Every compile is cached,
-    however short."""
+    however short, under a key that includes the op metadata (with file
+    names relative to the checkout)."""
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # a device trace names each op by the metadata its executable was
+    # compiled with (the pass scopes of core/trace.py): an executable cached
+    # from a build with other metadata would report other or no passes
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    # the metadata holds source file names: name those of this checkout
+    # from its root, so a checkout that moves still reads its entries back
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(str(REPO_ROOT) + os.sep))
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

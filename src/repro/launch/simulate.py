@@ -25,7 +25,7 @@ import numpy as np
 from repro.config import SimConfig, REDUCED_SIM
 from repro.configs import get_sim_config
 from repro.core import precompile as precompile_mod
-from repro.core import tracegen
+from repro.core import trace, tracegen
 from repro.core.pipeline import Simulation
 from repro.core.snapshot import save_snapshot
 from repro.core.state import validate_invariants
@@ -87,6 +87,9 @@ def main(argv=None):
                     help="write a pausable snapshot here at the end")
     ap.add_argument("--batch-windows", type=int, default=32)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--profile-dir", default=None,
+                    help="write a profiler trace of the run here (open it "
+                         "in TensorBoard's profile plugin or Perfetto)")
     ap.add_argument("--platform", default=None,
                     choices=("cpu", "tpu"),
                     help="pin the jax backend (default: auto-detect)")
@@ -145,11 +148,14 @@ def main(argv=None):
         parser = parser_cls(cfg, trace_dir)
         source = parser.packed_windows(args.windows, start_us=start_us)
 
-    sim = Simulation(cfg, source, scheduler=args.scheduler,
-                     batch_windows=args.batch_windows, seed=args.seed)
-    t0 = time.time()
-    state = sim.run()
-    wall = time.time() - t0
+    # the profiler covers the driver from its start: its feed thread
+    # begins filling as soon as it is built
+    with trace.profile(args.profile_dir):
+        sim = Simulation(cfg, source, scheduler=args.scheduler,
+                         batch_windows=args.batch_windows, seed=args.seed)
+        t0 = time.time()
+        state = sim.run()
+        wall = time.time() - t0
     sf = sim.stats_frame()
     sim_seconds = sim.windows_done * cfg.window_us / 1e6
     print(f"simulated {sim.windows_done} windows ({sim_seconds:.0f} sim-s) "

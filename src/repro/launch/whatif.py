@@ -39,7 +39,7 @@ import time
 
 from repro.config import SimConfig, REDUCED_SIM
 from repro.configs import get_sim_config
-from repro.core import tracegen
+from repro.core import trace, tracegen
 from repro.core.precompile import (overflow_warning, precompile_trace,
                                    stack_parse_stats)
 from repro import parsers as trace_parsers
@@ -165,6 +165,9 @@ def main(argv=None):
     ap.add_argument("--json", default=None, help="write full report here")
     ap.add_argument("--snapshot", default=None,
                     help="write a batched fleet snapshot here at the end")
+    ap.add_argument("--profile-dir", default=None,
+                    help="write a profiler trace of the run here (open it "
+                         "in TensorBoard's profile plugin or Perfetto)")
     ap.add_argument("--platform", default=None,
                     choices=("cpu", "tpu"),
                     help="pin the jax backend (default: auto-detect)")
@@ -246,27 +249,31 @@ def main(argv=None):
             print(warn)
         replay_path = args.precompile
 
+    if replay_path is not None and args.start_window:
+        from repro.core.precompile import stack_n_windows
+        n_stack = stack_n_windows(replay_path)
+        if args.start_window < 0 or args.start_window >= n_stack:
+            ap.error(f"--start-window {args.start_window} is outside "
+                     f"the stack's [0, {n_stack})")
+    if replay_path is None and args.start_window:
+        ap.error("--start-window needs --replay (a chunked stack)")
+
     t0 = time.time()
-    if replay_path is not None:
-        if args.start_window:
-            from repro.core.precompile import stack_n_windows
-            n_stack = stack_n_windows(replay_path)
-            if args.start_window < 0 or args.start_window >= n_stack:
-                ap.error(f"--start-window {args.start_window} is outside "
-                         f"the stack's [0, {n_stack})")
-        fleet = ScenarioFleet.from_precompiled(
-            cfg, replay_path, specs, batch_windows=args.batch_windows,
-            seed=args.seed, mesh=mesh, n_windows=args.windows,
-            start_window=args.start_window)
-    else:
-        if args.start_window:
-            ap.error("--start-window needs --replay (a chunked stack)")
-        parser = parser_cls(cfg, trace_dir)
-        source = parser.packed_windows(args.windows, start_us=start)
-        fleet = ScenarioFleet(cfg, source, specs,
-                              batch_windows=args.batch_windows,
-                              seed=args.seed, mesh=mesh)
-    fleet.run()
+    # the profiler covers the fleet from its start: its feed thread
+    # begins filling as soon as it is built
+    with trace.profile(args.profile_dir):
+        if replay_path is not None:
+            fleet = ScenarioFleet.from_precompiled(
+                cfg, replay_path, specs, batch_windows=args.batch_windows,
+                seed=args.seed, mesh=mesh, n_windows=args.windows,
+                start_window=args.start_window)
+        else:
+            parser = parser_cls(cfg, trace_dir)
+            source = parser.packed_windows(args.windows, start_us=start)
+            fleet = ScenarioFleet(cfg, source, specs,
+                                  batch_windows=args.batch_windows,
+                                  seed=args.seed, mesh=mesh)
+        fleet.run()
     if replay_path is None:
         warn = overflow_warning(parser.stats)
         if warn:

@@ -37,6 +37,7 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 from repro.config import SimConfig
 from repro.core import engine as eng
 from repro.core import stats as stats_mod
+from repro.core import trace
 from repro.core.events import EventWindow
 from repro.sched import (DispatchTable, base_pass, finalize,
                          make_switchless_dispatch, snapshot_dispatch)
@@ -159,18 +160,19 @@ def make_scenario_advance(cfg: SimConfig, scheduler_names: Tuple[str, ...],
 
     def dispatch(state: SimState, rng: jax.Array, idx: jax.Array) -> SimState:
         pend_idx, valid, base_ok, scores = base_pass(state, cfg)
-        if len(proposers) == 1:     # no switch needed — keeps lane 0 trivial
-            pref = proposers[0](state, cfg, rng, pend_idx, valid, base_ok,
-                                scores)
-            return finalize(state, cfg, pend_idx, valid, base_ok, pref,
-                            dynamic_bestfit=table.dynamic[0])
-        pref = jax.lax.switch(
-            idx,
-            [lambda s, r, pi, v, bo, sc, fn=fn: fn(s, cfg, r, pi, v, bo, sc)
-             for fn in proposers],
-            state, rng, pend_idx, valid, base_ok, scores)
+        with trace.scope(trace.PROPOSE):
+            if len(proposers) == 1:  # no switch: keeps lane 0 trivial
+                pref = proposers[0](state, cfg, rng, pend_idx, valid,
+                                    base_ok, scores)
+            else:
+                pref = jax.lax.switch(
+                    idx,
+                    [lambda s, r, pi, v, bo, sc, fn=fn:
+                     fn(s, cfg, r, pi, v, bo, sc) for fn in proposers],
+                    state, rng, pend_idx, valid, base_ok, scores)
+        dyn = table.dynamic[0] if len(proposers) == 1 else dyn_table[idx]
         return finalize(state, cfg, pend_idx, valid, base_ok, pref,
-                        dynamic_bestfit=dyn_table[idx])
+                        dynamic_bestfit=dyn)
 
     def advance(state: SimState, w: EventWindow, rng: jax.Array,
                 knobs: ScenarioKnobs) -> Tuple[SimState, jax.Array]:

@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import SimConfig
+from repro.core import trace
 from repro.core.events import EventKind, EventWindow
 from repro.core.ordered import ordered_scatter_add
 from repro.core.state import SimState, TASK_EMPTY, TASK_PENDING, TASK_RUNNING
@@ -81,6 +82,7 @@ _TASK_KINDS = (EventKind.ADD_TASK, EventKind.UPDATE_TASK_REQUIRED,
                EventKind.REMOVE_TASK)
 
 
+@trace.scoped(trace.EVENTS)
 def perturb_window(w: EventWindow, k: ScenarioKnobs, cfg: SimConfig,
                    window: jax.Array = None) -> EventWindow:
     """Apply one scenario's event-stream transforms to one window.
@@ -198,6 +200,7 @@ def inject_arrivals(w: EventWindow, k: ScenarioKnobs, cfg: SimConfig,
     )
 
 
+@trace.scoped(trace.EVENTS)
 def expire_injected(state: SimState, k: ScenarioKnobs, cfg: SimConfig
                     ) -> SimState:
     """Injected-task lifecycles: synthesised REMOVEs after a sampled duration.
@@ -322,6 +325,7 @@ def storm_debit(state: SimState, victim: jax.Array, cum, cfg: SimConfig
                                       -state.task_usage[rows][:, ucols]))
 
 
+@trace.scoped(trace.EVENTS)
 def storm_evict(state: SimState, k: ScenarioKnobs, cfg: SimConfig) -> SimState:
     """Per-window eviction storm: force a hashed fraction of running tasks
     (up to ``cfg.resolved_storm_max_victims``) back to pending.

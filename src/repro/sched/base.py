@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import SimConfig
+from repro.core import trace
 from repro.core.state import SimState, TASK_PENDING
 from repro.kernels.constraint_match.ops import constraint_match
 
@@ -43,6 +44,7 @@ def pending_batch(state: SimState, cfg: SimConfig):
     return idx, valid
 
 
+@trace.scoped(trace.BASE)
 def base_pass(state: SimState, cfg: SimConfig):
     """Pending batch + constraint-match scores: (idx, valid, base_ok, scores).
 

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import SimConfig
+from repro.core import trace
 from repro.core.stats import ACCOUNTED_USAGE_COLS
 from repro.core.state import SimState, TASK_RUNNING
 from repro.kernels.placement_commit.ops import placement_commit
@@ -32,6 +33,7 @@ def commit_operands(state: SimState, cfg: SimConfig, idx):
     return total, denom, req
 
 
+@trace.scoped(trace.COMMIT)
 def finalize(state: SimState, cfg: SimConfig, idx, valid, base_ok, pref,
              dynamic_bestfit=False) -> SimState:
     """Sequential capacity-checked assignment in priority order.

@@ -50,6 +50,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, List, Optional, Tuple
 
+from repro.core import trace
 from repro.sched.base import base_pass
 from repro.sched.commit import finalize
 from repro.sched.table import DispatchTable, TableForm
@@ -106,7 +107,8 @@ def register_scheduler(name: str, propose: Callable, *,
 
     def scheduler(state, cfg, rng):
         idx, valid, base_ok, scores = base_pass(state, cfg)
-        pref = propose(state, cfg, rng, idx, valid, base_ok, scores)
+        with trace.scope(trace.PROPOSE):
+            pref = propose(state, cfg, rng, idx, valid, base_ok, scores)
         return finalize(state, cfg, idx, valid, base_ok, pref,
                         dynamic_bestfit=dynamic_bestfit)
 

@@ -32,6 +32,7 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import trace
 from repro.kernels.placement_commit.kernel import (FAM_EXTERNAL,
                                                    FAM_NODE_ORDER,
                                                    FAM_SCORES)
@@ -167,6 +168,35 @@ def make_switchless_dispatch(cfg, table: DispatchTable,
         return jax.vmap(
             lambda c: form.transform(cfg, c, rng, form.params))(sub)
 
+    @trace.scoped(trace.PROPOSE)
+    def external_prefs(ctx, rng):
+        """The fused path's materialised preferences: the external
+        families' rows stacked, and each lane's row in the stack."""
+        ext_parts, ext_row, n_rows = [], [0] * B, 0
+        for form, lanes in groups.items():
+            if form.fused != FAM_EXTERNAL:
+                continue
+            ext_parts.append(eval_family(form, lanes, ctx, rng))
+            for j, lane in enumerate(lanes):
+                ext_row[lane] = n_rows + j
+            n_rows += len(lanes)
+        ext = jnp.concatenate(ext_parts, axis=0) if ext_parts else None
+        return ext, tuple(ext_row)
+
+    @trace.scoped(trace.PROPOSE)
+    def lane_prefs(ctx, rng):
+        """The reference path's (B, P, N) preference stack: each family
+        over its lanes, reassembled by static inverse permutation."""
+        order, parts = [], []
+        for form, lanes in groups.items():
+            order.extend(lanes)
+            parts.append(eval_family(form, lanes, ctx, rng))
+        pref_B = jnp.concatenate(parts, axis=0)
+        inv = sorted(range(B), key=order.__getitem__)
+        if inv != list(range(B)):
+            pref_B = pref_B[jnp.asarray(inv)]
+        return pref_B
+
     def dispatch(state_B, rng):
         idx, valid, base_ok, scores = jax.vmap(
             base_pass, in_axes=(0, None))(state_B, cfg)
@@ -186,39 +216,23 @@ def make_switchless_dispatch(cfg, table: DispatchTable,
                     for f in forms]
             start_B = (state_B.window * jnp.asarray(rots, jnp.int32)) \
                 % cfg.max_nodes
-            ext_parts, ext_row, n_rows = [], [0] * B, 0
-            for form, lanes in groups.items():
-                if form.fused != FAM_EXTERNAL:
-                    continue
-                ext_parts.append(eval_family(form, lanes, ctx, rng))
-                for j, lane in enumerate(lanes):
-                    ext_row[lane] = n_rows + j
-                n_rows += len(lanes)
-            ext = (jnp.concatenate(ext_parts, axis=0)
-                   if ext_parts else None)
-            total_B, denom_B, _ = jax.vmap(
-                lambda s, i: commit_operands(s, cfg, i))(state_B, idx)
-            node_of, tally = sched_commit_fleet(
-                scores, base_ok, req, valid, total_B, denom_B,
-                state_B.node_reserved, start_B, fam=fam, dynamic=dynamic,
-                ext=ext, ext_row=tuple(ext_row), tile_p=tile_p,
-                tile_n=tile_n)
-            return jax.vmap(
-                lambda s, i, n, t: apply_commit(s, cfg, i, n, t)
-            )(state_B, idx, node_of, tally)
+            ext, ext_row = external_prefs(ctx, rng)
+            with trace.scope(trace.COMMIT):
+                total_B, denom_B, _ = jax.vmap(
+                    lambda s, i: commit_operands(s, cfg, i))(state_B, idx)
+                node_of, tally = sched_commit_fleet(
+                    scores, base_ok, req, valid, total_B, denom_B,
+                    state_B.node_reserved, start_B, fam=fam,
+                    dynamic=dynamic, ext=ext, ext_row=ext_row,
+                    tile_p=tile_p, tile_n=tile_n)
+                return jax.vmap(
+                    lambda s, i, n, t: apply_commit(s, cfg, i, n, t)
+                )(state_B, idx, node_of, tally)
 
-        # reference path: evaluate each family over its lanes, reassemble
-        # the (B, P, N) preference stack by static inverse permutation,
-        # commit with one vmapped finalize (traced per-lane dyn flags —
-        # bitwise-equal to the static selection)
-        order, parts = [], []
-        for form, lanes in groups.items():
-            order.extend(lanes)
-            parts.append(eval_family(form, lanes, ctx, rng))
-        pref_B = jnp.concatenate(parts, axis=0)
-        inv = sorted(range(B), key=order.__getitem__)
-        if inv != list(range(B)):
-            pref_B = pref_B[jnp.asarray(inv)]
+        # reference path: commit the lanes' preference stack with one
+        # vmapped finalize (traced per-lane dyn flags — bitwise-equal to
+        # the static selection)
+        pref_B = lane_prefs(ctx, rng)
         dyn_B = jnp.asarray(dynamic)
         return jax.vmap(
             lambda s, i, v, ok, p, d: finalize(s, cfg, i, v, ok, p,
