@@ -13,7 +13,12 @@ slots, 8,192 events per window, sched_batch 1,024):
   a       one greedy trajectory on the reference path (``Simulation``, as
           ``launch/simulate`` runs it), then ``validate_invariants``;
   b       the same trajectory with ``use_kernels=True`` (compiled Pallas
-          kernels): every decision bitwise equal to a;
+          kernels): every decision bitwise equal to a. The placement commit
+          is the compiled kernel on a TPU whatever ``use_kernels`` says, so
+          this a/b comparison no longer covers it: the commit's on-chip
+          check is ``bench/reference.py`` (the benchmark's ``correct``),
+          and phase c's chip-versus-CPU comparison, whose CPU side runs
+          the jnp reference commit;
   c       chip versus CPU in this process: the first windows of a again on
           ``jax.devices("cpu")[0]``; and a fleet with one lane per built-in
           scheduler, run on the chip to the end, whose last windows run

@@ -282,6 +282,12 @@ class SimConfig:
     seed: int = 0
     use_kernels: bool = False         # Pallas kernels (compiled on a TPU,
                                       # interpreted on the CPU) vs jnp ref
+                                      # for constraint match, window stats
+                                      # and segment usage. The placement
+                                      # commit is the compiled kernel on a
+                                      # TPU either way; on the CPU this
+                                      # picks its interpreted kernel over
+                                      # the jnp ref
     incremental_accounting: bool = True
                                       # maintain node_reserved/node_used by
                                       # per-event deltas (O(events) per window)

@@ -27,6 +27,17 @@ def by_platform(fn, *args):
         default=functools.partial(fn, interpret=True))
 
 
+def on_tpu(kernel, default, *args):
+    """``kernel(*args)`` when the call lowers for a TPU, ``default(*args)``
+    for any other platform: the same lowering-time choice as
+    :func:`by_platform`, for a pass whose TPU path is always its kernel
+    (compiled there by ``by_platform`` inside ``kernel``) and whose other
+    platforms keep a reference. ``default is kernel`` makes no choice."""
+    if default is kernel:
+        return kernel(*args)
+    return jax.lax.platform_dependent(*args, tpu=kernel, default=default)
+
+
 def vmem_limit(blocks, headroom: int = 4 << 20) -> int:
     """Scoped-VMEM request for a kernel whose blocks are ``blocks`` — a
     list of ``(shape, dtype)``. Each block is double-buffered and padded to

@@ -22,23 +22,28 @@ per-lane start (first-fit / round-robin), or a pre-evaluated external row
 (opaque proposals). So the (B, P, N) preference tensor of the fused
 families never exists in HBM.
 
-Layout (what ops.py hands in — node columns on the 128-wide lane axis,
-scenario lanes on sublanes, task rows and resource columns on untiled
-leading axes, so every per-task value is a 2-D (B, nodes) tile):
+Layout (what ops.py hands in). Row operands are read straight from the
+(lanes, task rows, nodes) arrays the base pass and the proposals produce,
+so nothing is transposed in HBM: a block is (B|1, TP, TN), and one task
+row of every lane at once is ``ref[:, t, :]``, a (B, TN) tile with the
+lanes on sublanes and the nodes on lanes (a sublane-strided load):
 
-* row operands (P, B|1, Np): scores, ok (i32), ext; req (P, B|1, R);
-  valid (P, B|1, 1) i32;
+* row operands (B, P, N), or (P, N) where every lane shares one: scores,
+  ok (i32; absent where the fit mask is ``isfinite(scores)``, as the base
+  pass defines it), ext; req (B|1, P, R); valid (B|1, P, 1) i32;
 * node operands (R, K, B|1, TN): total (dead / padded nodes = -1), denom,
   reserved0 — the node axis cut into K tiles of TN columns;
-* dyn (B, 1) i32 per-lane dynamic-best-fit flags; start (B|1, 1) i32.
+* dyn (B, 1) i32 per-lane dynamic-best-fit flags; start (B|1, 1) i32;
+* out: node_of (B, P) i32, lane-dense and resident, one column written per
+  task; reserved (R, K, B, TN) f32.
 
-The node axis is either whole (K = 1; any number of task rows per grid
-step, looped in the body) or streamed in K > 1 tiles of TN columns (one
-task row per step, grid (P, K)) with a cross-tile running argmax carried
-in revisited output blocks: tile 0 is adopted unconditionally, later tiles
-only on a STRICT improvement, which preserves the reference's global
-first-index tie-break including the all ``-inf`` row (node 0, placed iff it
-fits). Fit is carried with the score, not re-derived from it.
+The node axis is either whole (K = 1; TP task rows per grid step, looped in
+the body) or streamed in K > 1 tiles of TN columns (one task row per step,
+grid (P, K)) with a cross-tile running argmax carried in revisited output
+blocks: tile 0 is adopted unconditionally, later tiles only on a STRICT
+improvement, which preserves the reference's global first-index tie-break
+including the all ``-inf`` row (node 0, placed iff it fits). Fit is carried
+with the score, not re-derived from it.
 
 The assignment semantics are bit-identical to ``ref.placement_commit_ref``
 per lane: same fit epsilon, same score expressions (the re-score sums its
@@ -101,17 +106,25 @@ def _family_pref(scores, no, ext, fam, ext_row, n_lanes):
     return pref
 
 
-def _commit_kernel(scores_ref, ok_ref, req_ref, valid_ref, total_ref,
-                   denom_ref, res0_ref, dyn_ref, start_ref, *rest,
-                   mode: str, n_lanes: int, fam, ext_row, n_real: int,
-                   n_tiles: int, n_rows: int):
-    has_ext = any(f == FAM_EXTERNAL for f in fam)
-    ext_ref = rest[0] if has_ext else None
-    outs = rest[1:] if has_ext else rest
+def _row(ref, t):
+    """Task row t of a row block, every lane at once: (B|1, TN). A
+    lane-shared operand comes as a 2-D (TP, TN) block."""
+    return ref[pl.ds(t, 1), :] if len(ref.shape) == 2 else ref[:, t, :]
+
+
+def _commit_kernel(*refs, mode: str, n_lanes: int, fam, ext_row,
+                   n_real: int, n_tiles: int, n_rows: int, has_scores: bool,
+                   has_ok: bool, has_ext: bool):
+    refs = list(refs)
+    scores_ref = refs.pop(0) if has_scores else None
+    ok_ref = refs.pop(0) if has_ok else None
+    (req_ref, valid_ref, total_ref, denom_ref, res0_ref, dyn_ref,
+     start_ref) = refs[:7]
+    refs = refs[7:]
+    ext_ref = refs.pop(0) if has_ext else None
+    node_ref, res_ref = refs[:2]
     if n_tiles > 1:
-        node_ref, res_ref, bsc_ref, bn_ref, bfit_ref = outs
-    else:
-        node_ref, res_ref = outs
+        bsc_ref, bn_ref, bfit_ref = refs[2:]
     i, k = pl.program_id(0), pl.program_id(1)
     B, R = n_lanes, total_ref.shape[0]
     TN = total_ref.shape[-1]
@@ -121,6 +134,7 @@ def _commit_kernel(scores_ref, ok_ref, req_ref, valid_ref, total_ref,
         res_ref[...] = jnp.broadcast_to(res0_ref[...], res_ref.shape)
 
     lane_col = jax.lax.broadcasted_iota(jnp.int32, (B, TN), 1)
+    task_col = jax.lax.broadcasted_iota(jnp.int32, node_ref.shape, 1)
     tot = [total_ref[r, k] for r in range(R)]                 # (B|1, TN)
     den = [denom_ref[r, k] for r in range(R)] if mode != "static" else None
     dyn = dyn_ref[...] != 0                                   # (B, 1)
@@ -133,12 +147,17 @@ def _commit_kernel(scores_ref, ok_ref, req_ref, valid_ref, total_ref,
         no = -(((col - start_ref[...]) % n_real).astype(jnp.float32))
 
     def choose(t, res):
-        """Tile-local choice for task row t against tally tiles ``res``:
-        (tile best score, tile argmax column, fit there, req columns)."""
-        req_row = req_ref[t]                                  # (B|1, R)
+        """Tile-local choice for row t of the block against tally tiles
+        ``res``: (tile best score, tile argmax column, fit there, req
+        columns)."""
+        req_row = _row(req_ref, t)                            # (B|1, R)
         rq = [req_row[:, r:r + 1] for r in range(R)]          # (B|1, 1)
         free = [tot[r] - res[r] for r in range(R)]            # (B, TN)
-        fit = ok_ref[t] != 0
+        srow = _row(scores_ref, t) if has_scores else None
+        if has_ok:
+            fit = _row(ok_ref, t) != 0
+        else:
+            fit = jnp.abs(srow) < jnp.inf                     # isfinite
         for r in range(R):
             fit = fit & (rq[r] <= free[r] + 1e-9)
         fit = jnp.broadcast_to(fit, (B, TN))
@@ -146,8 +165,8 @@ def _commit_kernel(scores_ref, ok_ref, req_ref, valid_ref, total_ref,
             terms = [(free[r] - rq[r]) / den[r] for r in range(R)]
             sc_dyn = -functools.reduce(jnp.add, terms)
         if mode != "dynamic":
-            pref = _family_pref(scores_ref[t], no,
-                                ext_ref[t] if has_ext else None,
+            pref = _family_pref(srow, no,
+                                _row(ext_ref, t) if has_ext else None,
                                 fam, ext_row, B)
         if mode == "both":
             sc = jnp.where(dyn, sc_dyn, pref)
@@ -163,16 +182,18 @@ def _commit_kernel(scores_ref, ok_ref, req_ref, valid_ref, total_ref,
                          axis=1, keepdims=True)
         return best, loc, fit_at, rq
 
-    def place(t, best_n, best_fit, rq):
-        """Write row t's decision; return the (B, 1) per-column adds."""
-        can = (best_fit != 0) & (valid_ref[t] != 0)
-        node_ref[t] = jnp.where(can, best_n, -1)
+    def place(g, t, best_n, best_fit, rq):
+        """Write task g's decision (row t of the block) into its node_of
+        column; return the (B, 1) per-column adds."""
+        can = (best_fit != 0) & (_row(valid_ref, t) != 0)
+        node_ref[...] = jnp.where(task_col == g,
+                                  jnp.where(can, best_n, -1), node_ref[...])
         return [jnp.where(can, rq[r], 0.0) for r in range(R)]
 
     if n_tiles == 1:
         def row(t, res):
             _, loc, fit_at, rq = choose(t, res)
-            add = place(t, loc, fit_at, rq)
+            add = place(i * n_rows + t, t, loc, fit_at, rq)
             hit = lane_col == loc
             return tuple(jnp.where(hit, res[r] + add[r], res[r])
                          for r in range(R))
@@ -185,17 +206,19 @@ def _commit_kernel(scores_ref, ok_ref, req_ref, valid_ref, total_ref,
         return
 
     # node-streamed: one task row per grid step, argmax carried over tiles
-    best, loc, fit_at, rq = choose(0, [res_ref[r, k] for r in range(R)])
+    t = i % n_rows
+    best, loc, fit_at, rq = choose(t, [res_ref[r, k] for r in range(R)])
     adopt = (k == 0) | (best > bsc_ref[...])
     best_n = jnp.where(adopt, k * TN + loc, bn_ref[...])
     best_fit = jnp.where(adopt, fit_at, bfit_ref[...])
     bsc_ref[...] = jnp.where(adopt, best, bsc_ref[...])
     bn_ref[...] = best_n
     bfit_ref[...] = best_fit
-    add = place(0, best_n, best_fit, rq)
 
     @pl.when(k == n_tiles - 1)
     def _commit():
+        add = place(i, t, best_n, best_fit, rq)
+
         def tile(kk, carry):
             hit = kk * TN + lane_col == best_n
             for r in range(R):
@@ -210,38 +233,52 @@ def commit_pallas(scores, ok, req, valid, total, denom, reserved0, dyn,
                   start, ext, *, n_lanes: int, fam, ext_row, n_real: int,
                   mode: str, tile_p: int, interpret: bool):
     """Batched commit over ``n_lanes`` lanes in the kernel layout (module
-    doc). The node tiling is read off ``total`` (R, K, B|1, TN); K > 1
-    needs ``tile_p == 1``. Returns (node_of (P, B, 1) i32, reserved
+    doc); ``scores``, ``ok`` and ``ext`` may each be None where the mode
+    and families never read them (not both of scores and ok). The node
+    tiling is read off ``total`` (R, K, B|1, TN); row blocks hold
+    ``tile_p`` task rows. Returns (node_of (B, P) i32, reserved
     (R, K, B, TN) f32 — the final VMEM-resident tally)."""
-    P, Np = scores.shape[0], scores.shape[2]
+    P = req.shape[1]
     R, K, _, TN = total.shape
     assert mode in ("static", "dynamic", "both"), mode
     assert len(fam) in (1, n_lanes), (len(fam), n_lanes)
-    assert P % tile_p == 0 and K * TN == Np, (P, tile_p, K, TN, Np)
-    assert K == 1 or tile_p == 1, (K, tile_p)
+    assert scores is not None or ok is not None
+    assert P % tile_p == 0, (P, tile_p)
     B = n_lanes
+    # whole node axis: tile_p rows a step; streamed: one row a step, its
+    # block (the tile_p rows around it) fetched again for every tile
+    steps = P // tile_p if K == 1 else P
 
-    def row_spec(x):
-        return pl.BlockSpec((tile_p,) + x.shape[1:-1] + (TN,),
-                            lambda i, k: (i, 0, k))
+    def row_block(i):
+        return i if K == 1 else i // tile_p
+
+    def row_spec(x):                  # (B, P, Np) or lane-shared (P, Np)
+        if x.ndim == 2:
+            return pl.BlockSpec((tile_p, TN), lambda i, k: (row_block(i), k))
+        return pl.BlockSpec((x.shape[0], tile_p, TN),
+                            lambda i, k: (0, row_block(i), k))
+
+    def rows_of(x):                   # (B|1, P, C)
+        return pl.BlockSpec((x.shape[0], tile_p, x.shape[2]),
+                            lambda i, k: (0, row_block(i), 0))
 
     def whole(x):
         return pl.BlockSpec(x.shape, lambda i, k: (0,) * x.ndim)
 
-    operands = [scores, ok, req, valid, total, denom, reserved0, dyn, start]
-    in_specs = [row_spec(scores), row_spec(ok),
-                pl.BlockSpec((tile_p,) + req.shape[1:],
-                             lambda i, k: (i, 0, 0)),
-                pl.BlockSpec((tile_p,) + valid.shape[1:],
-                             lambda i, k: (i, 0, 0)),
-                whole(total), whole(denom), whole(reserved0), whole(dyn),
-                whole(start)]
+    operands, in_specs = [], []
+    for x in (scores, ok):
+        if x is not None:
+            operands.append(x)
+            in_specs.append(row_spec(x))
+    operands += [req, valid, total, denom, reserved0, dyn, start]
+    in_specs += [rows_of(req), rows_of(valid), whole(total), whole(denom),
+                 whole(reserved0), whole(dyn), whole(start)]
     if ext is not None:
         operands.append(ext)
         in_specs.append(row_spec(ext))
-    out_shape = [jax.ShapeDtypeStruct((P, B, 1), jnp.int32),
+    out_shape = [jax.ShapeDtypeStruct((B, P), jnp.int32),
                  jax.ShapeDtypeStruct((R, K, B, TN), jnp.float32)]
-    out_specs = [pl.BlockSpec((tile_p, B, 1), lambda i, k: (i, 0, 0)),
+    out_specs = [pl.BlockSpec((B, P), lambda i, k: (0, 0)),
                  pl.BlockSpec((R, K, B, TN), lambda i, k: (0, 0, 0, 0))]
     if K > 1:
         for dt in (jnp.float32, jnp.int32, jnp.int32):
@@ -250,18 +287,24 @@ def commit_pallas(scores, ok, req, valid, total, denom, reserved0, dyn,
 
     blocks = [(s.block_shape, x.dtype) for s, x in zip(in_specs, operands)]
     blocks += [(s.block_shape, o.dtype) for s, o in zip(out_specs, out_shape)]
-    kernel = functools.partial(_commit_kernel, mode=mode, n_lanes=B, fam=fam,
-                               ext_row=ext_row, n_real=n_real, n_tiles=K,
-                               n_rows=tile_p)
+    # the body's (B, TN) values (tally, capacities, scores, masks) spill
+    # to the kernel's own VMEM stack: room for 32 of them
+    spill = 32 * (-(-B // 8) * 8) * (-(-TN // 128) * 128) * 4
+    kernel = functools.partial(
+        _commit_kernel, mode=mode, n_lanes=B, fam=fam, ext_row=ext_row,
+        n_real=n_real, n_tiles=K, n_rows=tile_p,
+        has_scores=scores is not None, has_ok=ok is not None,
+        has_ext=ext is not None)
     out = pl.pallas_call(
         kernel,
-        grid=(P // tile_p, K),
+        grid=(steps, K),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=vmem_limit(blocks)),
+            vmem_limit_bytes=vmem_limit(blocks, (4 << 20) + spill)),
         interpret=interpret,
+        name="placement_commit",
     )(*operands)
     return out[0], out[1]

@@ -4,10 +4,12 @@ Proposals only rank nodes; *this* pass decides. An in-priority-order scan
 re-checks resource fit against the running reservation tally, so **no
 scheduler can overcommit a node** regardless of what it proposes — the
 engine invariant the tests verify. The scan itself lives in
-``kernels/placement_commit`` (Pallas kernel + jnp reference, dispatched on
-``cfg.use_kernels`` like every other kernelised pass); this module derives
-the kernel operands from the simulation state and applies the resulting
-assignment vector back to it.
+``kernels/placement_commit``: where the program lowers for a TPU it is
+always the compiled Pallas kernel (tally resident in VMEM); for any other
+platform it is the jnp reference, or the interpreted kernel where
+``cfg.use_kernels`` asks for it. This module derives the kernel operands
+from the simulation state and applies the resulting assignment vector
+back to it.
 """
 from __future__ import annotations
 
@@ -39,6 +41,8 @@ def finalize(state: SimState, cfg: SimConfig, idx, valid, base_ok, pref,
     """Sequential capacity-checked assignment in priority order.
 
     pref: (P, N) preference scores (higher better; NEG = never).
+    base_ok: (P, N) bool feasibility, or None where it is
+    ``isfinite(pref)`` (the proposal handed back the base pass's scores).
     dynamic_bestfit: recompute best-fit scores against the *running*
     reservation tally (true best-fit-decreasing) instead of static pref.
     May be a traced bool scalar (the scenario fleet dispatches schedulers

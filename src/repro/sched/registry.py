@@ -109,7 +109,10 @@ def register_scheduler(name: str, propose: Callable, *,
         idx, valid, base_ok, scores = base_pass(state, cfg)
         with trace.scope(trace.PROPOSE):
             pref = propose(state, cfg, rng, idx, valid, base_ok, scores)
-        return finalize(state, cfg, idx, valid, base_ok, pref,
+        # a proposal that hands back the base pass's scores (greedy) lets
+        # the commit derive base_ok, their finiteness mask, in place
+        return finalize(state, cfg, idx, valid,
+                        None if pref is scores else base_ok, pref,
                         dynamic_bestfit=dynamic_bestfit)
 
     scheduler.__name__ = name

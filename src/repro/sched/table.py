@@ -14,10 +14,13 @@ the whole fleet with the SA loop. The proposal table removes the switch:
 * :func:`make_switchless_dispatch` statically groups the fleet's lanes by
   *distinct* (transform, params) family and evaluates each family once over
   only its lane sub-batch — a greedy lane never pays a metaheuristic's loop
-  cost — then commits all lanes in one batched finaliser call. Under
-  ``cfg.use_kernels`` the commit is the fused ``sched_commit_fleet`` pass:
-  score-derived preference tiles are generated *inside* the Pallas grid, so
-  the (B, P, N) preference tensor never materialises in HBM.
+  cost — then commits all lanes in one batched finaliser call. Where the
+  program lowers for a TPU (and on any platform under ``cfg.use_kernels``,
+  interpreted off the TPU) the commit is the fused ``sched_commit_fleet``
+  kernel: score-derived preference tiles and the fit mask are generated
+  *inside* the Pallas grid, so neither the (B, P, N) preference tensor nor
+  a second mask operand is shipped; elsewhere it is the jnp reference, the
+  lanes' preference stack through one vmapped ``finalize``.
 
 Schedulers without a table form (opaque plugins) are still first-class:
 ``DispatchTable.switchless`` is False the moment any fleet lane names one,
@@ -33,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import trace
+from repro.kernels import on_tpu
 from repro.kernels.placement_commit.kernel import (FAM_EXTERNAL,
                                                    FAM_NODE_ORDER,
                                                    FAM_SCORES)
@@ -143,9 +147,10 @@ def make_switchless_dispatch(cfg, table: DispatchTable,
     one vmapped transform call over their sub-batch — distinct families run
     once each, over only the lanes that want them. The per-lane preference
     stack is reassembled by a static inverse permutation (a gather, not a
-    switch). Commit: one vmapped finalize with per-lane dynamic_bestfit
-    flags; under ``cfg.use_kernels`` the fused ``sched_commit_fleet`` kernel
-    commits all lanes with score/node-order preferences derived in-grid.
+    switch). Commit: on a TPU (or under ``cfg.use_kernels``) the fused
+    ``sched_commit_fleet`` kernel commits all lanes with score/node-order
+    preferences derived in-grid; otherwise one vmapped finalize with
+    per-lane dynamic_bestfit flags.
     """
     assert table.switchless, "opaque scheduler in a switchless dispatch"
     B = len(lane_scheds)
@@ -197,6 +202,39 @@ def make_switchless_dispatch(cfg, table: DispatchTable,
             pref_B = pref_B[jnp.asarray(inv)]
         return pref_B
 
+    def fused(state_B, ctx, rng):
+        """The kernel commit: only external families materialise a
+        preference; scores / node-order lanes are derived inside the kernel
+        grid, and so is the fit mask (``base_ok`` is ``isfinite(scores)``,
+        as ``base_pass`` defines it)."""
+        fam = tuple(f.fused for f in forms)
+        rots = [int(f.params[0]) if f.fused == FAM_NODE_ORDER else 0
+                for f in forms]
+        start_B = (state_B.window * jnp.asarray(rots, jnp.int32)) \
+            % cfg.max_nodes
+        ext, ext_row = external_prefs(ctx, rng)
+        with trace.scope(trace.COMMIT):
+            total_B, denom_B, _ = jax.vmap(
+                lambda s, i: commit_operands(s, cfg, i))(state_B, ctx.idx)
+            node_of, tally = sched_commit_fleet(
+                ctx.scores, None, ctx.req, ctx.valid, total_B, denom_B,
+                state_B.node_reserved, start_B, fam=fam, dynamic=dynamic,
+                ext=ext, ext_row=ext_row, tile_p=tile_p, tile_n=tile_n)
+            return jax.vmap(
+                lambda s, i, n, t: apply_commit(s, cfg, i, n, t)
+            )(state_B, ctx.idx, node_of, tally)
+
+    def reference(state_B, ctx, rng):
+        """The jnp commit: the lanes' preference stack through one vmapped
+        finalize (traced per-lane dyn flags — bitwise-equal to the static
+        selection)."""
+        pref_B = lane_prefs(ctx, rng)
+        dyn_B = jnp.asarray(dynamic)
+        return jax.vmap(
+            lambda s, i, v, ok, p, d: finalize(s, cfg, i, v, ok, p,
+                                               dynamic_bestfit=d)
+        )(state_B, ctx.idx, ctx.valid, ctx.base_ok, pref_B, dyn_B)
+
     def dispatch(state_B, rng):
         idx, valid, base_ok, scores = jax.vmap(
             base_pass, in_axes=(0, None))(state_B, cfg)
@@ -207,36 +245,7 @@ def make_switchless_dispatch(cfg, table: DispatchTable,
                            node_reserved=state_B.node_reserved,
                            node_active=state_B.node_active,
                            window=state_B.window)
-
-        if cfg.use_kernels:
-            # fused path: only external families materialise a preference;
-            # scores / node-order lanes are derived inside the kernel grid
-            fam = tuple(f.fused for f in forms)
-            rots = [int(f.params[0]) if f.fused == FAM_NODE_ORDER else 0
-                    for f in forms]
-            start_B = (state_B.window * jnp.asarray(rots, jnp.int32)) \
-                % cfg.max_nodes
-            ext, ext_row = external_prefs(ctx, rng)
-            with trace.scope(trace.COMMIT):
-                total_B, denom_B, _ = jax.vmap(
-                    lambda s, i: commit_operands(s, cfg, i))(state_B, idx)
-                node_of, tally = sched_commit_fleet(
-                    scores, base_ok, req, valid, total_B, denom_B,
-                    state_B.node_reserved, start_B, fam=fam,
-                    dynamic=dynamic, ext=ext, ext_row=ext_row,
-                    tile_p=tile_p, tile_n=tile_n)
-                return jax.vmap(
-                    lambda s, i, n, t: apply_commit(s, cfg, i, n, t)
-                )(state_B, idx, node_of, tally)
-
-        # reference path: commit the lanes' preference stack with one
-        # vmapped finalize (traced per-lane dyn flags — bitwise-equal to
-        # the static selection)
-        pref_B = lane_prefs(ctx, rng)
-        dyn_B = jnp.asarray(dynamic)
-        return jax.vmap(
-            lambda s, i, v, ok, p, d: finalize(s, cfg, i, v, ok, p,
-                                               dynamic_bestfit=d)
-        )(state_B, idx, valid, base_ok, pref_B, dyn_B)
+        return on_tpu(fused, fused if cfg.use_kernels else reference,
+                      state_B, ctx, rng)
 
     return dispatch

@@ -25,6 +25,7 @@ except ImportError:                                   # pragma: no cover
     HAVE_HYPOTHESIS = False
 
 from repro.kernels.placement_commit.ops import placement_commit
+from repro.kernels.placement_commit.ref import placement_commit_ref
 
 
 def _inputs(r, P, N, R=3):
@@ -43,15 +44,24 @@ def _inputs(r, P, N, R=3):
 
 
 def _assert_kernel_bitwise(seed, dyn, traced, P=None, N=None,
-                           tile_p=16, tile_n=32):
+                           tile_p=16, tile_n=32, derive_ok=False):
     r = np.random.default_rng(seed)
     P = P or int(r.integers(4, 48))
     N = N or int(r.integers(4, 64))
-    args = _inputs(r, P, N)
+    args = list(_inputs(r, P, N))
+    if derive_ok:
+        # the greedy call: no mask handed in, the kernel derives it as the
+        # reference does, isfinite(pref)
+        args[0] = jnp.where(args[2], args[0], -jnp.inf)
+        want = placement_commit_ref(args[0], *args[1:2],
+                                    jnp.isfinite(args[0]), *args[3:], dyn)
+        args[2] = None
     flag = jnp.asarray(dyn) if traced else dyn
     ref = placement_commit(*args, flag, use_kernel=False)
     ker = placement_commit(*args, flag, use_kernel=True, tile_p=tile_p)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(ker))
+    if derive_ok:
+        np.testing.assert_array_equal(np.asarray(want[0]), np.asarray(ker))
     # node-streamed: tile_n-column tiles with the cross-tile argmax carry
     streamed = placement_commit(*args, flag, use_kernel=True,
                                 stream_n=tile_n)
@@ -98,11 +108,15 @@ def _assert_no_overcommit(seed, dyn, use_kernel):
     (40, 50, 32, 32),       # padding in both dims
     (128, 96, 64, 32),      # multi-tile grid (sequential tally carry)
     (8, 200, 8, 128),       # wide node dim
+    (13, 130, 8, 64),       # P off the row tile, N off 128, streamed pad
 ])
 @pytest.mark.parametrize("dyn", [False, True])
-def test_commit_kernel_bitwise_matches_ref(P, N, tile_p, tile_n, dyn):
+@pytest.mark.parametrize("derive_ok", [False, True])
+def test_commit_kernel_bitwise_matches_ref(P, N, tile_p, tile_n, dyn,
+                                           derive_ok):
     _assert_kernel_bitwise(seed=P * 1000 + N, dyn=dyn, traced=False,
-                           P=P, N=N, tile_p=tile_p, tile_n=tile_n)
+                           P=P, N=N, tile_p=tile_p, tile_n=tile_n,
+                           derive_ok=derive_ok)
 
 
 @pytest.mark.parametrize("seed", range(5))
@@ -157,6 +171,34 @@ def test_commit_kernel_vmapped_matches_ref():
     ker = jax.vmap(lambda p, f: one(p, f, True))(prefs, flags)
     ref = jax.vmap(lambda p, f: one(p, f, False))(prefs, flags)
     np.testing.assert_array_equal(np.asarray(ker), np.asarray(ref))
+
+
+@pytest.mark.parametrize("lanes", [1, 7])
+@pytest.mark.parametrize("shared", [False, True])
+def test_commit_kernel_vmapped_lanes_match_ref(lanes, shared):
+    """The ``custom_vmap`` rule at one lane and at the fleet's seven, with
+    every row operand batched or the preference alone batched (the rest
+    lane-shared, as 2-D blocks), traced per-lane flags, P off the row tile
+    and N off 128: node_of and the tally lane for lane equal to the
+    reference's."""
+    r = np.random.default_rng(lanes * 10 + shared)
+    P, N = 13, 130
+    ops = [_inputs(r, P, N) for _ in range(lanes)]
+    stacked = [jnp.stack(x) for x in zip(*ops)]
+    flags = jnp.asarray([b % 2 == 0 for b in range(lanes)])
+    in_axes = (0,) + ((None,) * 6 if shared else (0,) * 6) + (0,)
+    if shared:
+        stacked = stacked[:1] + [x[0] for x in stacked[1:]]
+
+    def one(pref, req, ok, valid, total, denom, res0, flag, use_kernel):
+        return placement_commit(pref, req, ok, valid, total, denom, res0,
+                                flag, use_kernel=use_kernel, tile_p=8,
+                                return_tally=True)
+
+    ker = jax.vmap(lambda *a: one(*a, True), in_axes)(*stacked, flags)
+    ref = jax.vmap(lambda *a: one(*a, False), in_axes)(*stacked, flags)
+    for a, b in zip(ker, ref):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_commit_all_infeasible_places_nothing():

@@ -11,6 +11,7 @@ snapshotted at fleet build, so registry mutations after construction can
 never retarget a live fleet's scheduler indices.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,9 @@ import pytest
 
 from repro.config import REDUCED_SIM
 from repro.core.events import EventKind, HostEvent, pack_window, stack_windows
+from repro.kernels.placement_commit.kernel import (FAM_EXTERNAL,
+                                                   FAM_NODE_ORDER,
+                                                   FAM_SCORES)
 from repro.sched import (context_from_state, register_scheduler,
                          snapshot_dispatch, unregister_scheduler, TableForm)
 from repro.scenarios import ScenarioSpec, build_knobs
@@ -325,3 +329,118 @@ def test_sched_dispatch_config_validation():
         dataclasses.replace(REDUCED_SIM, sched_dispatch="bogus")
     with pytest.raises(ValueError, match="commit_tile"):
         dataclasses.replace(REDUCED_SIM, commit_tile_n=-1)
+
+
+# --- which commit a platform lowers to ---------------------------------------
+
+def _commit_program_text(cfg, fleet: bool) -> str:
+    """The CPU program of one commit pass: ``finalize`` on one lane, or a
+    4-lane switchless fleet of mixed families."""
+    from repro.core.state import init_state
+    from repro.sched.commit import finalize
+    from repro.sched.table import make_switchless_dispatch
+    if fleet:
+        names = ("greedy", "simulated_annealing", "round_robin", "first_fit")
+        dispatch = make_switchless_dispatch(cfg, snapshot_dispatch(names),
+                                            (0, 1, 2, 3))
+        state = batch_mod.init_batched_state(cfg, 4)
+        return jax.jit(dispatch).lower(
+            state, jax.random.PRNGKey(0)).compile().as_text()
+    P, N = cfg.sched_batch, cfg.max_nodes
+
+    def fn(state, idx, valid, pref):
+        return finalize(state, cfg, idx, valid, jnp.isfinite(pref), pref,
+                        dynamic_bestfit=True)
+
+    return jax.jit(fn).lower(
+        init_state(cfg), jnp.arange(P, dtype=jnp.int32),
+        jnp.ones((P,), bool), jnp.zeros((P, N))).compile().as_text()
+
+
+@pytest.mark.parametrize("fleet", [False, True])
+@pytest.mark.parametrize("use_kernels", [False, True])
+def test_cpu_commit_is_reference_unless_kernels_asked(fleet, use_kernels):
+    """Lowered for the CPU, the commit holds no Mosaic call either way: the
+    jnp reference's scan unless ``use_kernels`` asks for the interpreted
+    kernel, whose ops carry its name (the TPU side is
+    tests/test_tpu_compile.py)."""
+    cfg = dataclasses.replace(REDUCED_SIM, use_kernels=use_kernels)
+    text = _commit_program_text(cfg, fleet)
+    assert "tpu_custom_call" not in text
+    ops = re.findall(r'op_name="([^"]*pass\.commit[^"]*)"', text)
+    interpreted = any("/placement_commit/" in op for op in ops)
+    ref_loop = any("/while" in op and "/placement_commit/" not in op
+                   for op in ops)
+    assert interpreted == use_kernels
+    assert ref_loop != use_kernels
+
+
+# --- the fused fleet kernel, lane for lane against the reference -------------
+
+CELL_MIX = ((FAM_SCORES, True), (FAM_EXTERNAL, False),
+            (FAM_NODE_ORDER, False), (FAM_NODE_ORDER, False),
+            (FAM_SCORES, True), (FAM_EXTERNAL, False),
+            (FAM_NODE_ORDER, False))
+
+
+def _fleet_operands(B, P, N, n_ext, seed):
+    """Random fleet commit operands; row 3 of every lane is all -inf (no
+    feasible node), ~10% of nodes are dead (capacity folded to -1)."""
+    r = np.random.default_rng(seed)
+    scores = r.normal(size=(B, P, N)).astype(np.float32)
+    scores[r.random((B, P, N)) < 0.2] = -np.inf
+    scores[:, min(3, P - 1)] = -np.inf
+    req = (r.integers(1, 16, size=(B, P, 3)) / 64.0).astype(np.float32)
+    valid = r.random((B, P)) < 0.9
+    node_total = (r.integers(32, 128, size=(B, N, 3)) / 64.0
+                  ).astype(np.float32)
+    total = np.where(r.random((B, N, 1)) < 0.1, -1.0, node_total
+                     ).astype(np.float32)
+    denom = np.maximum(node_total, 1e-6)
+    res0 = (r.integers(0, 16, size=(B, N, 3)) / 64.0).astype(np.float32)
+    start = r.integers(0, N, size=B).astype(np.int32)
+    ext = r.normal(size=(max(n_ext, 1), P, N)).astype(np.float32)
+    return [jnp.asarray(x) for x in (scores, req, valid, total, denom, res0,
+                                     start, ext)]
+
+
+@pytest.mark.parametrize("B,P,N,tile_p,tile_n", [
+    (1, 37, 200, 8, None),      # one lane; P not a whole number of blocks
+    (7, 37, 200, 8, None),      # the cell's seven lanes
+    (7, 16, 130, None, None),   # whole batch in one step, N off 128
+    (7, 13, 200, 8, 64),        # streamed node tiles, padded P and N
+    (1, 13, 130, None, 48),     # streamed, one lane
+])
+@pytest.mark.parametrize("derive_ok", [True, False])
+def test_fleet_kernel_matches_ref_lane_for_lane(B, P, N, tile_p, tile_n,
+                                                derive_ok):
+    """``sched_commit_fleet`` (interpreted) against ``placement_commit_ref``
+    lane by lane: all three families in one call ('both' mode for B = 7),
+    an all-infeasible row, dead nodes, the fit mask derived in the kernel
+    from ``isfinite(scores)`` or handed in."""
+    from repro.kernels.placement_commit.ops import sched_commit_fleet
+    from repro.kernels.placement_commit.ref import (placement_commit_ref,
+                                                    sched_pref_ref)
+    mix = CELL_MIX[:B]
+    fam = tuple(f for f, _ in mix)
+    dynamic = tuple(d for _, d in mix)
+    ext_row, n_ext = [], 0
+    for f in fam:
+        ext_row.append(n_ext if f == FAM_EXTERNAL else 0)
+        n_ext += f == FAM_EXTERNAL
+    scores, req, valid, total, denom, res0, start, ext = _fleet_operands(
+        B, P, N, n_ext, seed=B * 1000 + P * 10 + N)
+    ok = jnp.isfinite(scores)
+    node_of, tally = sched_commit_fleet(
+        scores, None if derive_ok else ok, req, valid, total, denom, res0,
+        start, fam=fam, dynamic=dynamic, ext=ext if n_ext else None,
+        ext_row=tuple(ext_row), tile_p=tile_p, tile_n=tile_n)
+    for b in range(B):
+        pref = sched_pref_ref(scores[b], start[b], fam[b], ext[ext_row[b]])
+        want = placement_commit_ref(pref, req[b], ok[b], valid[b], total[b],
+                                    denom[b], res0[b], dynamic[b])
+        np.testing.assert_array_equal(np.asarray(node_of[b]),
+                                      np.asarray(want[0]), err_msg=f"{b}")
+        np.testing.assert_array_equal(np.asarray(tally[b]),
+                                      np.asarray(want[1]), err_msg=f"{b}")
+        assert int(node_of[b, min(3, P - 1)]) == -1

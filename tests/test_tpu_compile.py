@@ -18,6 +18,7 @@ worker imports this file would break the other workers.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -151,3 +152,65 @@ def test_segment_usage_compiles(one_chip):
 
     _compile(fn, one_chip, ((T,), jnp.int32), ((T, R), jnp.float32),
              ((T,), jnp.bool_))
+
+
+# --- the commit's dispatch: the kernel on a TPU whatever use_kernels says --
+
+COMMIT_LOOP = re.compile(r'op_name="[^"]*pass\.commit[^"]*/while')
+
+
+def _state_shapes(cfg, sharding, lanes=None):
+    from repro.core.state import init_state
+    lead = () if lanes is None else (lanes,)
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(lead + x.shape, x.dtype,
+                                       sharding=sharding),
+        jax.eval_shape(lambda: init_state(cfg)))
+
+
+@pytest.mark.parametrize("mask", ["base_ok", "isfinite"])
+def test_finalize_default_config_commits_in_kernel(one_chip, mask):
+    """``finalize`` at cell-A widths with the default ``use_kernels=False``,
+    lowered for a TPU: the commit is the Mosaic kernel, no scan loop is
+    left under ``pass.commit`` — with an explicit feasibility mask and
+    with the mask derived from the preference (the greedy call)."""
+    from repro.sched.commit import finalize
+    assert not CELL_A.use_kernels
+
+    def fn(state, idx, valid, base_ok, pref):
+        return finalize(state, CELL_A, idx, valid,
+                        base_ok if mask == "base_ok" else None, pref,
+                        dynamic_bestfit=True)
+
+    args = [_state_shapes(CELL_A, one_chip)] + [
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+        for s, d in (((P,), jnp.int32), ((P,), jnp.bool_),
+                     ((P, N), jnp.bool_), ((P, N), jnp.float32))]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "placement_commit" in text
+    assert not COMMIT_LOOP.search(text)
+
+
+def _small_fleet():
+    """A 4-lane switchless fleet of mixed families at small widths:
+    greedy (scores, dynamic), simulated annealing (external), round robin
+    and first fit (node order)."""
+    from repro.config import REDUCED_SIM
+    from repro.sched import snapshot_dispatch
+    from repro.sched.table import make_switchless_dispatch
+    names = ("greedy", "simulated_annealing", "round_robin", "first_fit")
+    table = snapshot_dispatch(names)
+    assert table.switchless and not REDUCED_SIM.use_kernels
+    return REDUCED_SIM, make_switchless_dispatch(REDUCED_SIM, table,
+                                                 (0, 1, 2, 3))
+
+
+def test_switchless_fleet_default_config_commits_in_kernel(one_chip):
+    cfg, dispatch = _small_fleet()
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    text = jax.jit(dispatch).lower(_state_shapes(cfg, one_chip, 4),
+                                   rng).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "placement_commit" in text
+    assert not COMMIT_LOOP.search(text)
