@@ -48,12 +48,15 @@ def as_dict(state) -> dict:
 
 def drift(s: dict) -> float:
     """Widest |tally - float64 recompute| / capacity over nodes, resources
-    and lanes."""
+    and lanes; a whole capacity (1.0) where a running task's node is no
+    node of the table, whose tally could hold it."""
     worst = 0.0
     for i in range(s["task_state"].shape[0]):
         run = s["task_state"][i] == ref.RUNNING
         node = s["task_node"][i][run]
         N = s["node_total"].shape[1]
+        if np.any((node < 0) | (node >= N)):
+            return 1.0
         cap = np.maximum(s["node_total"][i].astype(np.float64), 1e-6)
         for tally, vals in (("node_reserved", s["task_req"][i][run]),
                             ("node_used",
@@ -67,35 +70,45 @@ def drift(s: dict) -> float:
     return worst
 
 
-def reference_batch(cfg, lane: dict, s_before: dict, windows: dict,
-                    seed: int, dtype=None):
-    """The reference's (state after, stats rows) for one lane."""
+def reference_lanes(cfg, lanes, before, windows, seed, dtype=None,
+                    devices=None) -> list:
+    """The reference's (state after, stats rows) of every lane, as host
+    arrays. Each lane runs on one of ``devices`` (default: the first), in
+    turn, and every lane is launched before any is read, so that the chips
+    of a sharded cell replay their lanes side by side."""
     import jax
     import jax.numpy as jnp
-    out = ref.run_batch(
-        {k: jnp.asarray(v) for k, v in s_before.items()},
-        {k: jnp.asarray(v) for k, v in windows.items()}, seed,
-        np.float32(lane.get("node_outage_frac", 0.0)),
-        np.float32(lane.get("capacity_scale", 1.0)),
-        salt=int(cfg.scenario_salt),
-        sched=lane["scheduler"],
-        dims=(cfg.max_nodes, cfg.sched_batch, cfg.max_tasks),
-        dtype=dtype or jnp.float32)
-    return jax.tree.map(np.asarray, out)
-
-
-def control_outputs(cfg, lanes, before, windows, seed):
-    """The control: the reference in the program's place, with scores and
-    tallies in bfloat16, one step below the float32 the configuration
-    states. Returns (state after, stats rows) shaped as the program's."""
-    import jax.numpy as jnp
+    devices = devices or jax.devices()[:1]
     b = as_dict(before)
     single = np.ndim(b["window"]) == 0
     if single:
         b = _lanes(b, True)
-    outs = [reference_batch(cfg, lane, {k: v[i] for k, v in b.items()},
-                            windows, seed, dtype=jnp.bfloat16)
-            for i, lane in enumerate(lanes)]
+    outs = []
+    for i, lane in enumerate(lanes):
+        dev = devices[i % len(devices)]
+
+        def put(tree):
+            return {k: jax.device_put(np.asarray(v), dev)
+                    for k, v in tree.items()}
+        outs.append(ref.run_batch(
+            put({k: v[i] for k, v in b.items()}), put(windows), seed,
+            np.float32(lane.get("node_outage_frac", 0.0)),
+            np.float32(lane.get("capacity_scale", 1.0)),
+            salt=int(cfg.scenario_salt),
+            sched=lane["scheduler"],
+            dims=(cfg.max_nodes, cfg.sched_batch, cfg.max_tasks),
+            dtype=dtype or jnp.float32))
+    return [jax.tree.map(np.asarray, o) for o in outs]
+
+
+def control_outputs(cfg, lanes, before, windows, seed, devices=None):
+    """The control: the reference in the program's place, with scores and
+    tallies in bfloat16, one step below the float32 the configuration
+    states. Returns (state after, stats rows) shaped as the program's."""
+    import jax.numpy as jnp
+    single = np.ndim(as_dict(before)["window"]) == 0
+    outs = reference_lanes(cfg, lanes, before, windows, seed,
+                           dtype=jnp.bfloat16, devices=devices)
     after = {k: np.stack([o[0][k] for o in outs]) for k in outs[0][0]}
     rows = {k: np.stack([o[1][k] for o in outs], axis=1) for k in outs[0][1]}
     if single:
@@ -110,8 +123,9 @@ def _rel(a, b) -> float:
     return float((np.abs(a - b) / den).max(initial=0.0))
 
 
-def lane_numbers(cfg, lane, before_i, after_i, rows_i, windows, seed) -> dict:
-    r_state, r_rows = reference_batch(cfg, lane, before_i, windows, seed)
+def lane_numbers(after_i, rows_i, r_state, r_rows) -> dict:
+    """One lane's numbers: the program's state after the batch and stats
+    rows against the reference's."""
     n = {"events_differ": 0, "decisions_differ": 0, "tallies_differ": 0,
          "stats_differ": 0, "stats_gap": 0.0}
     for f in EVENT_FIELDS:
@@ -136,21 +150,22 @@ def lane_numbers(cfg, lane, before_i, after_i, rows_i, windows, seed) -> dict:
 
 
 def compare(cfg, lanes, before, after, rows, windows, seed,
-            limits) -> dict:
+            limits, devices=None) -> dict:
     """Every number, the worst over the lanes, with its limit; ``correct``
-    when none is over its limit."""
+    when none is over its limit. The reference replays the lanes on
+    ``devices`` (``reference_lanes``)."""
     b, a = as_dict(before), as_dict(after)
     single = len(lanes) == 1 and np.ndim(b["window"]) == 0
     if single:
         b, a = _lanes(b, True), _lanes(a, True)
         rows = _lanes(rows, True, axis=1)
+    refs = reference_lanes(cfg, lanes, b, windows, seed, devices=devices)
     worst = {}
     failed = 0
     for i in range(len(lanes)):
-        n = lane_numbers(cfg, lanes[i], {k: v[i] for k, v in b.items()},
-                         {k: v[i] for k, v in a.items()},
+        n = lane_numbers({k: v[i] for k, v in a.items()},
                          {k: np.asarray(v)[:, i] for k, v in rows.items()},
-                         windows, seed)
+                         *refs[i])
         failed += any(n[k] > limits[k] for k in n)
         for k, v in n.items():
             worst[k] = max(worst.get(k, v), v)

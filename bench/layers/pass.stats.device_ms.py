@@ -1,5 +1,6 @@
 """Device time of the window program's ``pass.stats`` scope (op self time,
-``scopes.py``) per lane-window advanced in the traced trajectory."""
+``scopes.py``), summed over the chips, per lane-window advanced in
+the traced trajectory."""
 import scopes
 
 

@@ -7,7 +7,12 @@ and print their ``memory_analysis()``: no chip, no run, no time.
 For each cell (all of ``BENCHMARK.json`` by default) it lowers and compiles
 the window program the cell's drive loop launches (``run_windows_jit`` for one
 lane, ``run_scenarios_jit`` for a fleet) at the cell's batch of windows,
-and the accounting resync, for one chip of a ``v5e:2x2`` topology.
+and the accounting resync, for one chip of a ``v5e:2x2`` topology. A cell
+on ``chips`` > 1 compiles ``run_scenarios_sharded_jit`` and
+``resync_fleet_jit`` instead, over a ``('data',)`` mesh of the topology's
+first ``chips`` devices, the lanes sharded over it and the window batch
+replicated (``NamedSharding`` arguments, as ``ScenarioFleet(mesh=)``
+launches them); its ``memory_analysis()`` is each device's.
 ``--repeat-lanes k`` compiles a fleet with its lane list repeated k times,
 to size a fleet against the chip's memory.
 """
@@ -40,9 +45,11 @@ def report(tag: str, compiled) -> dict:
     return out
 
 
-def rehearse(workload: str, repeat: int, device) -> dict:
+def rehearse(workload: str, repeat: int, topo) -> dict:
     import jax
-    from jax.sharding import SingleDeviceSharding
+    import numpy as np
+    from jax.sharding import (AxisType, Mesh, NamedSharding,
+                              PartitionSpec as P, SingleDeviceSharding)
     import run
     from repro.core import engine
     from repro.core.events import empty_window
@@ -54,11 +61,11 @@ def rehearse(workload: str, repeat: int, device) -> dict:
     cfg = run.sim_config(cell.config)
     W = int(cell.traffic["batch_windows"])
     lanes = run.lane_specs(cell.traffic) * repeat
-    sh = SingleDeviceSharding(device)
+    sh = SingleDeviceSharding(topo.devices[0])
 
-    def spec(tree, lead=()):
+    def spec(tree, lead=(), sharding=None):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            lead + x.shape, x.dtype, sharding=sh), tree)
+            lead + x.shape, x.dtype, sharding=sharding or sh), tree)
 
     windows = spec(empty_window(cfg), (W,))
     res = {}
@@ -75,6 +82,27 @@ def rehearse(workload: str, repeat: int, device) -> dict:
     specs = [ScenarioSpec(name=f"lane{i}", **ln) for i, ln in
              enumerate(lanes)]
     probe = ScenarioFleet(cfg, iter(()), specs, batch_windows=W)
+    if cell.chips > 1:
+        if len(lanes) % cell.chips:
+            raise SystemExit(f"{workload}: {len(lanes)} lanes do not split "
+                             f"over {cell.chips} chips")
+        mesh = Mesh(np.array(topo.devices[:cell.chips]),
+                    (batch_mod.FLEET_AXIS,), axis_types=(AxisType.Auto,))
+        lane_sh = NamedSharding(mesh, P(batch_mod.FLEET_AXIS))
+        state = spec(jax.eval_shape(lambda: probe.state), sharding=lane_sh)
+        knobs = spec(probe.knobs, sharding=lane_sh)
+        windows = spec(empty_window(cfg), (W,), NamedSharding(mesh, P()))
+        tag = f"B={len(lanes)} over {cell.chips} devices"
+        res["window"] = report(
+            f"{workload} run_scenarios_sharded_jit {tag} W={W}",
+            batch_mod.run_scenarios_sharded_jit.lower(
+                state, windows, knobs, cfg, probe.scheduler_names, mesh, 0,
+                has_storm=run.need(probe, "_has_storm"),
+                table=probe.dispatch_table).compile())
+        res["resync"] = report(f"{workload} resync_fleet_jit {tag}",
+                               batch_mod.resync_fleet_jit.lower(
+                                   state, cfg).compile())
+        return res
     state = spec(jax.eval_shape(lambda: probe.state))
     knobs = spec(probe.knobs)
     res["window"] = report(
@@ -103,7 +131,7 @@ def main(argv=None) -> int:
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         names = args.workloads or [w["name"] for w in
                                    json.load(f)["workloads"]]
-    out = {n: rehearse(n, args.repeat_lanes, topo.devices[0]) for n in names}
+    out = {n: rehearse(n, args.repeat_lanes, topo) for n in names}
     print(json.dumps(out))
     return 0
 

@@ -24,6 +24,16 @@ runs to its end): ``replay_windows`` -> ``WindowPrefetcher`` ->
 the simulated seconds of every lane over the wall seconds of the whole
 window. A compile inside the window fails the run.
 
+A cell on ``chips`` > 1 is one process over ``jax.devices()[:chips]``: its
+fleet gets the program's ``fleet_mesh(chips)``, so its lanes shard over the
+chips in order (``ScenarioFleet(mesh=)``, ``run_scenarios_sharded``) and
+each window batch goes to every chip. The snapshot, each restore and each
+kept state keep that lane sharding; a restored state whose sharding is not
+the one ``shard_over_fleet`` gives fails the run, since its first launch
+would reshard or compile inside the window. ``memory_peak_bytes`` is the
+fullest chip's, and ``device.count`` the chips used. A one-chip cell runs
+as it did before the mesh path: no mesh, the same spans and readings.
+
 Correctness: in each trajectory the states before and after one batch,
 drawn from the seed, and that batch's stats rows are kept as the program
 produced them; after the window ``reference.py`` replays the batch from the
@@ -33,8 +43,7 @@ are read from its trace and from host spans.
 
 The run fails (exit code 2, no result line) where JAX finds no TPU, fewer
 chips than the cell asks for, or a device kind missing from
-``peaks.json``; and for a cell on more than one chip, since the harness
-has no mesh path yet.
+``peaks.json``.
 """
 from __future__ import annotations
 
@@ -250,15 +259,17 @@ def spanned_class(base, spans: Spans):
 
 
 class Runner:
-    """Builds the program's drive loop for one segment of the stack."""
+    """Builds the program's drive loop for one segment of the stack; on
+    ``chips`` > 1, a fleet whose lanes shard over the first ``chips``."""
 
-    def __init__(self, cfg, traffic, stack, seed, spans):
+    def __init__(self, cfg, traffic, stack, seed, spans, chips: int):
         from repro.core.pipeline import Simulation
         from repro.scenarios import ScenarioFleet, ScenarioSpec
         self.cfg, self.stack, self.seed, self.spans = cfg, stack, seed, spans
         self.batch = int(traffic["batch_windows"])
         self.lanes = lane_specs(traffic)
         self.fleet = len(self.lanes) > 1
+        self.mesh = self.lane_sharding = None
         if self.fleet:
             self.specs = [ScenarioSpec(name=f"lane{i}", **ln)
                           for i, ln in enumerate(self.lanes)]
@@ -268,6 +279,15 @@ class Runner:
                 raise BenchError("a one-lane cell runs an unperturbed "
                                  "Simulation: give it a scheduler only")
             self.cls = spanned_class(Simulation, spans)
+        if chips > 1:
+            if not self.fleet:
+                raise BenchError(f"a one-lane cell cannot use {chips} "
+                                 "chips: only a fleet's lanes shard")
+            import jax.numpy as jnp
+            from repro.scenarios import batch as batch_mod
+            self.mesh = batch_mod.fleet_mesh(chips)
+            self.lane_sharding = batch_mod.shard_over_fleet(
+                jnp.zeros(chips), self.mesh).sharding
 
     def make(self, start: int, n: int):
         from repro.core.precompile import replay_windows
@@ -276,17 +296,26 @@ class Runner:
                                              start_window=start))
         if self.fleet:
             return self.cls(self.cfg, src, self.specs,
-                            batch_windows=self.batch, seed=self.seed)
+                            batch_windows=self.batch, seed=self.seed,
+                            mesh=self.mesh)
         return self.cls(self.cfg, src, scheduler=self.lanes[0]["scheduler"],
                         batch_windows=self.batch, seed=self.seed)
 
     def restore(self, drv, snap, windows_done: int):
         """Resume ``drv`` from a device copy of ``snap`` at window
-        ``windows_done``, as ``ScenarioFleet.restore`` resumes from a file."""
+        ``windows_done``, as ``ScenarioFleet.restore`` resumes from a file.
+        On a mesh the copy keeps the fleet's lane sharding."""
         import jax
         import jax.numpy as jnp
         from repro.core.pipeline import restored_resync_phase
         drv.state = jax.tree.map(jnp.copy, snap)
+        if self.mesh is not None:
+            off = sorted({str(x.sharding) for x in jax.tree.leaves(drv.state)
+                          if x.sharding != self.lane_sharding})
+            if off:
+                raise BenchError(f"a restored state is not lane-sharded as "
+                                 f"shard_over_fleet gives "
+                                 f"({self.lane_sharding}): {off}")
         drv.windows_done = windows_done
         need(drv, "_since_resync")
         drv._since_resync = restored_resync_phase(
@@ -354,7 +383,7 @@ def device_info(jax, chips: int, require_tpu: bool, peaks: dict) -> dict:
         raise BenchError(f"the cell needs {chips} chips, JAX sees "
                          f"{len(devs)}")
     return {"platform": d0.platform, "kind": d0.device_kind,
-            "count": len(devs)}
+            "count": chips}
 
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
@@ -377,10 +406,6 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 def _run_cell(workload, seed, seconds, trace, root, require_tpu,
               compile_cache, t_start, trace_out, control, compiles) -> dict:
     cell = resolve(root, workload)
-    if cell.chips != 1:
-        raise BenchError(f"{workload} asks for {cell.chips} chips: the "
-                         "harness has no mesh path yet and runs one-chip "
-                         "cells only")
     cache = os.path.join(root, "bench", ".cache")
     sys.path.insert(0, os.path.join(ROOT, "src"))
     try:
@@ -432,7 +457,7 @@ def _run_cell(workload, seed, seconds, trace, root, require_tpu,
 
     spans = Spans()
     prog_seed = seed % PROGRAM_SEED_MOD
-    runner = Runner(cfg, tr, stack, prog_seed, spans)
+    runner = Runner(cfg, tr, stack, prog_seed, spans, cell.chips)
     lanes = len(runner.lanes)
     # warm segment: the opening burst, untimed; its end is the snapshot
     drv = runner.make(0, plan.warm)
@@ -506,16 +531,21 @@ def _run_cell(workload, seed, seconds, trace, root, require_tpu,
     del snap, cap
     gc.collect()
     import check
+    t_check = time.perf_counter()
     batch_seed = prog_seed + plan.warm + k_check * batch
+    chips = jax.devices()[:cell.chips]
     if control:
         host = (host[0],) + check.control_outputs(cfg, runner.lanes, host[0],
-                                                  check_windows, batch_seed)
+                                                  check_windows, batch_seed,
+                                                  chips)
     verdict = check.compare(cfg, runner.lanes, host[0], host[1], host[2],
-                            check_windows, batch_seed, cell.limits)
+                            check_windows, batch_seed, cell.limits, chips)
+    check_s = time.perf_counter() - t_check
 
     out = {"correct": verdict["correct"], "attempted": trajectories * lanes,
            "failed": verdict["failed_lanes"],
            "metrics": {}, "device": device}
+    t_read = time.perf_counter()
     if trace:
         import xplane
         reduced = xplane.reduce_dir(traced["dir"])
@@ -546,7 +576,9 @@ def _run_cell(workload, seed, seconds, trace, root, require_tpu,
         f"steady_rows/window={steady_rows} warm_state={occupancy} "
         f"trajectories={trajectories} lane_windows={lane_windows} "
         f"wall_s={wall:.3f} sim_speed={e2e['sim_speed']:.3f} "
-        f"setup_s={setup_s:.3f} checked_batch={k_check}")
+        f"setup_s={setup_s:.3f} checked_batch={k_check} "
+        f"check_s={check_s:.3f} trace_read_s="
+        f"{time.perf_counter() - t_read if trace else 0:.3f}")
     out["checks"] = verdict["numbers"]
     return out
 

@@ -18,7 +18,9 @@ the lines, millions of events, are skipped unparsed) and joins them to
 * pass time is op self time (``xplane.self_times``' rule) inside the
   window program's modules (``run_windows*`` / ``run_scenarios*``) inside
   the traced window (first ``restore`` to last ``trajectory``), averaged
-  over the devices;
+  over the devices (``devices`` of them); a pass's device ms a lane-window
+  (:func:`pass_ms`) is summed over the devices, since the chips of a
+  sharded fleet each run their share of the lanes;
 * idle time is device 0's, with the window and gaps of ``xplane.reduce``.
 
 The names are spelled here, not imported from the program, so that a
@@ -336,6 +338,7 @@ def reduce(t: dict) -> dict:
                     u["s"] += d
     out = {"window_s": (hi - lo) / 1e9 if hi > lo > float("-inf") else None,
            "pass_s": pass_s, "program_op_s": sum(pass_s.values()),
+           "devices": n_dev,
            "ops": n_ops, "ops_joined": n_joined,
            "unscoped_ops": sorted(([k, v["category"], v["tf_op"], v["s"]]
                                    for k, v in unscoped.items()),
@@ -407,13 +410,13 @@ def of(ctx) -> Optional[dict]:
 
 
 def pass_ms(ctx, scope: str) -> Optional[float]:
-    """Device ms of ``scope`` per traced lane-window; None where no op
-    carries it."""
+    """Device ms of ``scope``, summed over the devices, per traced
+    lane-window; None where no op carries it."""
     r = of(ctx)
     lane_windows = ctx["traced"]["lane_windows"]
     if r is None or not lane_windows or r["pass_s"][scope] <= 0:
         return None
-    return 1e3 * r["pass_s"][scope] / lane_windows
+    return 1e3 * r["pass_s"][scope] * r["devices"] / lane_windows
 
 
 def summary(path: str) -> dict:

@@ -52,12 +52,18 @@ def tiny_files() -> dict:
             "bench/traffic/tiny_fleet.json": fleet}
 
 
-@pytest.fixture
-def tiny_root(tmp_path):
+def make_tiny_root(root) -> str:
+    """Write the tiny root's files and the benchmark's readers and limits
+    under the directory ``root``."""
     for rel, obj in tiny_files().items():
-        path = tmp_path / rel
+        path = root / rel
         path.parent.mkdir(parents=True, exist_ok=True)
         path.write_text(json.dumps(obj))
     for d in ("layers", "limits"):
-        shutil.copytree(os.path.join(BENCH, d), tmp_path / "bench" / d)
-    return str(tmp_path)
+        shutil.copytree(os.path.join(BENCH, d), root / "bench" / d)
+    return str(root)
+
+
+@pytest.fixture
+def tiny_root(tmp_path):
+    return make_tiny_root(tmp_path)

@@ -94,3 +94,20 @@ def test_drift_reads_a_bad_tally():
     assert check.drift(s) < 1e-6
     s["node_reserved"][0, 3, 1] += 1e-3
     assert check.drift(s) == pytest.approx(1e-3, rel=1e-3)
+
+
+def test_drift_reads_a_node_outside_the_table():
+    """A running task on no node of the table: a whole capacity, not a
+    crash of the check."""
+    T, N = 16, 8
+    s = {"task_state": np.full((1, T), 2, np.int8),
+         "task_node": np.arange(T).reshape(1, T) % N,
+         "task_req": np.full((1, T, 3), 0.01, np.float32),
+         "task_usage": np.zeros((1, T, 8), np.float32),
+         "node_total": np.ones((1, N, 3), np.float32),
+         "node_active": np.ones((1, N), bool)}
+    s["node_reserved"] = np.full((1, N, 3), 0.02, np.float32)
+    s["node_used"] = np.zeros((1, N, 3), np.float32)
+    assert check.drift(s) < 1e-6
+    s["task_node"][0, 0] = N
+    assert check.drift(s) == 1.0

@@ -90,8 +90,8 @@ def test_new_cell_needs_only_new_files(tiny_root):
 
 
 def test_cell_on_four_chips_is_refused(tiny_root):
-    """No mesh path yet: a four-chip cell fails rather than timing the
-    one-chip fleet under its name."""
+    """JAX sees one device here: a four-chip cell fails rather than timing
+    its fleet on fewer chips under its name."""
     path = os.path.join(tiny_root, "BENCHMARK.json")
     with open(path) as f:
         bench = json.load(f)

@@ -10,10 +10,15 @@ spans (``run.py``: ``restore``, ``trajectory``, ``dispatch``, ``resync``,
 * the traced window runs from the first ``restore`` span's start to the
   last ``trajectory`` span's end;
 * busy time is the union of a device's operation intervals inside the
-  window, averaged over the devices;
-* module time is the device time of each launched program, by name;
+  window, averaged over the devices (``busy_s``) and per device, in the
+  order of the planes' names (``busy_by_device_s``);
+* module time is the device time of each launched program, by name,
+  averaged over the devices; :func:`chips_module_s` gives it summed over
+  them, the device time of work that the chips split between them (a
+  sharded fleet's lanes);
 * an operation's time is its self time: its own interval less the
-  operations nested in it (a loop's body runs nested in the loop);
+  operations nested in it (a loop's body runs nested in the loop),
+  averaged over the devices;
 * an idle gap is a stretch of the window in which device 0 runs no
   operation; it is named for the host span that covers its middle (the
   innermost: ``resync``, ``dispatch``, ``restore``, ``feed.read``), as
@@ -142,6 +147,7 @@ def reduce(devices, host) -> dict:
                    for s, e in gaps0), key=lambda g: -g[1])
     return {"window_s": (hi - lo) / 1e9,
             "busy_s": sum(busy) / len(busy) / 1e9,
+            "busy_by_device_s": [b / 1e9 for b in busy],
             "modules_s": {k: v / 1e9 for k, v in modules.items()},
             "top_ops": [[k, v / 1e9] for k, v in
                         sorted(ops.items(), key=lambda kv: -kv[1])[:TOP]],
@@ -160,6 +166,12 @@ def module_s(reduced: dict, *needles: str) -> float:
     """Device seconds of the programs whose name holds any needle."""
     return sum(v for k, v in reduced["modules_s"].items()
                if any(n in k for n in needles))
+
+
+def chips_module_s(reduced: dict, *needles: str) -> float:
+    """Device seconds of the programs whose name holds any needle, summed
+    over the chips."""
+    return module_s(reduced, *needles) * len(reduced["busy_by_device_s"])
 
 
 def reduce_dir(trace_dir: str) -> dict:
