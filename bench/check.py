@@ -1,9 +1,10 @@
 """The comparison that decides ``correct``.
 
 The program's state before one batch of the timed window goes through the
-plain reference (``reference.py``); the program's state after the batch and
-its stats rows are compared with the reference's. The numbers compared,
-each held to its limit (``limits/``):
+plain reference of the deployment's family (``families/<family>.py``, built
+on ``reference.py``); the program's state after the batch and its stats
+rows are compared with the reference's. The numbers every family compares
+(``NUMBERS``), each held to its limit (``limits/``):
 
 * ``events_differ``: entries of the node and task tables that event
   application writes (capacities, attributes, activity, requests,
@@ -19,8 +20,14 @@ each held to its limit (``limits/``):
   between a tally the program keeps incrementally (``node_reserved``,
   ``node_used``) and a float64 recompute from its own task table, over
   every lane, before and after the batch.
+
+A family adds the state leaves its reference also keeps
+(``STATE_FIELDS``) and numbers of its own (``NUMBERS``, per lane from
+``numbers``); they go into the verdict and the result line as these do.
 """
 from __future__ import annotations
+
+import dataclasses
 
 import numpy as np
 
@@ -31,6 +38,8 @@ EVENT_FIELDS = ("node_active", "node_total", "node_attrs", "task_req",
                 "window")
 COUNTERS = ("evictions", "completions", "placements", "overflow_drops")
 USED_COLS = list(ref.USED_COLS)
+NUMBERS = ("events_differ", "decisions_differ", "tallies_differ",
+           "stats_differ", "stats_gap", "tally_drift")
 
 
 def _lanes(tree, single: bool, axis: int = 0):
@@ -40,10 +49,22 @@ def _lanes(tree, single: bool, axis: int = 0):
     return {k: np.expand_dims(np.asarray(v), axis) for k, v in tree.items()}
 
 
-def as_dict(state) -> dict:
+def state_fields(family) -> tuple:
+    """The leaves compared: the shared ones, then the family's own."""
+    return ref.STATE_FIELDS + tuple(f for f in family.STATE_FIELDS
+                                    if f not in ref.STATE_FIELDS)
+
+
+def as_dict(state, fields=ref.STATE_FIELDS) -> dict:
     if isinstance(state, dict):
-        return {f: np.asarray(state[f]) for f in ref.STATE_FIELDS}
-    return {f: np.asarray(getattr(state, f)) for f in ref.STATE_FIELDS}
+        return {f: np.asarray(state[f]) for f in fields}
+    return {f: np.asarray(getattr(state, f)) for f in fields}
+
+
+def dims(cfg) -> dict:
+    """The configuration's numbers, as the reference is given them."""
+    return {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)
+            if isinstance(getattr(cfg, f.name), (int, float))}
 
 
 def drift(s: dict) -> float:
@@ -70,16 +91,17 @@ def drift(s: dict) -> float:
     return worst
 
 
-def reference_lanes(cfg, lanes, before, windows, seed, dtype=None,
+def reference_lanes(family, cfg, lanes, before, windows, seed, dtype=None,
                     devices=None) -> list:
-    """The reference's (state after, stats rows) of every lane, as host
-    arrays. Each lane runs on one of ``devices`` (default: the first), in
-    turn, and every lane is launched before any is read, so that the chips
-    of a sharded cell replay their lanes side by side."""
+    """The family reference's (state after, stats rows) of every lane, as
+    host arrays. Each lane runs on one of ``devices`` (default: the first),
+    in turn, and every lane is launched before any is read, so that the
+    chips of a sharded cell replay their lanes side by side."""
     import jax
     import jax.numpy as jnp
     devices = devices or jax.devices()[:1]
-    b = as_dict(before)
+    b = as_dict(before, state_fields(family))
+    sizes = dims(cfg)
     single = np.ndim(b["window"]) == 0
     if single:
         b = _lanes(b, True)
@@ -90,24 +112,21 @@ def reference_lanes(cfg, lanes, before, windows, seed, dtype=None,
         def put(tree):
             return {k: jax.device_put(np.asarray(v), dev)
                     for k, v in tree.items()}
-        outs.append(ref.run_batch(
-            put({k: v[i] for k, v in b.items()}), put(windows), seed,
-            np.float32(lane.get("node_outage_frac", 0.0)),
-            np.float32(lane.get("capacity_scale", 1.0)),
-            salt=int(cfg.scenario_salt),
-            sched=lane["scheduler"],
-            dims=(cfg.max_nodes, cfg.sched_batch, cfg.max_tasks),
-            dtype=dtype or jnp.float32))
+        outs.append(family.run_batch(
+            put({k: v[i] for k, v in b.items()}), put(windows), seed, lane,
+            dims=sizes, dtype=dtype or jnp.float32))
     return [jax.tree.map(np.asarray, o) for o in outs]
 
 
-def control_outputs(cfg, lanes, before, windows, seed, devices=None):
-    """The control: the reference in the program's place, with scores and
-    tallies in bfloat16, one step below the float32 the configuration
-    states. Returns (state after, stats rows) shaped as the program's."""
+def control_outputs(family, cfg, lanes, before, windows, seed,
+                    devices=None):
+    """The control: the family's reference in the program's place, with
+    scores and tallies in bfloat16, one step below the float32 the
+    configuration states. Returns (state after, stats rows) shaped as the
+    program's."""
     import jax.numpy as jnp
     single = np.ndim(as_dict(before)["window"]) == 0
-    outs = reference_lanes(cfg, lanes, before, windows, seed,
+    outs = reference_lanes(family, cfg, lanes, before, windows, seed,
                            dtype=jnp.bfloat16, devices=devices)
     after = {k: np.stack([o[0][k] for o in outs]) for k in outs[0][0]}
     rows = {k: np.stack([o[1][k] for o in outs], axis=1) for k in outs[0][1]}
@@ -149,32 +168,34 @@ def lane_numbers(after_i, rows_i, r_state, r_rows) -> dict:
     return n
 
 
-def compare(cfg, lanes, before, after, rows, windows, seed,
+def compare(family, cfg, lanes, before, after, rows, windows, seed,
             limits, devices=None) -> dict:
-    """Every number, the worst over the lanes, with its limit; ``correct``
-    when none is over its limit. The reference replays the lanes on
-    ``devices`` (``reference_lanes``)."""
-    b, a = as_dict(before), as_dict(after)
+    """Every number, the shared ones and the family's, the worst over the
+    lanes, with its limit; ``correct`` when none is over its limit. The
+    reference replays the lanes on ``devices`` (``reference_lanes``)."""
+    fields = state_fields(family)
+    b, a = as_dict(before, fields), as_dict(after, fields)
     single = len(lanes) == 1 and np.ndim(b["window"]) == 0
     if single:
         b, a = _lanes(b, True), _lanes(a, True)
         rows = _lanes(rows, True, axis=1)
-    refs = reference_lanes(cfg, lanes, b, windows, seed, devices=devices)
+    refs = reference_lanes(family, cfg, lanes, b, windows, seed,
+                           devices=devices)
     worst = {}
     failed = 0
     for i in range(len(lanes)):
-        n = lane_numbers({k: v[i] for k, v in a.items()},
-                         {k: np.asarray(v)[:, i] for k, v in rows.items()},
-                         *refs[i])
+        a_i = {k: v[i] for k, v in a.items()}
+        rows_i = {k: np.asarray(v)[:, i] for k, v in rows.items()}
+        n = lane_numbers(a_i, rows_i, *refs[i])
+        if family.NUMBERS:
+            n.update(family.numbers(a_i, rows_i, *refs[i]))
         failed += any(n[k] > limits[k] for k in n)
         for k, v in n.items():
             worst[k] = max(worst.get(k, v), v)
     worst["tally_drift"] = max(drift(b), drift(a))
     failed = max(failed, int(worst["tally_drift"] > limits["tally_drift"]))
     numbers = {k: {"value": worst[k], "limit": limits[k]}
-               for k in ("events_differ", "decisions_differ",
-                         "tallies_differ", "stats_differ", "stats_gap",
-                         "tally_drift")}
+               for k in NUMBERS + tuple(family.NUMBERS)}
     return {"correct": all(v["value"] <= v["limit"]
                            for v in numbers.values()),
             "failed_lanes": failed, "numbers": numbers}

@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
-"""Run a cell's control on the chip: the plain reference in the program's
-place, computed in bfloat16 (one step below the float32 the configuration
-states), compared as a run's output is. Every seed must come out not
-correct; the numbers it reads are the upper readings the limits sit under.
+"""Run a cell's control on the chip: the plain reference of the
+deployment's family in the program's place, computed in bfloat16 (one step
+below the float32 the configuration states), compared as a run's output
+is. Every seed must come out not correct; the numbers it reads are the
+upper readings the limits sit under.
 
     python3 bench/control.py --workload <name> --seeds 1,2,3
 

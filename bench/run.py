@@ -5,17 +5,20 @@
 
 A cell (``workloads`` in ``BENCHMARK.json``) names a deployment
 (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<traffic>.json``); per-layer metrics are read by
-``bench/layers/<metric>.py``. Everything is found by name, so a new cell
-needs new files and entries only.
+(``bench/traffic/<traffic>.json``). The deployment file names its family
+(``bench/families/<family>.py``): the generator of its rows, its plain
+reference, and the state leaves and numbers it compares beyond the shared
+ones. Per-layer metrics are read by ``bench/layers/<metric>.py``.
+Everything is found by name, so a new cell, or a new family, needs new
+files and entries only.
 
 Set-up (``setup_s``: process start to the first timed window) generates the
-cell's windows from ``--seed`` (``gen.py``), writes them with the program's
-``precompile_stream``, runs the warm segment (the trace's opening burst and
-the windows up to the first whole batch) through the program's drive loop,
-keeps that state on the device as the start snapshot, and runs one batch
-of a restored trajectory so that every program the window uses is compiled
-or loaded from the persistent cache.
+cell's windows from ``--seed`` (the family's rows, packed by ``gen.py``),
+writes them with the program's ``precompile_stream``, runs the warm segment
+(the trace's opening burst and the windows up to the first whole batch)
+through the program's drive loop, keeps that state on the device as the
+start snapshot, and runs one batch of a restored trajectory so that every
+program the window uses is compiled or loaded from the persistent cache.
 
 The measured window then runs trajectories back to back over the steady
 segment, each restored from the snapshot, for ``--seconds`` (the last one
@@ -36,14 +39,16 @@ as it did before the mesh path: no mesh, the same spans and readings.
 
 Correctness: in each trajectory the states before and after one batch,
 drawn from the seed, and that batch's stats rows are kept as the program
-produced them; after the window ``reference.py`` replays the batch from the
-state before it and compares (``check.py``). With ``--trace 1`` the first
-trajectory of the window runs under the profiler and the per-layer metrics
-are read from its trace and from host spans.
+produced them; after the window the family's reference replays the batch
+from the state before it and ``check.py`` compares. With ``--trace 1`` the
+first trajectory of the window runs under the profiler and the per-layer
+metrics are read from its trace and from host spans.
 
 The run fails (exit code 2, no result line) where JAX finds no TPU, fewer
 chips than the cell asks for, or a device kind missing from
-``peaks.json``.
+``peaks.json``; where the deployment names no family, or one with no
+module; and where a number has no limit or the program's state lacks a
+leaf the family compares.
 """
 from __future__ import annotations
 
@@ -58,6 +63,7 @@ import gc
 import importlib.util
 import json
 import os
+import re
 import sys
 import threading
 
@@ -66,6 +72,8 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 PROGRAM_SEED_MOD = 1 << 30      # the program's seeds are int32 scalars
+NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
+FAMILY_NEEDS = ("horizon", "rows", "run_batch", "STATE_FIELDS", "NUMBERS")
 
 
 class BenchError(Exception):
@@ -98,6 +106,7 @@ class Cell:
     name: str
     chips: int
     config: dict
+    family: object          # the module bench/families/<family>.py
     traffic: dict
     end_to_end: list
     per_layer: list
@@ -129,20 +138,68 @@ def resolve(root: str, workload: str) -> Cell:
     own = os.path.join(here, "limits", workload + ".json")
     if os.path.exists(own):
         limits.update(_read_json(own))
+    family = load_family(root, config)
+    import check
+    unlimited = [k for k in check.NUMBERS + tuple(family.NUMBERS)
+                 if k not in limits]
+    if unlimited:
+        raise BenchError(f"{workload}: no limit for {unlimited} in "
+                         "bench/limits/")
     return Cell(name=workload, chips=int(w["chips"]), config=config,
-                traffic=traffic,
+                family=family, traffic=traffic,
                 end_to_end=[m for m in bench["end_to_end"] if mine(m)],
                 per_layer=[m for m in bench["per_layer"] if mine(m)],
                 limits=limits)
 
 
-def load_layer(root: str, metric: str):
-    """The reader module of a per-layer metric: ``layers/<metric>.py``."""
-    path = os.path.join(root, "bench", "layers", metric + ".py")
+def _load(path: str, name: str):
     spec = importlib.util.spec_from_file_location(
-        "bench_layer_" + metric.replace(".", "_"), path)
+        re.sub(r"[.-]", "_", name), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    return mod
+
+
+def load_layer(root: str, metric: str):
+    """The reader module of a per-layer metric: ``layers/<metric>.py``."""
+    return _load(os.path.join(root, "bench", "layers", metric + ".py"),
+                 "bench_layer_" + metric)
+
+
+def load_family(root: str, config: dict):
+    """The family module the deployment file names under ``family``:
+    ``families/<family>.py``, which provides ``FAMILY_NEEDS``:
+
+    * ``horizon(cfg, config, traffic, batch)``: the trace windows a run
+      needs, and ``rows(cfg, config, traffic, seed, horizon)``: its
+      ``gen.Rows``, the same for the same seed;
+    * ``run_batch(state, windows, seed, lane, *, dims, dtype)``: the plain
+      reference of one lane over a batch of windows, from a dict of state
+      leaves, with ``dims`` the configuration's numbers and ``dtype`` the
+      precision of scores and tallies (bfloat16 for the control); returns
+      (state after, stats rows);
+    * ``STATE_FIELDS``: state leaves it compares beyond
+      ``reference.STATE_FIELDS``, read from the program's state by name;
+    * ``NUMBERS``: the numbers it adds to ``check.NUMBERS``, each with its
+      limit in ``limits/``, computed per lane by
+      ``numbers(after, rows, ref_after, ref_rows)``.
+    """
+    name = config.get("family")
+    if name is None:
+        raise BenchError(f"configuration {config.get('name')!r} names no "
+                         "family")
+    path = os.path.join(root, "bench", "families", f"{name}.py")
+    if not (isinstance(name, str) and NAME.fullmatch(name)
+            and os.path.isfile(path)):
+        raise BenchError(f"configuration {config.get('name')!r} names the "
+                         f"family {name!r}, which has no "
+                         f"bench/families/{name}.py")
+    mod = _load(path, "bench_family_" + name)
+    needs = FAMILY_NEEDS + (("numbers",) if getattr(mod, "NUMBERS", ())
+                            else ())
+    lacks = [a for a in needs if not hasattr(mod, a)]
+    if lacks:
+        raise BenchError(f"the family {name!r} has no {lacks}")
     return mod
 
 
@@ -405,14 +462,14 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
 def _run_cell(workload, seed, seconds, trace, root, require_tpu,
               compile_cache, t_start, trace_out, control, compiles) -> dict:
-    cell = resolve(root, workload)
-    cache = os.path.join(root, "bench", ".cache")
     sys.path.insert(0, os.path.join(ROOT, "src"))
     try:
         from repro import env
     except ImportError as e:
         raise BenchError(f"the program (src/repro) is not in this checkout: "
                          f"{e}")
+    cell = resolve(root, workload)
+    cache = os.path.join(root, "bench", ".cache")
     import jax
     peaks = _read_json(os.path.join(HERE, "peaks.json"))
     device = device_info(jax, cell.chips, require_tpu, peaks)
@@ -424,7 +481,7 @@ def _run_cell(workload, seed, seconds, trace, root, require_tpu,
     cfg = sim_config(cell.config)
     tr = cell.traffic
     batch = int(tr["batch_windows"])
-    rows, plan = gen.make(cfg, cell.config["cluster"], tr, seed, batch)
+    rows, plan = gen.make(cell.family, cfg, cell.config, tr, seed, batch)
     rng = np.random.default_rng([seed, 7])
     n_batches = plan.steady // batch
     skip = resync_ends(cfg, plan.warm, batch, n_batches)
@@ -465,6 +522,10 @@ def _run_cell(workload, seed, seconds, trace, root, require_tpu,
     if drv.windows_done != plan.warm:
         raise BenchError(f"warm segment ran {drv.windows_done} of "
                          f"{plan.warm} windows")
+    lacks = [f for f in cell.family.STATE_FIELDS if not hasattr(snap, f)]
+    if lacks:
+        raise BenchError(f"the program's state has no {lacks}, which the "
+                         f"family {cell.config['family']!r} compares")
     occupancy = _occupancy(snap)
     del drv
     # every program of the window, once: the resync, and one restored batch
@@ -535,11 +596,12 @@ def _run_cell(workload, seed, seconds, trace, root, require_tpu,
     batch_seed = prog_seed + plan.warm + k_check * batch
     chips = jax.devices()[:cell.chips]
     if control:
-        host = (host[0],) + check.control_outputs(cfg, runner.lanes, host[0],
-                                                  check_windows, batch_seed,
-                                                  chips)
-    verdict = check.compare(cfg, runner.lanes, host[0], host[1], host[2],
-                            check_windows, batch_seed, cell.limits, chips)
+        host = (host[0],) + check.control_outputs(
+            cell.family, cfg, runner.lanes, host[0], check_windows,
+            batch_seed, chips)
+    verdict = check.compare(cell.family, cfg, runner.lanes, host[0], host[1],
+                            host[2], check_windows, batch_seed, cell.limits,
+                            chips)
     check_s = time.perf_counter() - t_check
 
     out = {"correct": verdict["correct"], "attempted": trajectories * lanes,
@@ -550,7 +612,7 @@ def _run_cell(workload, seed, seconds, trace, root, require_tpu,
         import xplane
         reduced = xplane.reduce_dir(traced["dir"])
         ctx = {"trace": reduced, "spans": spans, "traced": traced,
-               "lanes": lanes, "cfg": cfg}
+               "lanes": lanes, "cfg": cfg, "config": cell.config}
         for m in cell.per_layer:
             v = load_layer(root, m["name"]).read(ctx)
             if v is not None:

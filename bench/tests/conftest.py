@@ -53,13 +53,13 @@ def tiny_files() -> dict:
 
 
 def make_tiny_root(root) -> str:
-    """Write the tiny root's files and the benchmark's readers and limits
-    under the directory ``root``."""
+    """Write the tiny root's files and the benchmark's families, readers
+    and limits under the directory ``root``."""
     for rel, obj in tiny_files().items():
         path = root / rel
         path.parent.mkdir(parents=True, exist_ok=True)
         path.write_text(json.dumps(obj))
-    for d in ("layers", "limits"):
+    for d in ("families", "layers", "limits"):
         shutil.copytree(os.path.join(BENCH, d), root / "bench" / d)
     return str(root)
 

@@ -1,7 +1,9 @@
 """The comparison that decides ``correct`` fails when the timed path is
 broken underneath a whole run (the chip check skipped, tiny cells on the
-CPU), and when the bfloat16 control stands in the program's place."""
+CPU), and when the bfloat16 control stands in the program's place; a
+family's own leaves reach its reference."""
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -111,3 +113,29 @@ def test_drift_reads_a_node_outside_the_table():
     assert check.drift(s) < 1e-6
     s["task_node"][0, 0] = N
     assert check.drift(s) == 1.0
+
+
+def test_family_leaves_reach_its_reference():
+    """A leaf a family lists beyond the shared ones is read from the state
+    by name and handed to the family's reference with the configuration's
+    numbers, in the precision asked for."""
+    from repro.config import SimConfig
+    seen = []
+
+    def run_batch(state, windows, seed, lane, *, dims, dtype):
+        seen.append((sorted(state), lane, dims["max_nodes"], dtype))
+        return state, {}
+
+    family = types.SimpleNamespace(STATE_FIELDS=("gpu_free",),
+                                   run_batch=run_batch)
+    state = {f: np.zeros((2, 3)) for f in check.state_fields(family)}
+    assert check.state_fields(family)[-1] == "gpu_free"
+    lanes = [{"scheduler": "greedy"}, {"scheduler": "first_fit"}]
+    outs = check.reference_lanes(family, SimConfig(max_nodes=3), lanes,
+                                 state, {"kind": np.zeros((4, 5))}, 7,
+                                 dtype=jnp.bfloat16)
+    assert len(outs) == 2
+    assert [s[1] for s in seen] == lanes
+    for keys, _, nodes, dtype in seen:
+        assert "gpu_free" in keys and "node_used" in keys
+        assert nodes == 3 and dtype == jnp.bfloat16

@@ -1,9 +1,11 @@
-"""The benchmark's vectorised generator against the program's own model
-(``core/tracegen.py``) at a small size, its rates against the source's
-published totals at the cell's size, and its stacks through
-``validate_replay``."""
+"""The GCD family's vectorised generator (``families/gcd.py``) against the
+program's own model (``core/tracegen.py``) at a small size, its rates
+against the source's published totals at the cell's size, its stacks
+through ``validate_replay``, and its rows and packed windows against
+hashes pinned before it moved out of ``gen.py``."""
 import csv
 import glob
+import hashlib
 import json
 import os
 
@@ -11,7 +13,8 @@ import numpy as np
 import pytest
 
 import gen
-from conftest import BENCH, TINY_SIM
+import run
+from conftest import BENCH, REPO, TINY_SIM, tiny_files
 from repro.config import SimConfig
 from repro.core.events import EventKind
 from repro.core.precompile import (load_window_range, precompile_stream,
@@ -25,6 +28,9 @@ N_JOBS = 3000
 def _json(*parts):
     with open(os.path.join(BENCH, *parts)) as f:
         return json.load(f)
+
+
+gcd = run.load_family(REPO, _json("configs", "gcd_cell_a.json"))
 
 
 # core/tracegen.py's tail of tasks per job; the traffic files fit its tail
@@ -44,7 +50,7 @@ def _gcd_model():
 def ours():
     cl, tr = _gcd_model()
     cfg = SimConfig(**dict(TINY_SIM, max_tasks=32768))
-    return gen.gcd_rows(cfg, cl, tr, 3, HORIZON)
+    return gcd.gcd_rows(cfg, cl, tr, 3, HORIZON)
 
 
 @pytest.fixture(scope="module")
@@ -125,7 +131,7 @@ def test_rates_match_source_totals(traffic):
     assert tr["jobs_per_hour"] == pytest.approx(
         tot["jobs"] / (tot["trace_days"] * 24), rel=0.01)
     cfg = SimConfig(**conf["sim"])
-    rows, plan = gen.make(cfg, cl, tr, 2**31 + 11, 16)
+    rows, plan = gen.make(gcd, cfg, conf, tr, 2**31 + 11, 16)
     # arrivals come after window 0, which holds the standing workload
     per_job = np.bincount(rows.job[(rows.kind == EventKind.ADD_TASK)
                                    & (rows.t >= W)])
@@ -156,11 +162,11 @@ def test_rates_match_source_totals(traffic):
 @pytest.mark.parametrize("traffic", ["greedy_backlog", "mixed_fleet"])
 def test_stack_replays_verified(tmp_path, traffic):
     conf = _json("configs", "gcd_cell_a.json")
-    cl = dict(conf["cluster"], n_machines=48)
+    conf["cluster"] = dict(conf["cluster"], n_machines=48)
     tr = dict(_json("traffic", f"{traffic}.json"), steady_windows=32,
               standing_tasks=700, **TRACEGEN_TAIL)
     cfg = SimConfig(**TINY_SIM)
-    rows, plan = gen.make(cfg, cl, tr, 2**31 + 7, 8)
+    rows, plan = gen.make(gcd, cfg, conf, tr, 2**31 + 7, 8)
     windows = list(gen.windows(cfg, rows, plan.n_windows))
     path = str(tmp_path / "s.npz")
     precompile_stream(cfg, iter(windows), path, plan.n_windows,
@@ -176,8 +182,36 @@ def test_stack_replays_verified(tmp_path, traffic):
     assert gen.steady_rows_per_window(cfg, rows, plan)["max"] <= \
         cfg.events_per_window
     # the same seed gives the same rows, another seed others
-    again, _ = gen.make(cfg, cl, tr, 2**31 + 7, 8)
-    other, _ = gen.make(cfg, cl, tr, 2**31 + 8, 8)
+    again, _ = gen.make(gcd, cfg, conf, tr, 2**31 + 7, 8)
+    other, _ = gen.make(gcd, cfg, conf, tr, 2**31 + 8, 8)
     assert all(np.array_equal(x, y) for x, y in zip(rows, again))
     assert not np.array_equal(rows.t, other.t) or \
         not np.array_equal(rows.a, other.a)
+
+
+def _digest(arrays) -> str:
+    h = hashlib.sha256()
+    for x in arrays:
+        x = np.ascontiguousarray(x)
+        h.update(f"{x.dtype.str}{x.shape}".encode())
+        h.update(x.tobytes())
+    return h.hexdigest()
+
+
+PINS = _json("tests", "data", "gcd_pin.json")["seeds"]
+
+
+@pytest.mark.parametrize("seed", sorted(PINS))
+def test_gcd_family_reproduces_pinned_stack(seed):
+    """The tiny deployment's rows and packed windows hash as they did when
+    ``gen.py`` held the GCD model (``data/gcd_pin.json``)."""
+    files = tiny_files()
+    conf = files["bench/configs/tiny_gcd.json"]
+    tr = files["bench/traffic/tiny_single.json"]
+    cfg = SimConfig(**conf["sim"])
+    rows, plan = gen.make(gcd, cfg, conf, tr, int(seed), tr["batch_windows"])
+    windows = gen.windows(cfg, rows, plan.n_windows)
+    assert list(plan) == PINS[seed]["plan"]
+    assert _digest(rows) == PINS[seed]["rows"]
+    assert _digest(getattr(w, f) for w in windows for f in w._fields) == \
+        PINS[seed]["windows"]
