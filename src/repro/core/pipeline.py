@@ -256,6 +256,10 @@ class WindowedDriver:
         """Full accounting recompute (subclass hook; identity by default)."""
         return self.state
 
+    def _dispatch_args(self) -> dict:
+        """Extra args of the ``drive.dispatch`` span (subclass hook)."""
+        return {}
+
     def pause(self):
         self._paused.set()
 
@@ -272,7 +276,8 @@ class WindowedDriver:
                 time.sleep(0.01)
             W = batch.kind.shape[0]
             with trace.span("drive.dispatch", batch=self.prefetcher.batch_id,
-                            windows=W, inflight=len(self._inflight)):
+                            windows=W, inflight=len(self._inflight),
+                            **self._dispatch_args()):
                 stats = self._advance(batch, self.seed + self.windows_done)
             k = self.cfg.stats_stride
             m, r = divmod(W, k)

@@ -23,7 +23,9 @@ are derived the same way).
 devices (vmap inside each shard), the window batch is broadcast to every
 device, and per-lane stats are gathered back along the scenario axis. Lanes
 never communicate, so per-lane results are identical to the pure-vmap path
-(tested in tests/test_scenarios_sharded.py).
+(tested in tests/test_scenarios_sharded.py). Given the fleet's static lane
+map, each chip runs the switchless dispatch of its own lanes, picked by
+shard index.
 """
 from __future__ import annotations
 
@@ -206,18 +208,27 @@ def make_scenario_step(cfg: SimConfig, scheduler_names: Tuple[str, ...],
     return step
 
 
+def dispatch_mode(cfg: SimConfig, table: DispatchTable,
+                  lane_scheds) -> str:
+    """The scheduler dispatch a fleet launch compiles: ``"table"``
+    (switchless) where the launch has the per-lane scheduler assignment as
+    a STATIC tuple (``lane_scheds``, from ScenarioFleet) and every
+    scheduler in the table has a table form, unless
+    ``cfg.sched_dispatch='switch'`` forces the fallback; ``"switch"``
+    (the vmapped ``lax.switch``) otherwise. Never raises."""
+    able = lane_scheds is not None and table.switchless
+    return "table" if able and cfg.sched_dispatch != "switch" else "switch"
+
+
 def _want_switchless(cfg: SimConfig, table: DispatchTable,
                      lane_scheds) -> bool:
-    """Resolve ``cfg.sched_dispatch`` against what this launch can do.
-
-    Switchless needs the per-lane scheduler assignment as a STATIC tuple
-    (``lane_scheds``, from ScenarioFleet) and a table form for every
-    scheduler in the table. 'auto' falls back to switch when either is
-    missing; 'table' raises instead of silently degrading."""
-    able = lane_scheds is not None and table.switchless
-    if cfg.sched_dispatch == "switch":
-        return False
-    if cfg.sched_dispatch == "table" and not able:
+    """Resolve ``cfg.sched_dispatch`` against what this launch can do
+    (:func:`dispatch_mode`). A mesh-sharded launch counts as able when it
+    has the fleet's whole lane map: each chip then dispatches its own
+    lanes switchlessly. 'auto' falls back to switch when the map or a
+    table form is missing; 'table' raises instead of silently degrading."""
+    if (cfg.sched_dispatch == "table"
+            and dispatch_mode(cfg, table, lane_scheds) != "table"):
         opaque = [n for n, f in zip(table.names, table.forms) if f is None]
         if opaque:
             raise ValueError(
@@ -225,10 +236,29 @@ def _want_switchless(cfg: SimConfig, table: DispatchTable,
                 "no table form — register_scheduler(..., table_form=...) "
                 "them or drop to 'auto'/'switch'")
         raise ValueError(
-            "cfg.sched_dispatch='table' but no static lane assignment was "
-            "provided (sharded fleets and the serving warm path dispatch "
-            "with lax.switch) — use 'auto' or 'switch'")
-    return able
+            "cfg.sched_dispatch='table' but no static lane assignment "
+            "(lane_scheds) was provided (the serving warm path has none and "
+            "dispatches with lax.switch) — pass the fleet's per-lane "
+            "scheduler map or use 'auto'/'switch'")
+    return dispatch_mode(cfg, table, lane_scheds) == "table"
+
+
+def _switchless_advance(cfg: SimConfig, has_storm: bool, sched_B):
+    """The switchless (B, ...)-lane advance: the vmapped prelude, ONE
+    batched scheduler pass ``sched_B(state_B, key) -> state_B`` (base pass,
+    proposals, commit), then the vmapped accounting recompute."""
+    vpre = jax.vmap(make_scenario_prelude(cfg, has_storm),
+                    in_axes=(0, None, 0))
+    vrec = jax.vmap(lambda s: eng.recompute_accounting(s, cfg))
+
+    def vadv(state_B, w, key, kn):
+        state_B, injected = vpre(state_B, w, kn)
+        state_B = sched_B(state_B, key)
+        if not cfg.incremental_accounting:
+            state_B = vrec(state_B)
+        return state_B._replace(window=state_B.window + 1), injected
+
+    return vadv
 
 
 def run_scenarios(state: SimState, windows: EventWindow, knobs: ScenarioKnobs,
@@ -267,21 +297,20 @@ def run_scenarios(state: SimState, windows: EventWindow, knobs: ScenarioKnobs,
     if table is None:
         table = snapshot_dispatch(scheduler_names)
     if _want_switchless(cfg, table, lane_scheds):
-        prelude = make_scenario_prelude(cfg, has_storm)
-        vpre = jax.vmap(prelude, in_axes=(0, None, 0))
-        sched_B = make_switchless_dispatch(cfg, table, lane_scheds)
-        vrec = jax.vmap(lambda s: eng.recompute_accounting(s, cfg))
-
-        def vadv(state_B, w, key, kn):
-            state_B, injected = vpre(state_B, w, kn)
-            state_B = sched_B(state_B, key)
-            if not cfg.incremental_accounting:
-                state_B = vrec(state_B)
-            return state_B._replace(window=state_B.window + 1), injected
+        vadv = _switchless_advance(
+            cfg, has_storm, make_switchless_dispatch(cfg, table, lane_scheds))
     else:
         advance = make_scenario_advance(cfg, scheduler_names, has_storm,
                                         table)
         vadv = jax.vmap(advance, in_axes=(0, None, None, 0))
+    return _scan_fleet(state, windows, knobs, cfg, seed, vadv)
+
+
+def _scan_fleet(state: SimState, windows: EventWindow, knobs: ScenarioKnobs,
+                cfg: SimConfig, seed: int, vadv
+                ) -> Tuple[SimState, Dict[str, jax.Array]]:
+    """Scan the (B, ...)-lane advance ``vadv`` over the windows, with the
+    stats rows (``cfg.stats_stride``) and RNG keys of :func:`run_scenarios`."""
     vstats = jax.vmap(lambda s: stats_mod.window_stats(s, cfg))
 
     def rows_for(s, injected):
@@ -342,11 +371,25 @@ def resync_fleet_jit(state: SimState, cfg: SimConfig) -> SimState:
     return jax.vmap(lambda s: eng.recompute_accounting(s, cfg))(state)
 
 
+def chip_layouts(lane_scheds: Tuple[int, ...], n_chips: int
+                 ) -> Tuple[Tuple[Tuple[int, ...], ...], Tuple[int, ...]]:
+    """Cut a fleet's static lane map into the chips' local maps.
+
+    Chip ``c`` holds lanes ``c*L .. (c+1)*L - 1`` (``L = B / n_chips``, the
+    block ``P(FLEET_AXIS)`` gives it). Returns the distinct local maps, in
+    order of first use, and each chip's index into them."""
+    L = len(lane_scheds) // n_chips
+    local = [tuple(lane_scheds[c * L:(c + 1) * L]) for c in range(n_chips)]
+    layouts = tuple(dict.fromkeys(local))
+    return layouts, tuple(layouts.index(t) for t in local)
+
+
 def run_scenarios_sharded(state: SimState, windows: EventWindow,
                           knobs: ScenarioKnobs, cfg: SimConfig,
                           scheduler_names: Tuple[str, ...], mesh: Mesh,
                           seed: int = 0, has_storm: bool = True,
-                          table: Optional[DispatchTable] = None
+                          table: Optional[DispatchTable] = None,
+                          lane_scheds: Optional[Tuple[int, ...]] = None
                           ) -> Tuple[SimState, Dict[str, jax.Array]]:
     """``run_scenarios`` with the scenario axis split over a device mesh.
 
@@ -356,25 +399,47 @@ def run_scenarios_sharded(state: SimState, windows: EventWindow,
     the plain vmapped program on its B/n local lanes with the same RNG key
     schedule, so per-lane results match the single-device path exactly.
 
-    The shard body is traced once for every shard, so per-lane STATIC
-    scheduler grouping is unavailable — sharded fleets always dispatch
-    through the ``lax.switch`` path (``cfg.sched_dispatch='table'`` raises
-    here; lane trajectories are bitwise-identical either way).
+    Scheduler dispatch follows :func:`run_scenarios`' rule, per chip. With
+    ``lane_scheds`` (the whole fleet's static map, pad lanes included) and
+    a fully table-formed registry, the map is cut into the chips' local
+    maps (:func:`chip_layouts`) and each distinct one becomes its own
+    switchless dispatch. The shard body picks its chip's dispatch with a
+    ``lax.switch`` on the shard index: that index is not batched, so the
+    switch lowers to a conditional that runs one branch. Only the
+    scheduler pass sits in it; the prelude, the recompute and the stats
+    row stay outside. Where every chip has the same local map there is no
+    switch at all. Without the map, with an opaque plugin or under
+    ``cfg.sched_dispatch='switch'``, every lane dispatches through the
+    vmapped ``lax.switch`` fallback. Lane trajectories are
+    bitwise-identical either way.
     """
     B = jax.tree.leaves(state)[0].shape[0]
     n_dev = mesh.shape[FLEET_AXIS]
     if B % n_dev:
         raise ValueError(f"B={B} lanes not divisible by the {n_dev}-device "
                          f"'{FLEET_AXIS}' mesh axis — pad the spec list")
-    if cfg.sched_dispatch == "table":
-        raise ValueError(
-            "cfg.sched_dispatch='table' is incompatible with mesh-sharded "
-            "fleets (one shard_map trace serves every shard, so there is "
-            "no static per-lane scheduler assignment) — use 'auto'")
+    if table is None:
+        table = snapshot_dispatch(scheduler_names)
+    layouts, branch_of_chip = ((None,), None) if lane_scheds is None else \
+        chip_layouts(lane_scheds, n_dev)
 
-    def body(s, w, k):
-        return run_scenarios(s, w, k, cfg, scheduler_names, seed, has_storm,
-                             table)
+    if len(layouts) > 1 and _want_switchless(cfg, table, lane_scheds):
+        branches = [make_switchless_dispatch(cfg, table, local)
+                    for local in layouts]
+        branch = jnp.asarray(branch_of_chip, jnp.int32)
+
+        def sched_B(state_B, key):
+            return jax.lax.switch(branch[jax.lax.axis_index(FLEET_AXIS)],
+                                  branches, state_B, key)
+
+        vadv = _switchless_advance(cfg, has_storm, sched_B)
+
+        def body(s, w, k):
+            return _scan_fleet(s, w, k, cfg, seed, vadv)
+    else:
+        def body(s, w, k):
+            return run_scenarios(s, w, k, cfg, scheduler_names, seed,
+                                 has_storm, table, layouts[0])
 
     fn = jax.shard_map(body, mesh=mesh,
                        in_specs=(P(FLEET_AXIS), P(), P(FLEET_AXIS)),
@@ -385,12 +450,13 @@ def run_scenarios_sharded(state: SimState, windows: EventWindow,
 
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "scheduler_names", "mesh",
-                                    "has_storm", "table"),
+                                    "has_storm", "table", "lane_scheds"),
                    donate_argnames=("state",))
 def run_scenarios_sharded_jit(state: SimState, windows: EventWindow,
                               knobs: ScenarioKnobs, cfg: SimConfig,
                               scheduler_names: Tuple[str, ...], mesh: Mesh,
                               seed: int = 0, has_storm: bool = True,
-                              table: Optional[DispatchTable] = None):
+                              table: Optional[DispatchTable] = None,
+                              lane_scheds: Optional[Tuple[int, ...]] = None):
     return run_scenarios_sharded(state, windows, knobs, cfg, scheduler_names,
-                                 mesh, seed, has_storm, table)
+                                 mesh, seed, has_storm, table, lane_scheds)

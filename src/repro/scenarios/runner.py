@@ -12,7 +12,8 @@ Two scaling paths ride on top of the vmapped program:
 
 * ``mesh=`` shards the scenario axis over a 1-D ``('data',)`` device mesh
   via ``shard_map`` (vmap inside each shard, windows broadcast, per-lane
-  stats gathered back). The spec list is padded up to a multiple of the
+  stats gathered back; each chip runs the switchless dispatch of its own
+  lanes). The spec list is padded up to a multiple of the
   device count with inert identity lanes; padding lanes are invisible in
   stats, reports and snapshots.
 * :meth:`from_precompiled` feeds the fleet from a §V-A pre-compiled npz
@@ -87,12 +88,18 @@ class ScenarioFleet(WindowedDriver):
         # Dispatch contract, frozen NOW: the registry rows this fleet's
         # scheduler indices point at. Plugins registered after construction
         # cannot retarget them (regression-tested). The static per-lane
-        # scheduler map enables switchless dispatch on the unsharded path
-        # (sharded bodies are traced once for all shards — they keep the
-        # lax.switch fallback).
+        # scheduler map, pad lanes included, enables switchless dispatch:
+        # under a mesh each chip gets the dispatch of its own lanes
+        # (``batch.run_scenarios_sharded``).
         self.dispatch_table = snapshot_dispatch(self.scheduler_names)
-        self._lane_scheds = None if mesh is not None else tuple(
+        self._lane_scheds = tuple(
             self.scheduler_names.index(s.scheduler) for s in lanes)
+        # what the window program compiles: "table" or "switch", and the
+        # number of distinct chip lane maps (1 without a mesh)
+        self.sched_dispatch = batch_mod.dispatch_mode(
+            cfg, self.dispatch_table, self._lane_scheds)
+        self.n_layouts = 1 if mesh is None else len(batch_mod.chip_layouts(
+            self._lane_scheds, mesh.shape[batch_mod.FLEET_AXIS])[0])
         self.knobs = batch_mod.shard_over_fleet(self.knobs, mesh)
         self.state = batch_mod.init_batched_state(cfg, len(lanes), mesh)
 
@@ -139,7 +146,8 @@ class ScenarioFleet(WindowedDriver):
             self.state, stats = batch_mod.run_scenarios_sharded_jit(
                 self.state, batch, self.knobs, self.cfg,
                 self.scheduler_names, self.mesh, seed,
-                has_storm=self._has_storm, table=self.dispatch_table)
+                has_storm=self._has_storm, table=self.dispatch_table,
+                lane_scheds=self._lane_scheds)
         else:
             self.state, stats = batch_mod.run_scenarios_jit(
                 self.state, batch, self.knobs, self.cfg,
@@ -151,6 +159,9 @@ class ScenarioFleet(WindowedDriver):
 
     def _resync(self):
         return batch_mod.resync_fleet_jit(self.state, self.cfg)
+
+    def _dispatch_args(self) -> dict:
+        return {"sched": self.sched_dispatch, "layouts": self.n_layouts}
 
     def report(self, baseline: int = 0) -> dict:
         return scenario_report(self.names, self.stats_frame(),

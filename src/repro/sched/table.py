@@ -22,6 +22,11 @@ the whole fleet with the SA loop. The proposal table removes the switch:
   a second mask operand is shipped; elsewhere it is the jnp reference, the
   lanes' preference stack through one vmapped ``finalize``.
 
+Under a device mesh the fleet cuts its static lane map into the chips'
+local maps and builds one switchless dispatch per distinct map; each chip
+picks its own by shard index, a switch that is not vmapped and so runs one
+branch (``scenarios.batch.run_scenarios_sharded``).
+
 Schedulers without a table form (opaque plugins) are still first-class:
 ``DispatchTable.switchless`` is False the moment any fleet lane names one,
 and the fleet falls back to the original ``lax.switch`` path — bitwise the

@@ -34,12 +34,26 @@ U = CELL_A.n_usage_stats
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    topo = topologies.get_topology_desc(platform="tpu",
+    return topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The described host's four chips as the fleet's ('data',) mesh."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+    from repro.scenarios.batch import FLEET_AXIS
+    return Mesh(np.array(topo.devices[:4]), (FLEET_AXIS,),
+                axis_types=(AxisType.Auto,))
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -214,3 +228,34 @@ def test_switchless_fleet_default_config_commits_in_kernel(one_chip):
     assert "tpu_custom_call" in text
     assert "placement_commit" in text
     assert not COMMIT_LOOP.search(text)
+
+
+def test_sharded_fleet_dispatch_per_chip_compiles(four_chips):
+    """The mesh-sharded mixed fleet, lowered for four v5e chips with its
+    lane map: 8 lanes, 2 a chip, two distinct chip maps (greedy + annealing,
+    round robin + first fit). The chip's dispatch is one conditional whose
+    branches each hold the commit kernel; no branch is vmapped away."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro.config import REDUCED_SIM
+    from repro.core.events import empty_window
+    from repro.scenarios import ScenarioFleet, ScenarioSpec
+    from repro.scenarios import batch as batch_mod
+    names = ("greedy", "simulated_annealing", "round_robin", "first_fit")
+    specs = [ScenarioSpec(name=f"l{i}", scheduler=names[i % 4])
+             for i in range(8)]
+    probe = ScenarioFleet(REDUCED_SIM, iter(()), specs)
+    lane = NamedSharding(four_chips, PartitionSpec(batch_mod.FLEET_AXIS))
+    every = NamedSharding(four_chips, PartitionSpec())
+
+    def shapes(tree, sharding, lead=()):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            lead + x.shape, x.dtype, sharding=sharding), tree)
+
+    text = batch_mod.run_scenarios_sharded_jit.lower(
+        _state_shapes(REDUCED_SIM, lane, 8),
+        shapes(empty_window(REDUCED_SIM), every, (4,)),
+        shapes(probe.knobs, lane), REDUCED_SIM, probe.scheduler_names,
+        four_chips, 0, has_storm=False, table=probe.dispatch_table,
+        lane_scheds=probe._lane_scheds).compile().as_text()
+    assert text.count("conditional(") == 1
+    assert text.count("tpu_custom_call") >= 2

@@ -102,6 +102,10 @@ def test_drive_and_feed_spans_with_args(tmp_path, kind):
     assert [a["batch"] for a in disp] == list(range(n_batches))
     assert all(a["windows"] == BATCH for a in disp)
     assert [a["inflight"] for a in disp] == [0, 1, 1]
+    # a fleet names the dispatch it compiled and its chip lane maps
+    want = ({"sched": "table", "layouts": 1} if kind == "fleet" else {})
+    assert all({k: a[k] for k in ("sched", "layouts") if k in a} == want
+               for a in disp)
     assert [a["inflight"] for a in spans["drive.backpressure"]] == [2, 2]
     # every get, the end of the feed's queue included, with its depth
     assert len(spans["drive.wait_feed"]) == n_batches + 1
